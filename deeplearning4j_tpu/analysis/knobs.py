@@ -217,8 +217,6 @@ KNOBS: Tuple[Knob, ...] = (
     Knob("DL4J_TPU_AUTOSCALER_TICK_S", "float", "1", "serving",
          "Autoscaler control-loop tick interval."),
     # -- cold-start plane -----------------------------------------------------
-    Knob("DL4J_TPU_COMPILE_CACHE_DIR", "path", "unset", "warmstart",
-         "Arms the persistent compile cache (integrity-manifested)."),
     Knob("DL4J_TPU_WARMUP_MANIFEST", "path", "unset", "warmstart",
          "Traffic-derived warmup manifest: restarts AOT-compile "
          "observed shapes before /readyz flips."),

@@ -15,7 +15,7 @@ debugging/listeners (``sd.output(..., interpreted=True)``) — the moral
 equivalent of InferenceSession, useful for per-op inspection, never for the
 hot path.
 
-Variable taxonomy matches the reference: VARIABLE (trainable, persisted),
+Variable kinds match the reference: VARIABLE (trainable, persisted),
 CONSTANT (persisted, not trained), PLACEHOLDER (fed per call), ARRAY
 (activations — here just recorded graph nodes, never materialized except
 under the interpreter).

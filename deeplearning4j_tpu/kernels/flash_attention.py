@@ -22,30 +22,28 @@ sweep per q block — plus one XLA pass for delta = rowsum(dO*O). Scores are
 recomputed on-chip, so backward memory stays O(T·D) like forward. The
 same kernels run everywhere: compiled on TPU, interpret-mode in CPU tests
 (via DL4J_TPU_FORCE_PALLAS=1; plain CPU callers never reach them because
-flash_attention dispatches to reference_attention off-TPU).
+flash_attention auto-dispatches to reference_attention off-TPU, and an
+explicit ``backend="pallas"`` there raises).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
-from deeplearning4j_tpu.kernels._dispatch import on_tpu as _on_tpu
 from deeplearning4j_tpu.kernels._dispatch import (
+    active_kernel_mesh as _active_kernel_mesh,
     flash_block_sizes as _flash_block_sizes,
     flash_min_seq as _flash_min_seq,
     force_pallas as _force_pallas,
+    interpret as _interpret,
+    on_tpu as _on_tpu,
     use_pallas as _use_pallas,
 )
 
@@ -83,7 +81,7 @@ def _compiler_params(*semantics):
     """Mosaic grid-dimension semantics (parallel dims enable multi-core
     partitioning on megacore chips and better pipelining); only meaningful
     when compiled for TPU — interpret mode ignores them."""
-    if not (_HAS_PLTPU and _on_tpu()):
+    if not _on_tpu():
         return None
     return pltpu.CompilerParams(dimension_semantics=tuple(semantics))
 
@@ -267,7 +265,7 @@ def _flash_fwd(q, k, v, key_mask, *, causal, scale, block_q, block_k,
             pltpu.VMEM((block_q, dp), jnp.float32),
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
-        interpret=not _on_tpu(),
+        interpret=_interpret(),
     )(qp, kp, vp, km)
     out, lse = res if save_lse else (res, None)
     return out[:, :t, :d].reshape(b, h, t, d), lse
@@ -422,7 +420,7 @@ def _flash_bwd_impl(q, k, v, key_mask, out, lse, g, *, causal, scale,
             pltpu.VMEM((block_k, dp), jnp.float32),
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
-        interpret=not _on_tpu(),
+        interpret=_interpret(),
     )(qp, kp, vp, km, gp, lse, delta)
 
     km_index_qk = (lambda bh, qi, ki: (bh, 0, ki)) if has_mask else (
@@ -444,7 +442,7 @@ def _flash_bwd_impl(q, k, v, key_mask, out, lse, g, *, causal, scale,
         out_shape=jax.ShapeDtypeStruct((b * h, tq, dp), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
-        interpret=not _on_tpu(),
+        interpret=_interpret(),
     )(qp, kp, vp, km, gp, lse, delta)
 
     dq = dq[:, :t, :d].reshape(b, h, t, d)
@@ -479,6 +477,34 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, res, g):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+def _flash_on_mesh(mesh, q, k, v, key_mask, causal, scale, block_q, block_k):
+    """The kernel under a multi-device mesh: inside ``shard_map``, batch
+    split over the data-like axes and heads over the model axis (attention
+    is independent across both, so no collective is needed); a dimension
+    the axis size does not divide stays whole and is computed redundantly.
+    """
+    from deeplearning4j_tpu.runtime.device import MODEL_AXIS, data_like_axes
+
+    b, h = q.shape[:2]
+    batch_axes = data_like_axes(mesh)
+    if b % math.prod(mesh.shape[a] for a in batch_axes):
+        batch_axes = ()
+    head_axis = (MODEL_AXIS if h % mesh.shape.get(MODEL_AXIS, h + 1) == 0
+                 else None)
+    qkv_spec = P(batch_axes or None, head_axis, None, None)
+    args, specs = [q, k, v], [qkv_spec] * 3
+    if key_mask is not None:
+        args.append(key_mask)
+        specs.append(P(batch_axes or None, None))
+
+    def local(q, k, v, *km):
+        return _flash(q, k, v, km[0] if km else None, causal, scale,
+                      block_q, block_k)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=qkv_spec, check_vma=False)(*args)
+
+
 def flash_attention(q, k, v, *, causal: bool = False, scale=None, bias=None,
                     key_mask=None, block_q: int = None, block_k: int = None,
                     backend: str = None):
@@ -489,11 +515,11 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None, bias=None,
     forces the XLA fallback.
 
     ``backend``: None (auto), 'pallas', or 'xla'. Auto dispatch picks XLA's
-    fused attention below ``_dispatch.flash_min_seq()`` keys — measured on
-    v5e it wins there (kernels_ab 2026-07-30: fwd 8x at T=512) — and the
-    Pallas kernel at long sequences where the O(T^2) score materialization
-    pressures HBM. DL4J_TPU_FORCE_PALLAS=1 (kernel unit tests) still
-    forces the kernel path.
+    fused attention below ``_dispatch.flash_min_seq()`` keys, off-TPU, for
+    biased attention and for fewer than 8 queries, and the Pallas kernel
+    at long sequences where the O(T^2) score materialization pressures
+    HBM. DL4J_TPU_FORCE_PALLAS=1 (kernel unit tests) forces the kernel
+    path. An explicit 'pallas' that cannot be honoured raises.
     """
     d = q.shape[-1]
     scale = (d ** -0.5) if scale is None else scale
@@ -502,18 +528,24 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None, bias=None,
     default_bq, default_bk = _flash_block_sizes()
     block_q = default_bq if block_q is None else block_q
     block_k = default_bk if block_k is None else block_k
-    # Hard constraints on the kernel path regardless of request (off-TPU
-    # without the force env, an explicit 'pallas' also falls back — the
-    # compiled kernel only exists on TPU):
-    if (bias is not None or q.shape[2] < 8 or not _HAS_PLTPU
-            or not _use_pallas()):
-        backend = "xla"
-    elif backend is None:
-        if _force_pallas() or k.shape[2] >= _flash_min_seq():
-            backend = "pallas"
-        else:
-            backend = "xla"
+    can_pallas = bias is None and q.shape[2] >= 8 and _use_pallas()
+    if backend == "pallas" and not can_pallas:
+        # an explicit request is a contract: a caller that asked for the
+        # kernel must never be handed XLA without a word
+        raise ValueError(
+            "backend='pallas' cannot be honoured: "
+            + ("additive bias is not supported by the kernel"
+               if bias is not None else
+               f"query length {q.shape[2]} < 8" if q.shape[2] < 8 else
+               "not on TPU and DL4J_TPU_FORCE_PALLAS is not set"))
+    if backend is None:
+        backend = "pallas" if can_pallas and (
+            _force_pallas() or k.shape[2] >= _flash_min_seq()) else "xla"
     if backend == "xla":
         return reference_attention(q, k, v, causal=causal, bias=bias,
                                    key_mask=key_mask, scale=scale)
+    mesh = _active_kernel_mesh()
+    if mesh is not None:
+        return _flash_on_mesh(mesh, q, k, v, key_mask, causal, scale,
+                              block_q, block_k)
     return _flash(q, k, v, key_mask, causal, scale, block_q, block_k)

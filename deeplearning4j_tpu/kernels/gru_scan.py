@@ -36,15 +36,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU backend may be absent on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
-from deeplearning4j_tpu.kernels._dispatch import on_tpu as _on_tpu
+from deeplearning4j_tpu.kernels._dispatch import interpret as _interpret
 from deeplearning4j_tpu.kernels._dispatch import use_pallas as _use_pallas
 from deeplearning4j_tpu.ops import rnn as opsrnn
 
@@ -140,7 +134,7 @@ def _gru_pallas_fwd(x_proj_tm, rw, b, h0, save_workspace=False):
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        interpret=not _on_tpu(),
+        interpret=_interpret(),
     )(
         x_proj_tm,
         rw.astype(jnp.float32),
@@ -225,7 +219,7 @@ def _gru_pallas_bwd(gates_tm, hpn_tm, h_prev_tm, gh_tm, rw):
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        interpret=not _on_tpu(),
+        interpret=_interpret(),
     )(gates_tm, hpn_tm, h_prev_tm, gh_tm, rw.astype(jnp.float32))
 
 

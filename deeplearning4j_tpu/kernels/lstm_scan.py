@@ -31,15 +31,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU backend may be absent on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
-from deeplearning4j_tpu.kernels._dispatch import on_tpu as _on_tpu
+from deeplearning4j_tpu.kernels._dispatch import interpret as _interpret
 from deeplearning4j_tpu.kernels._dispatch import use_pallas as _use_pallas
 from deeplearning4j_tpu.ops import rnn as opsrnn
 
@@ -172,7 +166,7 @@ def _lstm_pallas_fwd(x_proj_tm, rw, b, h0, c0, peepholes, forget_bias,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        interpret=not _on_tpu(),
+        interpret=_interpret(),
     )(
         x_proj_tm,
         rw.astype(jnp.float32),
@@ -190,11 +184,10 @@ def _make_bwd_kernel(peep: bool):
     The sweep is dgrad-only (dz per step + the dh/dc carries): weight,
     bias and peephole grads are ONE large batched GEMM / reduction over
     the saved dz tensor OUTSIDE the kernel (the cuDNN dgrad-then-wgrad
-    schedule). r3's kernel accumulated dRW/db per step inside the sweep —
-    a tiny [H,N]x[N,4H] matmul plus a [H,4H] VMEM read-modify-write every
-    timestep on the sequential critical path — and measured 0.65x XLA
-    (BASELINE.md kernel A/B); hoisting the wgrad out removes that work
-    from the recurrence entirely."""
+    schedule). Accumulating dRW/db per step inside the sweep would put a
+    tiny [H,N]x[N,4H] matmul plus a [H,4H] VMEM read-modify-write on the
+    sequential critical path of every timestep; hoisting the wgrad out
+    removes that work from the recurrence entirely."""
 
     def kernel(*refs):
         (gates_ref, cs_ref, csp_ref, gh_ref, gcT_ref, rw_ref) = refs[0:6]
@@ -294,7 +287,7 @@ def _lstm_pallas_bwd(gates_tm, cs_tm, c_prev_tm, gh_tm, gcT, rw, peepholes):
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        interpret=not _on_tpu(),
+        interpret=_interpret(),
     )(
         gates_tm,
         cs_tm,

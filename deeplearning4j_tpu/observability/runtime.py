@@ -162,13 +162,10 @@ def _install_listener():
     global _listener_installed
     if _listener_installed:
         return
-    try:
-        import jax.monitoring
+    import jax.monitoring
 
-        jax.monitoring.register_event_duration_secs_listener(_dispatch_event)
-        _listener_installed = True
-    except Exception:  # noqa: BLE001 - older jax without the API
-        pass
+    jax.monitoring.register_event_duration_secs_listener(_dispatch_event)
+    _listener_installed = True
 
 
 def get_runtime_collector() -> RuntimeCollector:

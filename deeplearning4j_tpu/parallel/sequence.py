@@ -34,23 +34,19 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-try:  # jax >= 0.6: top-level export, replication check renamed check_vma
-    from jax import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-except ImportError:  # jax 0.4/0.5: experimental module, check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
 
-
-def shard_map(f, mesh, in_specs, out_specs):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **_SHARD_MAP_KW)
-
+from deeplearning4j_tpu.kernels._dispatch import kernel_mesh, use_pallas
 from deeplearning4j_tpu.kernels.flash_attention import (
     flash_attention,
     reference_attention,
 )
 from deeplearning4j_tpu.runtime.device import SEQ_AXIS
+
+
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
 
 _NEG_INF = -1e30
 
@@ -165,11 +161,18 @@ def ring_attention(
 
 def ulysses_attention(
     q, k, v, *, mesh: Mesh, causal: bool = False, scale: Optional[float] = None,
-    key_mask=None, seq_axis: str = SEQ_AXIS, use_flash: bool = True,
+    key_mask=None, seq_axis: str = SEQ_AXIS,
+    use_flash: Optional[bool] = None,
     block_q: int = 256, block_k: int = 256,
 ):
     """Ulysses-style SP: all-to-all head-scatter/seq-gather, local full-seq
-    attention (flash kernel), inverse all-to-all. q/k/v [B,H,T,D] global."""
+    attention, inverse all-to-all. q/k/v [B,H,T,D] global. ``use_flash``:
+    the local attention is the Pallas flash kernel (True) or the XLA
+    reference (False); None picks the kernel where it can run (on TPU, or
+    under the tests' DL4J_TPU_FORCE_PALLAS). An explicit True that cannot
+    be honoured raises."""
+    if use_flash is None:
+        use_flash = use_pallas()
     if seq_axis not in mesh.axis_names:
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                key_mask=key_mask)
@@ -202,10 +205,12 @@ def ulysses_attention(
         if use_flash:
             # Explicit backend: use_flash=True means the Pallas kernel, not
             # the auto-dispatch (which would route short sequences to XLA
-            # and make this flag a no-op).
-            out = flash_attention(qh, kh, vh, causal=causal, scale=scale,
-                                  key_mask=km_full, block_q=block_q,
-                                  block_k=block_k, backend="pallas")
+            # and make this flag a no-op). Already inside shard_map: shadow
+            # any mesh the kernel would otherwise wrap itself over.
+            with kernel_mesh(None):
+                out = flash_attention(qh, kh, vh, causal=causal, scale=scale,
+                                      key_mask=km_full, block_q=block_q,
+                                      block_k=block_k, backend="pallas")
         else:
             out = reference_attention(qh, kh, vh, causal=causal, scale=scale,
                                       key_mask=km_full)

@@ -79,14 +79,10 @@ def _train_obs():
 def _nan_exception_types():
     """Exception classes that mean 'this step produced non-finite values':
     our host check, numpy's FP errors, and the checkify guard's raise."""
-    types: list = [NonFiniteLossError, FloatingPointError]
-    try:
-        from jax.experimental import checkify
+    from jax.experimental import checkify
 
-        types.append(checkify.JaxRuntimeError)
-    except (ImportError, AttributeError):  # older jax spells it differently
-        pass
-    return tuple(types)
+    return (NonFiniteLossError, FloatingPointError,
+            checkify.JaxRuntimeError)
 
 
 @dataclasses.dataclass

@@ -119,7 +119,7 @@ ENV_SHRINK_POLICY = "DL4J_TPU_SHRINK_POLICY"
 # shape mix instead of recompiling from scratch. Literals duplicated
 # from runtime/compilecache.py + serving/warmstart.py — this module
 # must stay importable without jax.
-ENV_COMPILE_CACHE_DIR = "DL4J_TPU_COMPILE_CACHE_DIR"
+ENV_COMPILE_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 ENV_WARMUP_MANIFEST = "DL4J_TPU_WARMUP_MANIFEST"
 
 # the rotation-index file serde/checkpoint.py maintains — watched (never
@@ -744,7 +744,10 @@ class ElasticSupervisor:
                 env[ENV_SHRINK_POLICY] = str(self.shrink_policy)
             env[ENV_HEARTBEAT_DIR] = str(hb)
             env[ENV_HEARTBEAT_INTERVAL] = str(self.heartbeat_interval_s)
-            if self.compile_cache_dir is not None:
+            if self.compile_cache_dir is not None \
+                    and not env.get(ENV_COMPILE_CACHE_DIR):
+                # a directory the environment already names stands: the
+                # cache lives in one place (runtime/compilecache.py)
                 self.compile_cache_dir.mkdir(parents=True, exist_ok=True)
                 env[ENV_COMPILE_CACHE_DIR] = str(self.compile_cache_dir)
             if self.warmup_manifest is not None:

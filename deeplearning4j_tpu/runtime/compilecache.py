@@ -28,15 +28,20 @@ must catch it; ``compile.cache_stall`` sleeps inside activation — a
 hung cache filesystem must keep ``/readyz`` not-ready, not wedge the
 process.
 
-Env config (the supervisor arms these for every worker generation, so
-relaunches and re-expansions land on a warm cache)::
+Where the cache lives is decided in ONE place, :func:`resolve_cache_dir`:
+``JAX_COMPILATION_CACHE_DIR`` when it is set — jax reads that variable
+itself at import, so this module then verifies and seals that directory
+in place and never points jax anywhere else — otherwise the fixed
+``<checkout>/.jax_cache``. The directory is part of jax's cache key, so
+it is never a temporary name, a pid or a timestamp.
 
-    DL4J_TPU_COMPILE_CACHE_DIR=/fast/cache   # arm on this directory
-    DL4J_TPU_WARMUP_MANIFEST=/fast/warmup.json  # serving/warmstart.py
-
+``enable_compile_cache()`` arms it explicitly (entry-point scripts:
+``chip_smoke.py``, ``bench.py``, ``kernels_ab.py``, the examples).
 ``maybe_enable_compile_cache()`` is the one-liner ``Trainer.fit`` and
-``ModelServer.start`` call: no env, no cost; env set, the process-wide
-cache activates once (idempotent).
+``ModelServer.start`` call: it arms only where the variable is set (the
+supervisor sets it for every worker generation, so relaunches and
+re-expansions land on a warm cache) — a library call must not start
+writing every program of a test run to disk on its own.
 """
 
 from __future__ import annotations
@@ -48,7 +53,16 @@ import time
 from pathlib import Path
 from typing import Dict, List, Optional
 
-ENV_COMPILE_CACHE_DIR = "DL4J_TPU_COMPILE_CACHE_DIR"
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+_CHECKOUT = Path(__file__).resolve().parents[2]
+
+
+def resolve_cache_dir() -> Path:
+    """The compile cache's directory: ``JAX_COMPILATION_CACHE_DIR`` if
+    set, else ``<checkout>/.jax_cache``."""
+    env = os.environ.get(ENV_CACHE_DIR)
+    return Path(env) if env else _CHECKOUT / ".jax_cache"
 
 _CACHE_MANIFEST = "cache_manifest.json"
 _QUARANTINE_DIR = "quarantine"
@@ -95,8 +109,9 @@ class CompileCache:
     compiles, it does not take the process down.
     """
 
-    def __init__(self, directory: str | Path):
-        self.directory = Path(directory)
+    def __init__(self, directory: Optional[str | Path] = None):
+        self.directory = (Path(directory) if directory is not None
+                          else resolve_cache_dir())
         self.quarantine_dir = self.directory / _QUARANTINE_DIR
         self._lock = threading.Lock()
         self.active = False
@@ -265,27 +280,31 @@ class CompileCache:
             inj.maybe_sleep("compile.cache_stall")
             if inj.fire("compile.cache_corrupt") is not None:
                 self._chaos_corrupt_one()
+        env_dir = os.environ.get(ENV_CACHE_DIR)
+        if env_dir and Path(env_dir) != self.directory:
+            raise ValueError(
+                f"{ENV_CACHE_DIR}={env_dir} is set: the compile cache "
+                f"lives there, not at {self.directory}")
         self.directory.mkdir(parents=True, exist_ok=True)
         verdict = self.verify()
+        if not env_dir:
+            # with the variable set jax already holds the directory (it
+            # reads the variable at import) and nothing here moves it
+            jax.config.update("jax_compilation_cache_dir",
+                              str(self.directory))
+            # jax binds its cache object to the FIRST directory it
+            # initializes; re-activation onto a different directory
+            # (tests) must drop that handle or the new dir is ignored
+            from jax._src import compilation_cache as _jax_cc
+
+            _jax_cc.reset_cache()
         # min-compile-time/entry-size floors dropped: serving buckets
         # are exactly the many-small-programs workload the defaults
         # (1 s / 4 KiB) would decline to cache
-        jax.config.update("jax_compilation_cache_dir",
-                          str(self.directory))
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         # cache faults degrade to fresh compiles, never crash serving
         jax.config.update("jax_raise_persistent_cache_errors", False)
-        try:
-            # jax binds its cache object to the FIRST directory it
-            # initializes; re-activation onto a different directory
-            # (tests, operator re-config) must drop that handle or the
-            # new dir is silently ignored
-            from jax._src import compilation_cache as _jax_cc
-
-            _jax_cc.reset_cache()
-        except Exception:  # noqa: BLE001 — private API; worst case the
-            pass           # process keeps its first cache dir
         self.active = True
         m = _metrics()
         if m is not None:
@@ -347,26 +366,28 @@ def set_compile_cache(cache: Optional[CompileCache]):
     _active_cache = cache
 
 
-def maybe_enable_compile_cache(
-        directory: Optional[str | Path] = None) -> Optional[CompileCache]:
-    """Activate the process-wide persistent compile cache once.
-
-    ``directory`` defaults to ``DL4J_TPU_COMPILE_CACHE_DIR``; with
-    neither set this is a no-op returning None. Subsequent calls return
-    the already-active cache (one directory per process — jax has one
-    global cache config). Called from ``Trainer.fit`` and
-    ``ModelServer.start`` so any entry point into compiled work picks
-    the cache up without plumbing."""
+def enable_compile_cache() -> CompileCache:
+    """Activate the process-wide persistent compile cache on
+    :func:`resolve_cache_dir`, once. Subsequent calls return the
+    already-active cache (one directory per process — jax has one
+    global cache config)."""
     global _active_cache
-    if _active_cache is not None:
-        return _active_cache
-    if directory is None:
-        directory = os.environ.get(ENV_COMPILE_CACHE_DIR) or None
-    if directory is None:
-        return None
     with _active_lock:
         if _active_cache is None:
-            cache = CompileCache(directory)
+            cache = CompileCache()
             cache.activate()
             _active_cache = cache
     return _active_cache
+
+
+def maybe_enable_compile_cache() -> Optional[CompileCache]:
+    """The implicit arm ``Trainer.fit`` and ``ModelServer.start`` call,
+    so any entry point into compiled work picks the cache up without
+    plumbing: the already-active cache if there is one, a fresh
+    activation if ``JAX_COMPILATION_CACHE_DIR`` is set, else None (cold
+    compiles, nothing written)."""
+    if _active_cache is not None:
+        return _active_cache
+    if not os.environ.get(ENV_CACHE_DIR):
+        return None
+    return enable_compile_cache()

@@ -342,6 +342,7 @@ class GenerationEngine:
         self.prefix_cache = resolve_prefix_store(prefix_cache, model=name)
         self._graft_fns: Dict[int, Any] = {}
         self.warmed = False
+        self.warm_stats: Dict[str, Dict[str, float]] = {}  # last warm()
         self.compiles_total = 0
         self.compiles_after_warm = 0
         # scheduler state
@@ -630,6 +631,7 @@ class GenerationEngine:
                 stats["graft"][str(p)] = round(time.monotonic() - t0, 4)
                 note(f"graft:{p}", stats["graft"][str(p)])
         self.warmed = True
+        self.warm_stats = stats
         record_event("generation.warmup", model=self.name,
                      programs=self.compiles_total,
                      seconds=round(time.monotonic() - t_all, 3))

@@ -270,7 +270,7 @@ class ModelServer:
         # those shapes before /readyz flips; the persistent compile
         # cache (integrity-verified, quarantining) makes each of those
         # compiles a disk read on restart. Both default from env
-        # (DL4J_TPU_WARMUP_MANIFEST / DL4J_TPU_COMPILE_CACHE_DIR; the
+        # (DL4J_TPU_WARMUP_MANIFEST / JAX_COMPILATION_CACHE_DIR; the
         # elastic supervisor arms them per generation); pass False to
         # disable explicitly, a path or instance to configure directly.
         self.warm_manifest = _warmstart.resolve_warmup_manifest(
@@ -1735,7 +1735,7 @@ class ModelServer:
         if self.compile_cache is None:
             if not self._compile_cache_disabled:
                 # fall back to the env-armed process cache (the
-                # supervisor sets DL4J_TPU_COMPILE_CACHE_DIR for worker
+                # supervisor sets JAX_COMPILATION_CACHE_DIR for worker
                 # generations); compile_cache=False opted out
                 # explicitly and stays out
                 self.compile_cache = \

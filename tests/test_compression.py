@@ -91,42 +91,3 @@ class TestBitmapCodec:
         g = _grad(shape=(64,))
         packed, res = jax.jit(lambda g: bitmap_encode(g, 0.3))(g)
         assert packed.shape == (4,)
-
-
-class TestPallasBitmapKernel:
-    """Fused Pallas bitmap encode (kernels/bitmap_pack.py) vs the XLA
-    codec — bit-identical packing, shared decode."""
-
-    def test_parity_with_xla_codec(self):
-        import numpy as np
-
-        from deeplearning4j_tpu.kernels.bitmap_pack import bitmap_encode
-        from deeplearning4j_tpu.ops import compression as C
-
-        rng = np.random.default_rng(0)
-        for n in (16, 100, 2048, 5000):
-            g = jnp.asarray(rng.normal(scale=0.02, size=(n,)), jnp.float32)
-            pk, rk = bitmap_encode(g, 0.02, backend="pallas")
-            px, rx = C.bitmap_encode(g, 0.02)
-            np.testing.assert_array_equal(np.asarray(pk), np.asarray(px))
-            np.testing.assert_allclose(np.asarray(rk), np.asarray(rx),
-                                       atol=1e-7)
-            # decode is shared and round-trips
-            dec = C.bitmap_decode(pk, 0.02, g.shape)
-            np.testing.assert_allclose(
-                np.asarray(dec + rk), np.asarray(g), atol=1e-6)
-
-    def test_2d_and_auto_backend(self):
-        import numpy as np
-
-        from deeplearning4j_tpu.kernels.bitmap_pack import bitmap_encode
-        from deeplearning4j_tpu.ops import compression as C
-
-        rng = np.random.default_rng(1)
-        g = jnp.asarray(rng.normal(scale=0.05, size=(37, 53)), jnp.float32)
-        pk, rk = bitmap_encode(g, 0.05, backend="pallas")
-        assert rk.shape == g.shape
-        px, _ = C.bitmap_encode(g, 0.05)
-        np.testing.assert_array_equal(np.asarray(pk), np.asarray(px))
-        pa, _ = bitmap_encode(g, 0.05, backend="auto")  # xla off-TPU
-        np.testing.assert_array_equal(np.asarray(pa), np.asarray(px))

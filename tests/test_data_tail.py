@@ -1,4 +1,4 @@
-"""Tests for the data/RL tail readers (VERDICT r3 next-round #7):
+"""Tests for the data/RL tail readers:
 Arrow IPC reader (pyarrow-written files decoded by the dependency-free
 reader), GeoJSON point reader + coordinate transforms, and the ALE-style
 frame-stack connector."""

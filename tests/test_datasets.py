@@ -1,4 +1,4 @@
-"""Dataset fetcher tests (VERDICT r2 Missing #5).
+"""Dataset fetcher tests.
 
 ref strategy: the reference's iterator tests assert shapes/classes/label
 encoding per fetcher. Synthetic-fallback loaders must additionally be

@@ -1,4 +1,4 @@
-"""Early stopping tests (VERDICT r2 Weak #3 / round-1 task #5 bar).
+"""Early stopping tests.
 
 ref strategy: deeplearning4j-core TestEarlyStopping — terminate on score
 plateau with patience, best-checkpoint retention, invalid-score and

@@ -1,6 +1,6 @@
 """Multi-process checkpoint round-trip across a TOPOLOGY CHANGE.
 
-VERDICT r3 next-round #6 / SURVEY §5.3-§5.4: the recovery story is
+SURVEY §5.3-§5.4: the recovery story is
 topology-independent restore — a job checkpointed on one mesh shape must
 restore bitwise onto a different mesh and keep training. The in-process
 tests pin this on one process; here it crosses real process boundaries:

@@ -1,4 +1,4 @@
-"""NaN-guard + crash-report tests (VERDICT r2 Missing #7/#8, task #10).
+"""NaN-guard + crash-report tests.
 
 ref strategy: Nd4j checkForNAN tests (inject a NaN, expect an exception
 naming the operation) and CrashReportingUtil tests (dump file exists and

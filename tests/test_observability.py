@@ -1,4 +1,4 @@
-"""Profiler + TensorBoard writer tests (VERDICT r2 Missing #1/#3).
+"""Profiler + TensorBoard writer tests.
 
 Oracles: event files are read back with REAL TensorFlow's summary_iterator
 (independent reader — our writer can't be self-consistently wrong), and the

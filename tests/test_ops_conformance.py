@@ -1,4 +1,4 @@
-"""Op-catalog conformance matrix (VERDICT r2 Weak #5 / round-1 task #6).
+"""Op-catalog conformance matrix.
 
 ref strategy: nd4j OpValidationSuite — every op in the public catalog gets a
 golden test against an fp64 numpy oracle, swept across dtypes. The catalog

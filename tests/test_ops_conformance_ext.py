@@ -1,4 +1,4 @@
-"""Conformance matrices for the non-math op namespaces (VERDICT r3 #5).
+"""Conformance matrices for the non-math op namespaces.
 
 Extends the ops/math.py pattern (tests/test_ops_conformance.py) over
 ops/nn.py, ops/cnn.py, ops/rnn.py, ops/loss.py and ops/random.py: every

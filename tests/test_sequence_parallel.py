@@ -112,7 +112,9 @@ class TestUlyssesAttention:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-6)
 
-    def test_flash_local_path(self, seq_mesh):
+    def test_flash_local_path(self, seq_mesh, monkeypatch):
+        # the kernel itself (interpret mode), inside ulysses' shard_map
+        monkeypatch.setenv("DL4J_TPU_FORCE_PALLAS", "1")
         q, k, v = _qkv(11)
         want = reference_attention(q, k, v)
         got = ulysses_attention(q, k, v, mesh=seq_mesh, use_flash=True)

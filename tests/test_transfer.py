@@ -1,4 +1,4 @@
-"""Transfer learning tests (VERDICT r2 Weak #3 / round-1 task #5 bar).
+"""Transfer learning tests.
 
 ref strategy: deeplearning4j-core TransferLearning*Test — surgery on a
 trained net, frozen-prefix fine-tune, weight carry-over, nOutReplace.

@@ -1,4 +1,4 @@
-"""Training UI server tests (SURVEY §2.7 Training UI; VERDICT r2 Missing #3).
+"""Training UI server tests (SURVEY §2.7 Training UI).
 
 The server must list runs, serve scalar series parsed from BOTH storage
 formats the listeners write (JSONL and TB event files), and render the

@@ -15,6 +15,7 @@ FleetRouter with the test_router spawn idiom.
 
 import json
 import os
+import pathlib
 import signal
 import socket
 import subprocess
@@ -233,15 +234,79 @@ class TestCompileCacheIntegrity:
 
     def test_maybe_enable_from_env_is_idempotent(self, tmp_path,
                                                  monkeypatch):
-        monkeypatch.setenv(cc.ENV_COMPILE_CACHE_DIR,
-                           str(tmp_path / "envcc"))
+        monkeypatch.setenv(cc.ENV_CACHE_DIR, str(tmp_path / "envcc"))
         c1 = cc.maybe_enable_compile_cache()
         c2 = cc.maybe_enable_compile_cache()
         assert c1 is c2 and c1.active
+        assert c1.directory == tmp_path / "envcc"
         assert _wm().cache_active.value() == 1.0
-        monkeypatch.delenv(cc.ENV_COMPILE_CACHE_DIR)
+        monkeypatch.delenv(cc.ENV_CACHE_DIR)
         cc.set_compile_cache(None)
+        # the implicit arm (Trainer.fit / ModelServer.start) stays off
+        # without the variable: no write-everything cache by default
         assert cc.maybe_enable_compile_cache() is None
+
+
+class TestCacheDirResolver:
+    """One resolver decides where the cache lives
+    (runtime/compilecache.resolve_cache_dir)."""
+
+    def test_variable_wins_else_the_checkout(self, tmp_path, monkeypatch):
+        import tempfile
+
+        monkeypatch.setenv(cc.ENV_CACHE_DIR, str(tmp_path / "placed"))
+        assert cc.resolve_cache_dir() == tmp_path / "placed"
+        assert cc.CompileCache().directory == tmp_path / "placed"
+        monkeypatch.delenv(cc.ENV_CACHE_DIR)
+        checkout = pathlib.Path(cc.__file__).resolve().parents[2]
+        assert cc.resolve_cache_dir() == checkout / ".jax_cache"
+        assert cc.CompileCache().directory == checkout / ".jax_cache"
+        # a fixed place: never a temporary name (the path is part of
+        # jax's cache key, a directory that moves never hits)
+        assert not cc.resolve_cache_dir().is_relative_to(
+            tempfile.gettempdir())
+        assert cc.resolve_cache_dir() == cc.resolve_cache_dir()
+
+    def test_variable_set_and_our_code_never_writes_the_dir(
+            self, tmp_path, monkeypatch):
+        """With JAX_COMPILATION_CACHE_DIR set jax holds the directory
+        itself; no path through our code points it anywhere."""
+        import jax
+
+        monkeypatch.setenv(cc.ENV_CACHE_DIR, str(tmp_path / "placed"))
+        writes = []
+        real_update = jax.config.update
+
+        def spy(name, value):
+            writes.append(name)
+            return real_update(name, value)
+
+        monkeypatch.setattr(jax.config, "update", spy)
+        cache = cc.enable_compile_cache()
+        assert cache.active and cache.directory == tmp_path / "placed"
+        cache.seal()
+        assert (tmp_path / "placed" / "cache_manifest.json").is_file()
+        assert "jax_compilation_cache_dir" not in writes
+        # the integrity layer works in place; another directory is refused
+        with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+            cc.CompileCache(tmp_path / "elsewhere").activate()
+        assert "jax_compilation_cache_dir" not in writes
+
+    def test_supervisor_keeps_a_directory_the_environment_names(
+            self, tmp_path):
+        from deeplearning4j_tpu.resilience.supervisor import (
+            ElasticSupervisor,
+        )
+
+        dump = "import os; print(os.environ['JAX_COMPILATION_CACHE_DIR'])"
+        env = dict(os.environ,
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "placed"))
+        sup = ElasticSupervisor(
+            [sys.executable, "-c", dump], num_workers=1, workdir=tmp_path,
+            max_restarts=0, env=env, compile_cache_dir=tmp_path / "cc")
+        sup.run()
+        assert sup.worker_log(0).read_text().strip() == str(
+            tmp_path / "placed")
 
 
 # ---------------------------------------------------------------------------
@@ -537,7 +602,7 @@ class TestSupervisorArming:
         )
 
         dump = ("import os, json; print(json.dumps({k: v for k, v in "
-                "os.environ.items() if 'COMPILE_CACHE' in k or "
+                "os.environ.items() if 'COMPILATION_CACHE' in k or "
                 "'WARMUP_MANIFEST' in k}))")
         sup = ElasticSupervisor(
             [sys.executable, "-c", dump], num_workers=1,
@@ -546,7 +611,7 @@ class TestSupervisorArming:
             warmup_manifest=tmp_path / "wm.json")
         sup.run()
         env = json.loads(sup.worker_log(0).read_text().strip())
-        assert env["DL4J_TPU_COMPILE_CACHE_DIR"] == str(tmp_path / "cc")
+        assert env["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path / "cc")
         assert env["DL4J_TPU_WARMUP_MANIFEST"] == str(
             tmp_path / "wm.json")
         assert (tmp_path / "cc").is_dir()  # pre-created for the worker
@@ -557,10 +622,11 @@ class TestSupervisorArming:
         )
 
         dump = ("import os, json; print(json.dumps([k for k in "
-                "os.environ if 'COMPILE_CACHE' in k or "
+                "os.environ if 'COMPILATION_CACHE' in k or "
                 "'WARMUP_MANIFEST' in k]))")
         env = {k: v for k, v in os.environ.items()
-               if "COMPILE_CACHE" not in k and "WARMUP_MANIFEST" not in k}
+               if "COMPILATION_CACHE" not in k
+               and "WARMUP_MANIFEST" not in k}
         sup = ElasticSupervisor([sys.executable, "-c", dump],
                                 num_workers=1, workdir=tmp_path,
                                 max_restarts=0, env=env)
@@ -613,7 +679,7 @@ def _free_port():
 def _spawn_backend(port, scale, version, *, cache_dir, manifest,
                    faults=None):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               DL4J_TPU_COMPILE_CACHE_DIR=str(cache_dir),
+               JAX_COMPILATION_CACHE_DIR=str(cache_dir),
                DL4J_TPU_WARMUP_MANIFEST=str(manifest))
     if faults:
         env["DL4J_TPU_FAULTS"] = faults

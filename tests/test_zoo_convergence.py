@@ -1,6 +1,6 @@
 """Zoo convergence sanity: every zoo entry must overfit 10 samples
-(VERDICT r2 Weak #9; SURVEY §4 pattern 5 — a model that cannot memorize a
-tiny batch is broken regardless of its shapes).
+(SURVEY §4 pattern 5 — a model that cannot memorize a tiny batch is
+broken regardless of its shapes).
 
 Models run at reduced input resolution (the configs are parametric) so the
 whole suite stays CPU-feasible; architecture — blocks, skips, BN, pooling,
