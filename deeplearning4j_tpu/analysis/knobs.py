@@ -70,8 +70,6 @@ KNOBS: Tuple[Knob, ...] = (
     Knob("DL4J_TPU_FORCE_PALLAS", "flag", "0", "kernels",
          "Force Pallas kernels regardless of per-shape dispatch."),
     # -- training -------------------------------------------------------------
-    Knob("DL4J_TPU_PEAK_FLOPS", "float", "0 (unset)", "train",
-         "Per-chip peak FLOP/s; arms analytic MFU in train metrics."),
     Knob("DL4J_TPU_STEP_COST_ANALYSIS", "flag", "1", "train",
          "Background XLA cost analysis of the train step; set 0 to "
          "skip the second compile."),

@@ -266,6 +266,7 @@ def _flash_fwd(q, k, v, key_mask, *, causal, scale, block_q, block_k,
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=_interpret(),
+        name="flash_fwd",
     )(qp, kp, vp, km)
     out, lse = res if save_lse else (res, None)
     return out[:, :t, :d].reshape(b, h, t, d), lse
@@ -421,6 +422,7 @@ def _flash_bwd_impl(q, k, v, key_mask, out, lse, g, *, causal, scale,
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(qp, kp, vp, km, gp, lse, delta)
 
     km_index_qk = (lambda bh, qi, ki: (bh, 0, ki)) if has_mask else (
@@ -443,6 +445,7 @@ def _flash_bwd_impl(q, k, v, key_mask, out, lse, g, *, causal, scale,
         scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(qp, kp, vp, km, gp, lse, delta)
 
     dq = dq[:, :t, :d].reshape(b, h, t, d)

@@ -135,6 +135,7 @@ def _gru_pallas_fwd(x_proj_tm, rw, b, h0, save_workspace=False):
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=_interpret(),
+        name="gru_scan_fwd",
     )(
         x_proj_tm,
         rw.astype(jnp.float32),
@@ -220,6 +221,7 @@ def _gru_pallas_bwd(gates_tm, hpn_tm, h_prev_tm, gh_tm, rw):
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=_interpret(),
+        name="gru_scan_bwd",
     )(gates_tm, hpn_tm, h_prev_tm, gh_tm, rw.astype(jnp.float32))
 
 

@@ -167,6 +167,7 @@ def _lstm_pallas_fwd(x_proj_tm, rw, b, h0, c0, peepholes, forget_bias,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=_interpret(),
+        name="lstm_scan_fwd",
     )(
         x_proj_tm,
         rw.astype(jnp.float32),
@@ -288,6 +289,7 @@ def _lstm_pallas_bwd(gates_tm, cs_tm, c_prev_tm, gh_tm, gcT, rw, peepholes):
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=_interpret(),
+        name="lstm_scan_bwd",
     )(
         gates_tm,
         cs_tm,

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn.activations import get_activation
 from deeplearning4j_tpu.nn.config import NeuralNetConfiguration, register_config
 from deeplearning4j_tpu.nn.layers.attention import TransformerEncoderBlock
+from deeplearning4j_tpu.observability.vocab import SCOPE_EMBED, SCOPE_HEAD
 from deeplearning4j_tpu.ops import loss as losses
 from deeplearning4j_tpu.ops import nn as opsnn
 from deeplearning4j_tpu.train.updaters import Adam
@@ -144,13 +145,15 @@ class Bert:
         mask = features.get("mask")
         t = ids.shape[1]
         emb = params["embeddings"]
-        x = opsnn.embedding_lookup(emb["word"], ids)
-        x = x + emb["position"][:t][None, :, :]
-        if seg is not None:
-            x = x + opsnn.embedding_lookup(emb["type"], seg)
-        x = opsnn.layer_norm(x, emb["ln_gamma"], emb["ln_beta"], eps=c.eps)
-        if train and c.dropout > 0.0 and rng is not None:
-            x = opsnn.dropout(x, c.dropout, jax.random.fold_in(rng, 999))
+        with jax.named_scope(SCOPE_EMBED):
+            x = opsnn.embedding_lookup(emb["word"], ids)
+            x = x + emb["position"][:t][None, :, :]
+            if seg is not None:
+                x = x + opsnn.embedding_lookup(emb["type"], seg)
+            x = opsnn.layer_norm(x, emb["ln_gamma"], emb["ln_beta"],
+                                 eps=c.eps)
+            if train and c.dropout > 0.0 and rng is not None:
+                x = opsnn.dropout(x, c.dropout, jax.random.fold_in(rng, 999))
         for i in range(c.num_layers):
             lrng = jax.random.fold_in(rng, i) if rng is not None else None
             x, _ = self._block.apply(
@@ -180,11 +183,15 @@ class Bert:
         return opsnn.linear(pooled, params["nsp"]["W"], params["nsp"]["b"])
 
     def loss_fn(self, params, state, batch, rng=None):
-        c = self.config
         features = batch["features"]
         labels = batch["labels"]
         hidden = self.encode(params, features, train=True, rng=rng)
+        # both heads and the losses over them are one component scope
+        with jax.named_scope(SCOPE_HEAD):
+            return self._heads_loss(params, state, hidden, labels)
 
+    def _heads_loss(self, params, state, hidden, labels):
+        c = self.config
         if "mlm_positions" in labels:
             # Gathered head: decoder GEMM over the P masked slots only.
             pos = labels["mlm_positions"]  # [N,P] int32

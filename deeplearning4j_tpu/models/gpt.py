@@ -31,6 +31,12 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn.activations import get_activation
 from deeplearning4j_tpu.nn.config import NeuralNetConfiguration, register_config
 from deeplearning4j_tpu.nn.layers.attention import TransformerEncoderBlock
+from deeplearning4j_tpu.observability.vocab import (
+    SCOPE_ATTN,
+    SCOPE_EMBED,
+    SCOPE_HEAD,
+    SCOPE_MLP,
+)
 from deeplearning4j_tpu.ops import loss as losses
 from deeplearning4j_tpu.ops import nn as opsnn
 from deeplearning4j_tpu.train.updaters import Adam
@@ -121,21 +127,25 @@ class Gpt:
         c = self.config
         t = ids.shape[1]
         emb = params["embeddings"]
-        x = opsnn.embedding_lookup(emb["word"], ids)
-        x = x + emb["position"][:t][None, :, :]
-        if train and c.dropout > 0.0 and rng is not None:
-            x = opsnn.dropout(x, c.dropout, jax.random.fold_in(rng, 999))
+        with jax.named_scope(SCOPE_EMBED):
+            x = opsnn.embedding_lookup(emb["word"], ids)
+            x = x + emb["position"][:t][None, :, :]
+            if train and c.dropout > 0.0 and rng is not None:
+                x = opsnn.dropout(x, c.dropout, jax.random.fold_in(rng, 999))
         for i in range(c.num_layers):
             lrng = jax.random.fold_in(rng, i) if rng is not None else None
             x, _ = self._block.apply(params[f"layer_{i}"], {}, x,
                                      train=train, rng=lrng, mask=mask)
         f = params["final"]
-        return opsnn.layer_norm(x, f["ln_gamma"], f["ln_beta"], eps=c.eps)
+        with jax.named_scope(SCOPE_HEAD):
+            return opsnn.layer_norm(x, f["ln_gamma"], f["ln_beta"],
+                                    eps=c.eps)
 
     def logits(self, params, hidden):
-        return (jnp.einsum("nth,vh->ntv", hidden,
-                           params["embeddings"]["word"])
-                + params["final"]["out_b"])
+        with jax.named_scope(SCOPE_HEAD):
+            return (jnp.einsum("nth,vh->ntv", hidden,
+                               params["embeddings"]["word"])
+                    + params["final"]["out_b"])
 
     def apply(self, variables, features, *, train=False, rng=None):
         """Returns (logits [N,T,V], state)."""
@@ -158,15 +168,16 @@ class Gpt:
         ids = features["token_ids"]
         mask = features.get("mask")
         h = self.encode(params, ids, train=True, rng=rng, mask=mask)
-        lg = self.logits(params, h)[:, :-1]
-        labels = batch.get("labels")
-        if labels is None:
-            labels = ids[:, 1:]
-        w = (jnp.ones(labels.shape, jnp.float32) if mask is None
-             else mask[:, 1:].astype(jnp.float32))
-        per_tok = losses.sparse_softmax_cross_entropy(lg, labels,
-                                                      reduction="none")
-        loss = jnp.sum(per_tok * w) / jnp.maximum(jnp.sum(w), 1.0)
+        with jax.named_scope(SCOPE_HEAD):
+            lg = self.logits(params, h)[:, :-1]
+            labels = batch.get("labels")
+            if labels is None:
+                labels = ids[:, 1:]
+            w = (jnp.ones(labels.shape, jnp.float32) if mask is None
+                 else mask[:, 1:].astype(jnp.float32))
+            per_tok = losses.sparse_softmax_cross_entropy(lg, labels,
+                                                          reduction="none")
+            loss = jnp.sum(per_tok * w) / jnp.maximum(jnp.sum(w), 1.0)
         return loss, (state, {"loss": loss})
 
     def loss_weight(self, batch):
@@ -219,47 +230,53 @@ class Gpt:
                                     p[f"{which}_beta"], eps=eps)
 
         ap = p["attention"]
-        a_in = ln(x_t, "ln1")  # [N,H]
-        n, e = a_in.shape
-        hd = e // h
+        with jax.named_scope(SCOPE_ATTN):
+            a_in = ln(x_t, "ln1")  # [N,H]
+            n, e = a_in.shape
+            hd = e // h
 
-        def heads(z):
-            return z.reshape(n, h, 1, hd)  # [N,h,1,hd] from [N, h*hd]
+            def heads(z):
+                return z.reshape(n, h, 1, hd)  # [N,h,1,hd] from [N, h*hd]
 
-        q = heads(opsnn.linear(a_in, ap["Wq"], ap.get("bq")))
-        k = heads(opsnn.linear(a_in, ap["Wk"], ap.get("bk")))
-        v = heads(opsnn.linear(a_in, ap["Wv"], ap.get("bv")))
-        kc = jax.lax.dynamic_update_slice(cache["k"], k, (0, 0, pos, 0))
-        vc = jax.lax.dynamic_update_slice(cache["v"], v, (0, 0, pos, 0))
-        scores = jnp.einsum("nhqd,nhld->nhql", q, kc) / jnp.sqrt(
-            jnp.asarray(hd, q.dtype))
-        # causal-by-construction: only slots <= pos are live
-        live = (jnp.arange(kc.shape[2]) <= pos)[None, None, None, :]
-        scores = jnp.where(live, scores, jnp.finfo(scores.dtype).min)
-        att = jax.nn.softmax(scores, axis=-1)
-        y = jnp.einsum("nhql,nhld->nhqd", att, vc).reshape(n, e)
-        a = opsnn.linear(y, ap["Wo"], ap.get("bo"))
-        x = x_t + a
-        f_in = ln(x, "ln2")
-        f = opsnn.linear(f_in, p["W1"], p["b1"])
-        f = get_activation(c.activation)(f)
-        f = opsnn.linear(f, p["W2"], p["b2"])
-        return x + f, {"k": kc, "v": vc}
+            q = heads(opsnn.linear(a_in, ap["Wq"], ap.get("bq")))
+            k = heads(opsnn.linear(a_in, ap["Wk"], ap.get("bk")))
+            v = heads(opsnn.linear(a_in, ap["Wv"], ap.get("bv")))
+            kc = jax.lax.dynamic_update_slice(cache["k"], k, (0, 0, pos, 0))
+            vc = jax.lax.dynamic_update_slice(cache["v"], v, (0, 0, pos, 0))
+            scores = jnp.einsum("nhqd,nhld->nhql", q, kc) / jnp.sqrt(
+                jnp.asarray(hd, q.dtype))
+            # causal-by-construction: only slots <= pos are live
+            live = (jnp.arange(kc.shape[2]) <= pos)[None, None, None, :]
+            scores = jnp.where(live, scores, jnp.finfo(scores.dtype).min)
+            att = jax.nn.softmax(scores, axis=-1)
+            y = jnp.einsum("nhql,nhld->nhqd", att, vc).reshape(n, e)
+            a = opsnn.linear(y, ap["Wo"], ap.get("bo"))
+            x = x_t + a
+        with jax.named_scope(SCOPE_MLP):
+            f_in = ln(x, "ln2")
+            f = opsnn.linear(f_in, p["W1"], p["b1"])
+            f = get_activation(c.activation)(f)
+            f = opsnn.linear(f, p["W2"], p["b2"])
+            return x + f, {"k": kc, "v": vc}
 
     def decode_step(self, params, caches, ids_t, pos):
         """One decode step: ids_t [N] int32 at position pos → (logits [N,V],
         updated caches)."""
         c = self.config
         emb = params["embeddings"]
-        x = opsnn.embedding_lookup(emb["word"], ids_t)  # [N,H]
-        x = x + jax.lax.dynamic_slice_in_dim(emb["position"], pos, 1, 0)[0]
+        with jax.named_scope(SCOPE_EMBED):
+            x = opsnn.embedding_lookup(emb["word"], ids_t)  # [N,H]
+            x = x + jax.lax.dynamic_slice_in_dim(
+                emb["position"], pos, 1, 0)[0]
         new_caches = []
         for i in range(c.num_layers):
             x, cc = self._block_step(params[f"layer_{i}"], caches[i], x, pos)
             new_caches.append(cc)
         f = params["final"]
-        hfin = opsnn.layer_norm(x, f["ln_gamma"], f["ln_beta"], eps=c.eps)
-        lg = hfin @ params["embeddings"]["word"].T + f["out_b"]
+        with jax.named_scope(SCOPE_HEAD):
+            hfin = opsnn.layer_norm(x, f["ln_gamma"], f["ln_beta"],
+                                    eps=c.eps)
+            lg = hfin @ params["embeddings"]["word"].T + f["out_b"]
         return lg, new_caches
 
     # -- continuous-batching decode (serving/generation.py) ----------------
@@ -281,34 +298,37 @@ class Gpt:
                                     p[f"{which}_beta"], eps=eps)
 
         ap = p["attention"]
-        a_in = ln(x_t, "ln1")  # [N,H]
-        n, e = a_in.shape
-        hd = e // h
+        with jax.named_scope(SCOPE_ATTN):
+            a_in = ln(x_t, "ln1")  # [N,H]
+            n, e = a_in.shape
+            hd = e // h
 
-        def heads(z):
-            return z.reshape(n, h, hd)  # [N,h,hd] from [N, h*hd]
+            def heads(z):
+                return z.reshape(n, h, hd)  # [N,h,hd] from [N, h*hd]
 
-        q = heads(opsnn.linear(a_in, ap["Wq"], ap.get("bq")))
-        k = heads(opsnn.linear(a_in, ap["Wk"], ap.get("bk")))
-        v = heads(opsnn.linear(a_in, ap["Wv"], ap.get("bv")))
-        rows = jnp.arange(n)
-        # per-row scatter: row i's new K/V lands at its own pos[i]
-        kc = cache["k"].at[rows, :, pos, :].set(k)
-        vc = cache["v"].at[rows, :, pos, :].set(v)
-        scores = jnp.einsum("nhd,nhld->nhl", q, kc) / jnp.sqrt(
-            jnp.asarray(hd, q.dtype))
-        # causal-by-construction, per row: only slots <= pos[i] are live
-        live = jnp.arange(kc.shape[2])[None, None, :] <= pos[:, None, None]
-        scores = jnp.where(live, scores, jnp.finfo(scores.dtype).min)
-        att = jax.nn.softmax(scores, axis=-1)
-        y = jnp.einsum("nhl,nhld->nhd", att, vc).reshape(n, e)
-        a = opsnn.linear(y, ap["Wo"], ap.get("bo"))
-        x = x_t + a
-        f_in = ln(x, "ln2")
-        f = opsnn.linear(f_in, p["W1"], p["b1"])
-        f = get_activation(c.activation)(f)
-        f = opsnn.linear(f, p["W2"], p["b2"])
-        return x + f, {"k": kc, "v": vc}
+            q = heads(opsnn.linear(a_in, ap["Wq"], ap.get("bq")))
+            k = heads(opsnn.linear(a_in, ap["Wk"], ap.get("bk")))
+            v = heads(opsnn.linear(a_in, ap["Wv"], ap.get("bv")))
+            rows = jnp.arange(n)
+            # per-row scatter: row i's new K/V lands at its own pos[i]
+            kc = cache["k"].at[rows, :, pos, :].set(k)
+            vc = cache["v"].at[rows, :, pos, :].set(v)
+            scores = jnp.einsum("nhd,nhld->nhl", q, kc) / jnp.sqrt(
+                jnp.asarray(hd, q.dtype))
+            # causal-by-construction, per row: only slots <= pos[i] are live
+            live = (jnp.arange(kc.shape[2])[None, None, :]
+                    <= pos[:, None, None])
+            scores = jnp.where(live, scores, jnp.finfo(scores.dtype).min)
+            att = jax.nn.softmax(scores, axis=-1)
+            y = jnp.einsum("nhl,nhld->nhd", att, vc).reshape(n, e)
+            a = opsnn.linear(y, ap["Wo"], ap.get("bo"))
+            x = x_t + a
+        with jax.named_scope(SCOPE_MLP):
+            f_in = ln(x, "ln2")
+            f = opsnn.linear(f_in, p["W1"], p["b1"])
+            f = get_activation(c.activation)(f)
+            f = opsnn.linear(f, p["W2"], p["b2"])
+            return x + f, {"k": kc, "v": vc}
 
     def decode_step_slots(self, params, caches, ids_t, pos):
         """One iteration-level decode step over independent sequences:
@@ -318,16 +338,19 @@ class Gpt:
         core primitive of the continuous-batching serving engine."""
         c = self.config
         emb = params["embeddings"]
-        x = opsnn.embedding_lookup(emb["word"], ids_t)  # [N,H]
-        x = x + emb["position"][pos]                    # per-row gather
+        with jax.named_scope(SCOPE_EMBED):
+            x = opsnn.embedding_lookup(emb["word"], ids_t)  # [N,H]
+            x = x + emb["position"][pos]                # per-row gather
         new_caches = []
         for i in range(c.num_layers):
             x, cc = self._block_step_slots(params[f"layer_{i}"], caches[i],
                                            x, pos)
             new_caches.append(cc)
         f = params["final"]
-        hfin = opsnn.layer_norm(x, f["ln_gamma"], f["ln_beta"], eps=c.eps)
-        lg = hfin @ params["embeddings"]["word"].T + f["out_b"]
+        with jax.named_scope(SCOPE_HEAD):
+            hfin = opsnn.layer_norm(x, f["ln_gamma"], f["ln_beta"],
+                                    eps=c.eps)
+            lg = hfin @ params["embeddings"]["word"].T + f["out_b"]
         return lg, new_caches
 
     def prefill_chunk(self, params, ids):
@@ -343,8 +366,9 @@ class Gpt:
         h = c.num_heads
         emb = params["embeddings"]
         n, pl = ids.shape
-        x = opsnn.embedding_lookup(emb["word"], ids)
-        x = x + emb["position"][:pl][None, :, :]
+        with jax.named_scope(SCOPE_EMBED):
+            x = opsnn.embedding_lookup(emb["word"], ids)
+            x = x + emb["position"][:pl][None, :, :]
         causal = jnp.tril(jnp.ones((pl, pl), bool))[None, None]
         kvs = []
         for i in range(c.num_layers):
@@ -355,36 +379,39 @@ class Gpt:
                                         p[f"{which}_beta"], eps=c.eps)
 
             ap = p["attention"]
-            a_in = ln(x, "ln1")                      # [N,P,E]
-            e = a_in.shape[-1]
-            hd = e // h
+            with jax.named_scope(SCOPE_ATTN):
+                a_in = ln(x, "ln1")                      # [N,P,E]
+                e = a_in.shape[-1]
+                hd = e // h
 
-            def heads(z):
-                # [N,P,h*hd] -> [N,h,P,hd]; feature layout head-major,
-                # matching _block_step's reshape(n, h, 1, hd)
-                return z.reshape(n, pl, h, hd).transpose(0, 2, 1, 3)
+                def heads(z):
+                    # [N,P,h*hd] -> [N,h,P,hd]; feature layout head-major,
+                    # matching _block_step's reshape(n, h, 1, hd)
+                    return z.reshape(n, pl, h, hd).transpose(0, 2, 1, 3)
 
-            q = heads(opsnn.linear(a_in, ap["Wq"], ap.get("bq")))
-            k = heads(opsnn.linear(a_in, ap["Wk"], ap.get("bk")))
-            v = heads(opsnn.linear(a_in, ap["Wv"], ap.get("bv")))
-            scores = jnp.einsum("nhqd,nhkd->nhqk", q, k) / jnp.sqrt(
-                jnp.asarray(hd, q.dtype))
-            scores = jnp.where(causal, scores,
-                               jnp.finfo(scores.dtype).min)
-            att = jax.nn.softmax(scores, axis=-1)
-            y = jnp.einsum("nhqk,nhkd->nhqd", att, v)
-            y = y.transpose(0, 2, 1, 3).reshape(n, pl, e)
-            x = x + opsnn.linear(y, ap["Wo"], ap.get("bo"))
-            f_in = ln(x, "ln2")
-            f = opsnn.linear(f_in, p["W1"], p["b1"])
-            f = get_activation(c.activation)(f)
-            x = x + opsnn.linear(f, p["W2"], p["b2"])
+                q = heads(opsnn.linear(a_in, ap["Wq"], ap.get("bq")))
+                k = heads(opsnn.linear(a_in, ap["Wk"], ap.get("bk")))
+                v = heads(opsnn.linear(a_in, ap["Wv"], ap.get("bv")))
+                scores = jnp.einsum("nhqd,nhkd->nhqk", q, k) / jnp.sqrt(
+                    jnp.asarray(hd, q.dtype))
+                scores = jnp.where(causal, scores,
+                                   jnp.finfo(scores.dtype).min)
+                att = jax.nn.softmax(scores, axis=-1)
+                y = jnp.einsum("nhqk,nhkd->nhqd", att, v)
+                y = y.transpose(0, 2, 1, 3).reshape(n, pl, e)
+                x = x + opsnn.linear(y, ap["Wo"], ap.get("bo"))
+            with jax.named_scope(SCOPE_MLP):
+                f_in = ln(x, "ln2")
+                f = opsnn.linear(f_in, p["W1"], p["b1"])
+                f = get_activation(c.activation)(f)
+                x = x + opsnn.linear(f, p["W2"], p["b2"])
             kvs.append({"k": k, "v": v})
         fin = params["final"]
-        hfin = opsnn.layer_norm(x, fin["ln_gamma"], fin["ln_beta"],
-                                eps=c.eps)
-        lg = (jnp.einsum("nth,vh->ntv", hfin, emb["word"])
-              + fin["out_b"])
+        with jax.named_scope(SCOPE_HEAD):
+            hfin = opsnn.layer_norm(x, fin["ln_gamma"], fin["ln_beta"],
+                                    eps=c.eps)
+            lg = (jnp.einsum("nth,vh->ntv", hfin, emb["word"])
+                  + fin["out_b"])
         return lg, kvs
 
     def generate(self, variables, prime_ids, *, n_steps: int, rng,

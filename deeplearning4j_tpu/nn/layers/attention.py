@@ -26,6 +26,7 @@ from deeplearning4j_tpu.kernels.flash_attention import flash_attention
 from deeplearning4j_tpu.nn.activations import get_activation
 from deeplearning4j_tpu.nn.config import LayerConfig, register_config
 from deeplearning4j_tpu.nn.initializers import get_initializer
+from deeplearning4j_tpu.observability.vocab import SCOPE_ATTN, SCOPE_MLP
 from deeplearning4j_tpu.ops import nn as opsnn
 
 
@@ -438,30 +439,38 @@ class TransformerEncoderBlock(LayerConfig):
                 h, params[f"{which}_gamma"], params[f"{which}_beta"], eps=self.eps
             )
 
+        # each sub-layer, with its norm and its residual add, is one
+        # component scope of the profiler trace (observability/vocab.py)
         if self.post_ln:  # original-BERT residual order
-            a, _ = att.apply(params["attention"], {}, x, train=train, rng=r1, mask=mask)
+            with jax.named_scope(SCOPE_ATTN):
+                a, _ = att.apply(params["attention"], {}, x, train=train,
+                                 rng=r1, mask=mask)
+                if train and self.dropout > 0.0 and r2 is not None:
+                    a = opsnn.dropout(a, self.dropout, r2)
+                x = ln(x + a, "ln1")
+            with jax.named_scope(SCOPE_MLP):
+                f = opsnn.linear(x, params["W1"], params["b1"])
+                f = get_activation(self.activation)(f)
+                f = opsnn.linear(f, params["W2"], params["b2"])
+                if train and self.dropout > 0.0 and r3 is not None:
+                    f = opsnn.dropout(f, self.dropout, r3)
+                return ln(x + f, "ln2")
+        # pre-LN (more stable for deep stacks)
+        with jax.named_scope(SCOPE_ATTN):
+            a_in = ln(x, "ln1")
+            a, _ = att.apply(params["attention"], {}, a_in, train=train,
+                             rng=r1, mask=mask)
             if train and self.dropout > 0.0 and r2 is not None:
                 a = opsnn.dropout(a, self.dropout, r2)
-            x = ln(x + a, "ln1")
-            f = opsnn.linear(x, params["W1"], params["b1"])
+            x = x + a
+        with jax.named_scope(SCOPE_MLP):
+            f_in = ln(x, "ln2")
+            f = opsnn.linear(f_in, params["W1"], params["b1"])
             f = get_activation(self.activation)(f)
             f = opsnn.linear(f, params["W2"], params["b2"])
             if train and self.dropout > 0.0 and r3 is not None:
                 f = opsnn.dropout(f, self.dropout, r3)
-            return ln(x + f, "ln2")
-        # pre-LN (more stable for deep stacks)
-        a_in = ln(x, "ln1")
-        a, _ = att.apply(params["attention"], {}, a_in, train=train, rng=r1, mask=mask)
-        if train and self.dropout > 0.0 and r2 is not None:
-            a = opsnn.dropout(a, self.dropout, r2)
-        x = x + a
-        f_in = ln(x, "ln2")
-        f = opsnn.linear(f_in, params["W1"], params["b1"])
-        f = get_activation(self.activation)(f)
-        f = opsnn.linear(f, params["W2"], params["b2"])
-        if train and self.dropout > 0.0 and r3 is not None:
-            f = opsnn.dropout(f, self.dropout, r3)
-        return x + f
+            return x + f
 
 
 @register_config

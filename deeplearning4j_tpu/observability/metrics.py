@@ -472,13 +472,6 @@ class TrainingMetrics:
             "step_flops", "Analytic FLOPs of one compiled train step "
             "(XLA cost_analysis; computed once per batch shape in a "
             "background thread).", namespace=ns)
-        self.flops_per_second = r.gauge(
-            "flops_per_second", "Analytic model FLOP/s: step_flops over "
-            "the last measured host step wall-time.", namespace=ns)
-        self.analytic_mfu = r.gauge(
-            "analytic_mfu", "flops_per_second / peak chip FLOP/s; set "
-            "only when DL4J_TPU_PEAK_FLOPS declares the peak.",
-            namespace=ns)
         self.data_starved = r.gauge(
             "data_starved", "1 while data-read latency dominates step "
             "wall-time over the recent window (input pipeline is the "

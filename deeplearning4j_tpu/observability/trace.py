@@ -42,14 +42,25 @@ cost only the staging append. The serving request ledger
 (``observability/reqlog.py``) drives ``begin``/``finish`` for every
 request on both serving planes.
 
-Stdlib only; safe to import from any layer (the retention policy's
-rolling baseline is imported lazily from ``observability.sentinel``).
+**On the profiler's clock** (:func:`annotate`): the ring above is this
+module's own and a device trace never sees it. ``annotate(name)`` opens a
+``jax.profiler.TraceAnnotation`` instead, which a running profiler writes
+onto the host plane of its own trace, beside the device's operations and
+on their clock; ``span()`` opens one too, so every span recorded here is
+also there. While no profiler runs an annotation costs a fraction of a
+microsecond, and it is a no-op while ``jax`` has not been imported.
+
+Stdlib only at import; safe to import from any layer (the retention
+policy's rolling baseline is imported lazily from
+``observability.sentinel``, and ``annotate`` looks ``jax`` up in
+``sys.modules`` and never imports it).
 """
 
 from __future__ import annotations
 
 import itertools
 import json
+import sys
 import threading
 import time
 import uuid
@@ -401,6 +412,35 @@ def current_span() -> Optional[Span]:
     return st[-1] if st else None
 
 
+class _NoAnnotation:
+    """What :func:`annotate` returns while ``jax`` is not imported."""
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_ANNOTATION = _NoAnnotation()
+
+
+def annotate(name: str, **attrs):
+    """A context manager that puts ``name`` on the host plane of a
+    running ``jax.profiler`` trace, on the device's clock: a
+    ``TraceAnnotation`` (a ``StepTraceAnnotation`` when given
+    ``step_num``) if ``jax`` is already imported, a no-op otherwise. The
+    names in use are declared in ``observability/vocab.py``
+    (``HOST_SPANS``)."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return _NO_ANNOTATION
+    if "step_num" in attrs:
+        return profiler.StepTraceAnnotation(name, **attrs)
+    return profiler.TraceAnnotation(name, **attrs)
+
+
 @contextmanager
 def span(name: str, *, trace_id: Optional[str] = None,
          parent_id: Optional[str] = None, tracer: Optional[Tracer] = None,
@@ -410,6 +450,8 @@ def span(name: str, *, trace_id: Optional[str] = None,
     parent and shares its trace. Yields the live Span (attrs mutable)
     or None when tracing is disabled. An exception in the block is
     recorded as an ``error`` attr and re-raised; the span always closes.
+    The block is also an :func:`annotate` of the same name, so a running
+    profiler sees it on the host plane of its trace.
     """
     if not _ENABLED:
         yield None
@@ -424,7 +466,8 @@ def span(name: str, *, trace_id: Optional[str] = None,
              attrs=dict(attrs))
     _stack().append(s)
     try:
-        yield s
+        with annotate(name):
+            yield s
     except BaseException as e:
         s.attrs.setdefault("error", type(e).__name__)
         raise

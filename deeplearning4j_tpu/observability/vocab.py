@@ -17,6 +17,8 @@ does not care, but reviewers diff this file.
 
 from __future__ import annotations
 
+import re
+
 # serving data plane (server.py / registry.py / warmup.py)
 SERVING_KINDS = frozenset({
     "serving.admission_cap",
@@ -120,6 +122,7 @@ COMPILE_KINDS = frozenset({
     "compile_cache.activate",
     "compile_cache.quarantined",
     "compile_cache.sealed",
+    "compile_cache.stale_metadata",
 })
 
 # observability plane's own events (sentinel, SLO, profiling, recorder)
@@ -181,3 +184,58 @@ EVENT_KINDS = frozenset().union(
 def known_event_kinds() -> frozenset:
     """The full declared flight-event ``kind`` vocabulary."""
     return EVENT_KINDS
+
+
+# -- names a profiler trace carries -------------------------------------------
+#
+# Three kinds of name reach a ``jax.profiler`` trace, each by the mechanism
+# JAX already has. They are declared here once, used where the work is
+# written, and read by the benchmark's per-layer metrics (PERF.md section 3
+# has the table of which metric reads which name).
+
+# ``jax.named_scope`` on the device side: the component of the model step an
+# HLO instruction belongs to, found in its ``op_name`` metadata
+# (``jit(train_step)/transpose(jvp(attn))/dot_general`` -> ``attn``).
+SCOPE_EMBED = "embed"          # lookups, position/type add, embedding norm
+SCOPE_ATTN = "attn"            # attention sub-layer with its norm + residual
+SCOPE_MLP = "mlp"              # feed-forward sub-layer with its norm + residual
+SCOPE_HEAD = "head"            # final norm, logits, the loss over them
+SCOPE_OPTIMIZER = "optimizer"  # Trainer._finish_step + the master-weight cast
+COMPONENT_SCOPES = (SCOPE_EMBED, SCOPE_ATTN, SCOPE_MLP, SCOPE_HEAD,
+                    SCOPE_OPTIMIZER)
+
+# ``name=`` of each Pallas kernel: the custom call reads ``jvp(flash_fwd)``
+# where an unnamed one reads ``jvp()``.
+KERNEL_NAMES = frozenset({
+    "flash_fwd", "flash_bwd_dkv", "flash_bwd_dq",
+    "lstm_scan_fwd", "lstm_scan_bwd", "gru_scan_fwd", "gru_scan_bwd",
+})
+
+# ``observability.trace.annotate`` on the host side: spans on the profiler's
+# own clock (the host plane's ``python`` line), beside the device's.
+HOST_SPANS = frozenset({
+    "train.step", "train.read", "train.put", "train.dispatch",
+    "train.listeners",
+    "generation.prefill", "generation.decode", "generation.graft",
+})
+
+# jit names of the generation engine's three programs (``XLA Modules`` line)
+GENERATION_PROGRAMS = ("generation_prefill", "generation_decode",
+                       "generation_graft")
+
+_TRANSFORM = re.compile(r"^[A-Za-z_]+\((.*)\)$")
+
+
+def scope_of(op_name: str):
+    """The component scope of an HLO ``op_name``, or None: the outermost
+    path element that, with its transform wrappers (``jvp(...)``,
+    ``transpose(jvp(...))``) taken off, is one of ``COMPONENT_SCOPES``."""
+    for part in op_name.split("/")[1:]:
+        while True:
+            m = _TRANSFORM.match(part)
+            if m is None:
+                break
+            part = m.group(1)
+        if part in COMPONENT_SCOPES:
+            return part
+    return None

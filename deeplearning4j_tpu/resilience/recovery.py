@@ -40,6 +40,8 @@ from typing import Any, Dict, List, Optional, Set, Tuple
 
 import jax
 
+from deeplearning4j_tpu.observability.trace import annotate
+
 
 class NonFiniteLossError(RuntimeError):
     """Raised host-side when a step's loss is NaN/inf (the recovery
@@ -338,102 +340,115 @@ class FaultTolerantTrainer:
                 b = 0
                 it = iter(data)
                 while True:
-                    # manual next(): the read is timed so the starvation
-                    # detector sees FT runs too (Trainer.fit measures the
-                    # same leg)
-                    t_read = time.perf_counter() if tm is not None else 0.0
-                    try:
-                        batch = next(it)
-                    except StopIteration:
-                        break
-                    read_s = (time.perf_counter() - t_read
-                              if tm is not None else 0.0)
-                    if b < skip_batches:
-                        b += 1
-                        continue
-                    if (epoch, b) in skip_set:
-                        self.recoveries.append(
-                            {"kind": "skip_batch", "epoch": epoch, "batch": b})
-                        rm = _obs()
-                        if rm is not None:
-                            rm.skipped_batches_total.inc()
-                        _flight("resilience.skip_batch", epoch=epoch, batch=b)
-                        b += 1
-                        continue
-                    batch = as_batch_dict(batch)
-                    if inj.enabled:
-                        # "train.worker_kill": die here (SIGKILL under
-                        # !kill) so supervisor relaunch/resume paths are
-                        # chaos-testable at an exact step
-                        inj.maybe_fail("train.worker_kill")
-                        batch = inj.maybe_poison_batch(batch)
-                    if tr._batch_sharding is not None:
-                        batch = jax.device_put(batch, tr._batch_sharding)
-                    new_ts = None
-                    t_step = time.perf_counter() if tm is not None else 0.0
-                    try:
-                        new_ts, metrics = self._step_fn(ts, batch)
-                        if pol.check_every and \
-                                (host_step + 1) % pol.check_every == 0:
-                            loss = float(jax.device_get(
-                                metrics["total_loss"]))
-                            if not math.isfinite(loss):
-                                raise NonFiniteLossError(
-                                    f"non-finite loss {loss} at step "
-                                    f"{host_step + 1}", step=host_step + 1)
-                    except nan_types as e:
-                        rollbacks += 1
-                        key = (epoch, b)
-                        fail_counts[key] = fail_counts.get(key, 0) + 1
-                        if rollbacks > pol.max_rollbacks:
-                            raise
-                        if pol.skip_poison_after and \
-                                fail_counts[key] >= pol.skip_poison_after:
-                            skip_set.add(key)
-                        template = new_ts if new_ts is not None else ts
-                        ts, (r_epoch, r_skip) = self._rollback(template, e)
-                        host_step = int(jax.device_get(ts.step))
-                        if pol.lr_cut != 1.0:
-                            self._lr_scale *= pol.lr_cut
-                            # fresh jit wrapper → fresh trace → the new
-                            # scale constant is baked into the executable
-                            self._step_fn = tr._jit_with_nan_guard(
-                                tr._raw_step, tr._jit_kwargs)
+                    # the same spans on the profiler's clock as Trainer.fit
+                    with annotate("train.step", step_num=host_step + 1):
+                        # manual next(): the read is timed so the starvation
+                        # detector sees FT runs too (Trainer.fit measures the
+                        # same leg)
+                        t_read = time.perf_counter() if tm is not None else 0.0
+                        with annotate("train.read"):
+                            try:
+                                batch = next(it)
+                            except StopIteration:
+                                break
+                        read_s = (time.perf_counter() - t_read
+                                  if tm is not None else 0.0)
+                        if b < skip_batches:
+                            b += 1
+                            continue
+                        if (epoch, b) in skip_set:
                             self.recoveries.append(
-                                {"kind": "lr_cut", "scale": self._lr_scale})
+                                {"kind": "skip_batch", "epoch": epoch,
+                                 "batch": b})
                             rm = _obs()
                             if rm is not None:
-                                rm.lr_cuts_total.inc()
-                            _flight("resilience.lr_cut",
-                                    scale=self._lr_scale)
-                        epoch = r_epoch
-                        skip_batches = r_skip
-                        restart_epoch = True
-                        break
-                    ts = new_ts
-                    host_step += 1
-                    note_train_step()  # armed incident capture boundary
-                    touch_heartbeat()  # supervisor hang-detector beacon
-                    if tm is not None:
-                        step_s = time.perf_counter() - t_step
-                        tm.step_seconds.observe(step_s)
-                        tm.data_read_seconds.observe(read_s)
-                        tm.steps_total.inc()
-                        feats = jax.tree_util.tree_leaves(batch["features"])
-                        tm.samples_total.inc(feats[0].shape[0])
-                        tele.on_step(ts, batch, read_s, step_s, host_step)
-                    b += 1
-                    if pol.checkpoint_every and \
-                            host_step % pol.checkpoint_every == 0:
-                        self._save(ts, epoch=epoch, batch_in_epoch=b,
-                                   tag="auto")
-                    for lst in listeners:
-                        if lst.on_iteration(epoch, host_step, ts, metrics):
-                            stop = True
-                    if steps_per_epoch is not None and b >= steps_per_epoch:
-                        break
-                    if stop:
-                        break
+                                rm.skipped_batches_total.inc()
+                            _flight("resilience.skip_batch", epoch=epoch,
+                                    batch=b)
+                            b += 1
+                            continue
+                        batch = as_batch_dict(batch)
+                        if inj.enabled:
+                            # "train.worker_kill": die here (SIGKILL under
+                            # !kill) so supervisor relaunch/resume paths are
+                            # chaos-testable at an exact step
+                            inj.maybe_fail("train.worker_kill")
+                            batch = inj.maybe_poison_batch(batch)
+                        if tr._batch_sharding is not None:
+                            with annotate("train.put"):
+                                batch = jax.device_put(
+                                    batch, tr._batch_sharding)
+                        new_ts = None
+                        t_step = time.perf_counter() if tm is not None else 0.0
+                        try:
+                            with annotate("train.dispatch"):
+                                new_ts, metrics = self._step_fn(ts, batch)
+                            if pol.check_every and \
+                                    (host_step + 1) % pol.check_every == 0:
+                                loss = float(jax.device_get(
+                                    metrics["total_loss"]))
+                                if not math.isfinite(loss):
+                                    raise NonFiniteLossError(
+                                        f"non-finite loss {loss} at step "
+                                        f"{host_step + 1}", step=host_step + 1)
+                        except nan_types as e:
+                            rollbacks += 1
+                            key = (epoch, b)
+                            fail_counts[key] = fail_counts.get(key, 0) + 1
+                            if rollbacks > pol.max_rollbacks:
+                                raise
+                            if pol.skip_poison_after and \
+                                    fail_counts[key] >= pol.skip_poison_after:
+                                skip_set.add(key)
+                            template = new_ts if new_ts is not None else ts
+                            ts, (r_epoch, r_skip) = self._rollback(template, e)
+                            host_step = int(jax.device_get(ts.step))
+                            if pol.lr_cut != 1.0:
+                                self._lr_scale *= pol.lr_cut
+                                # fresh jit wrapper → fresh trace → the new
+                                # scale constant is baked into the executable
+                                self._step_fn = tr._jit_with_nan_guard(
+                                    tr._raw_step, tr._jit_kwargs)
+                                self.recoveries.append(
+                                    {"kind": "lr_cut",
+                                     "scale": self._lr_scale})
+                                rm = _obs()
+                                if rm is not None:
+                                    rm.lr_cuts_total.inc()
+                                _flight("resilience.lr_cut",
+                                        scale=self._lr_scale)
+                            epoch = r_epoch
+                            skip_batches = r_skip
+                            restart_epoch = True
+                            break
+                        ts = new_ts
+                        host_step += 1
+                        note_train_step()  # armed incident capture boundary
+                        touch_heartbeat()  # supervisor hang-detector beacon
+                        if tm is not None:
+                            step_s = time.perf_counter() - t_step
+                            tm.step_seconds.observe(step_s)
+                            tm.data_read_seconds.observe(read_s)
+                            tm.steps_total.inc()
+                            feats = jax.tree_util.tree_leaves(
+                                batch["features"])
+                            tm.samples_total.inc(feats[0].shape[0])
+                            tele.on_step(ts, batch, read_s, step_s, host_step)
+                        b += 1
+                        if pol.checkpoint_every and \
+                                host_step % pol.checkpoint_every == 0:
+                            self._save(ts, epoch=epoch, batch_in_epoch=b,
+                                       tag="auto")
+                        with annotate("train.listeners"):
+                            for lst in listeners:
+                                if lst.on_iteration(epoch, host_step, ts,
+                                                    metrics):
+                                    stop = True
+                        if (steps_per_epoch is not None
+                                and b >= steps_per_epoch):
+                            break
+                        if stop:
+                            break
                 if restart_epoch:
                     if hasattr(data, "reset"):
                         data.reset()

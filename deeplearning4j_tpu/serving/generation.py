@@ -425,8 +425,10 @@ class GenerationEngine:
         model = self._model
         nl = model.config.num_layers
 
-        def run(params, kslabs, vslabs, base_key, step, slot, prompt, t0,
-                temp):
+        # the function's name is the program's on a trace's XLA Modules
+        # line (jit_generation_prefill): observability/vocab.py
+        def generation_prefill(params, kslabs, vslabs, base_key, step, slot,
+                               prompt, t0, temp):
             logits, kvs = model.prefill_chunk(params, prompt[None, :])
             ks, vs = [], []
             for i in range(nl):
@@ -441,14 +443,14 @@ class GenerationEngine:
             tok = sample_token(last[None, :], key, temp[None])[0]
             return tuple(ks), tuple(vs), tok
 
-        return jax.jit(run, donate_argnums=self._donate())
+        return jax.jit(generation_prefill, donate_argnums=self._donate())
 
     def _build_decode(self, b: int, kv: int):
         model = self._model
         nl = model.config.num_layers
 
-        def run(params, kslabs, vslabs, base_key, step, slot_idx, ids, pos,
-                temps):
+        def generation_decode(params, kslabs, vslabs, base_key, step,
+                              slot_idx, ids, pos, temps):
             caches = [{"k": kslabs[i][slot_idx, :, :kv, :],
                        "v": vslabs[i][slot_idx, :, :kv, :]}
                       for i in range(nl)]
@@ -465,7 +467,7 @@ class GenerationEngine:
             tok = sample_token(logits, key, temps)
             return tuple(ks), tuple(vs), tok
 
-        return jax.jit(run, donate_argnums=self._donate())
+        return jax.jit(generation_decode, donate_argnums=self._donate())
 
     def _build_graft(self, P: int):
         # scatter a shared prefix slab (per-layer (heads, P, head_dim)
@@ -473,7 +475,7 @@ class GenerationEngine:
         # prefill replaced by one copy when the prefix is cached
         nl = self._model.config.num_layers
 
-        def run(kslabs, vslabs, pks, pvs, slot):
+        def generation_graft(kslabs, vslabs, pks, pvs, slot):
             ks, vs = [], []
             for i in range(nl):
                 ks.append(jax.lax.dynamic_update_slice(
@@ -485,7 +487,7 @@ class GenerationEngine:
             return tuple(ks), tuple(vs)
 
         donate = () if jax.default_backend() == "cpu" else (0, 1)
-        return jax.jit(run, donate_argnums=donate)
+        return jax.jit(generation_graft, donate_argnums=donate)
 
     def _get_graft_fn(self, P: int):
         fn = self._graft_fns.get(P)
@@ -992,12 +994,13 @@ class GenerationEngine:
         prompt[:t0v] = req.prompt
         self._rng_step += 1
         tp0 = _trace.now()
-        ks, vs, tok = fn(self._params, self._kslabs, self._vslabs,
-                         self._base_key, np.int32(self._rng_step),
-                         np.int32(req.slot), prompt, np.int32(t0v),
-                         np.float32(req.temperature))
-        self._kslabs, self._vslabs = ks, vs
-        tok = int(np.asarray(tok))
+        with _trace.annotate("generation.prefill"):
+            ks, vs, tok = fn(self._params, self._kslabs, self._vslabs,
+                             self._base_key, np.int32(self._rng_step),
+                             np.int32(req.slot), prompt, np.int32(t0v),
+                             np.float32(req.temperature))
+            self._kslabs, self._vslabs = ks, vs
+            tok = int(np.asarray(tok))
         tp1 = _trace.now()
         if pc is not None:
             self._publish_prefix(req, t0v)
@@ -1052,8 +1055,9 @@ class GenerationEngine:
         gfn = self._get_graft_fn(P)
         pks = tuple(k for k, _ in entry.kvs)
         pvs = tuple(v for _, v in entry.kvs)
-        ks, vs = gfn(self._kslabs, self._vslabs, pks, pvs,
-                     np.int32(req.slot))
+        with _trace.annotate("generation.graft"):
+            ks, vs = gfn(self._kslabs, self._vslabs, pks, pvs,
+                         np.int32(req.slot))
         self._kslabs, self._vslabs = ks, vs
         b = _bucket(self.slot_buckets, 1)
         tok = None
@@ -1070,12 +1074,14 @@ class GenerationEngine:
             pos[0] = j
             temps = np.zeros(b, np.float32)
             temps[0] = req.temperature
-            ks, vs, toks = fn(self._params, self._kslabs, self._vslabs,
-                              self._base_key, np.int32(self._rng_step),
-                              slot_idx, ids, pos, temps)
+            with _trace.annotate("generation.decode"):
+                ks, vs, toks = fn(self._params, self._kslabs, self._vslabs,
+                                  self._base_key, np.int32(self._rng_step),
+                                  slot_idx, ids, pos, temps)
             self._kslabs, self._vslabs = ks, vs
             tok = toks
-        tok = int(np.asarray(tok)[0])
+        with _trace.annotate("generation.decode"):  # the last feed's sync
+            tok = int(np.asarray(tok)[0])
         tp1 = _trace.now()
         with self._cv:
             if req.state != _ACTIVE:
@@ -1157,11 +1163,12 @@ class GenerationEngine:
         fn = self._get_decode_fn(b, kv)
         self._rng_step += 1
         td0 = _trace.now()
-        ks, vs, toks = fn(self._params, self._kslabs, self._vslabs,
-                          self._base_key, np.int32(self._rng_step),
-                          slot_idx, ids, pos, temps)
-        self._kslabs, self._vslabs = ks, vs
-        toks = np.asarray(toks)
+        with _trace.annotate("generation.decode"):
+            ks, vs, toks = fn(self._params, self._kslabs, self._vslabs,
+                              self._base_key, np.int32(self._rng_step),
+                              slot_idx, ids, pos, temps)
+            self._kslabs, self._vslabs = ks, vs
+            toks = np.asarray(toks)
         td1 = _trace.now()
         step_s = td1 - td0
         self.steps += 1

@@ -5,15 +5,29 @@ TPU-era design: the reference intercepts per-op JNI dispatches and
 aggregates host-side timings. Under XLA there are no per-op dispatches to
 intercept — the step is one fused program — so profiling is (a) the XLA
 profiler (``jax.profiler``) capturing a device trace viewable in
-TensorBoard/Perfetto, wrapped per-step with ``StepTraceAnnotation`` so
-steps show as rows, and (b) host-side step wall-time statistics with
+TensorBoard/Perfetto, and (b) host-side step wall-time statistics with
 forced-materialization sync (dispatch is asynchronous) for the per-step
 breakdown.
 
+Nothing in this module annotates a step. The steps show as rows because
+the fit loops do it: ``Trainer.fit`` and ``FaultTolerantTrainer.fit``
+wrap every iteration in ``StepTraceAnnotation("train.step",
+step_num=...)`` with ``train.read`` / ``train.put`` / ``train.dispatch``
+/ ``train.listeners`` inside (``observability/trace.annotate``), and the
+compiled step's operations carry the component scopes of
+``observability/vocab.py``; ``ProfilingListener`` only starts and stops
+the capture around them.
+
 ``analyze_trace``/``compare_traces`` are the ProfileAnalyzer analogue:
-they parse the captured ``.trace.json.gz`` (Chrome trace format) and
+they parse a captured ``.trace.json.gz`` (Chrome trace format) and
 aggregate device-op durations, so a regression between two runs is
-attributable to named XLA ops.
+attributable to named XLA ops. They read only that file, never the
+``.xplane.pb`` beside it, so they see a capture only where
+``start_trace`` also wrote the Chrome form; the server's
+``POST /debug/profile`` is their reader. The benchmark reads the
+``.xplane.pb`` instead (``benchmark/harness/trace_reduce.py``: busy
+union, step window, host spans on the device's clock), which
+``analyze_trace`` has none of.
 """
 
 from __future__ import annotations
@@ -53,7 +67,6 @@ class ProfilingListener(TrainingListener):
         self.step_ms: List[float] = []
         self._active = False
         self._t_prev: Optional[float] = None
-        self._annotation = None
 
     # -- trace control -----------------------------------------------------
 

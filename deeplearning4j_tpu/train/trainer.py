@@ -26,6 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from deeplearning4j_tpu.kernels._dispatch import kernel_mesh as _kernel_mesh
 from deeplearning4j_tpu.nn.config import NeuralNetConfiguration
+from deeplearning4j_tpu.observability.vocab import SCOPE_OPTIMIZER
 from deeplearning4j_tpu.ops import math as opsmath
 from deeplearning4j_tpu.train.updaters import apply_updates, resolve_updater
 
@@ -190,7 +191,8 @@ class Trainer:
             (one copy of the mixed-precision param cast)."""
             def loss_of(p):
                 if mixed:
-                    p = _to_bf16(p)
+                    with jax.named_scope(SCOPE_OPTIMIZER):
+                        p = _to_bf16(p)
                 return self.model.loss_fn(p, model_state, batch, rng=rng)
 
             # trace-time: Pallas kernels in the model place themselves
@@ -309,7 +311,8 @@ class Trainer:
 
             def loss_of(params):
                 if mixed:
-                    params = _to_bf16(params)
+                    with jax.named_scope(SCOPE_OPTIMIZER):
+                        params = _to_bf16(params)
                 return self.model.loss_fn_tbptt(
                     params, ts.model_state, batch, carries, rng=step_rng)
 
@@ -341,6 +344,8 @@ class Trainer:
         # compile so the fit loop never blocks on cost analysis
         self._step_cost_cache: Dict[Any, Any] = {}
         self._step_cost_lock = threading.Lock()
+        # module name of the step the last analysis described
+        self._step_module: Optional[str] = None
 
     # -- analytic step cost (observability) ---------------------------------
 
@@ -349,7 +354,11 @@ class Trainer:
         None while unknown. First call per shape kicks off a background
         thread that lowers + compiles the step ABSTRACTLY (ShapeDtype
         structs — no live buffers held, donation-safe) and reads XLA's
-        ``cost_analysis``; later calls return the cached number. Disable
+        ``cost_analysis``; later calls return the cached number. The same
+        lowering, compiled again when asked (a read of the compilation
+        cache where one is armed), says which component scope each of the
+        step's HLO instructions belongs to; both go to the process table
+        of ``observability/runtime.py`` (:meth:`step_description`). Disable
         with ``DL4J_TPU_STEP_COST_ANALYSIS=0`` (a second compile of a
         huge model, even off-thread, may not be worth the gauge)."""
         if os.environ.get("DL4J_TPU_STEP_COST_ANALYSIS", "1") == "0":
@@ -374,6 +383,10 @@ class Trainer:
 
         a_ts, a_batch = abstract(ts), abstract(batch)
 
+        def lower():
+            return jax.jit(self._raw_step,
+                           **self._jit_kwargs).lower(a_ts, a_batch)
+
         def _compute():
             from deeplearning4j_tpu.train.profiling import (
                 normalize_cost_analysis,
@@ -383,12 +396,22 @@ class Trainer:
                 if _COST_SHUTDOWN.is_set():
                     result = "failed"  # process exiting: never start a
                 else:                  # compile the exit would tear down
-                    compiled = jax.jit(
-                        self._raw_step,
-                        **self._jit_kwargs).lower(a_ts, a_batch).compile()
-                    costs = normalize_cost_analysis(compiled.cost_analysis())
+                    lowered = lower()
+                    costs = normalize_cost_analysis(
+                        lowered.compile().cost_analysis())
                     flops = float(costs.get("flops") or 0.0)
                     result = flops if flops > 0 else "failed"
+                    # what the step is made of: its text is fetched when
+                    # the table is first read (observability/runtime.py
+                    # says why not here); every step carries
+                    # _finish_step's scope
+                    module = str(lowered.compiler_ir().operation
+                                 .attributes["sym_name"]).strip('"')
+                    _publish_program(
+                        module, flops=flops if flops > 0 else None,
+                        text=lambda: lower().compile().as_text(),
+                        carries=SCOPE_OPTIMIZER)
+                    self._step_module = module
             except Exception:  # noqa: BLE001 — diagnostics never kill a fit
                 result = "failed"
             with self._step_cost_lock:
@@ -401,12 +424,27 @@ class Trainer:
         t.start()
         return None
 
+    def step_description(self) -> Optional[Dict[str, Any]]:
+        """What the compiled step is made of, once :meth:`step_flops`'s
+        background analysis has run: ``{"module", "flops", "scopes",
+        "stale"}`` — the step program's name on a trace's ``XLA Modules``
+        line, XLA's cost-model FLOPs (the ``train_step_flops`` gauge), per
+        HLO instruction its component scope (``observability/vocab.py``)
+        or None, and whether the executable came from a compile-cache
+        entry whose metadata predate the scopes. None while unknown. The
+        first call fetches the step's text and can take seconds."""
+        module = self._step_module
+        entry = _program_table().get(module) if module else None
+        return None if entry is None else dict(entry, module=module)
+
+    @jax.named_scope(SCOPE_OPTIMIZER)
     def _finish_step(self, ts: TrainState, grads, new_model_state, metrics,
                      loss, batch):
         """Shared back half of every step kind: freeze-mask, normalize,
         updater, constraints, metric assembly, TrainState rebuild. Keeping
         it in ONE place is what guarantees the standard, chained, and TBPTT
-        paths can never diverge on gradient handling."""
+        paths can never diverge on gradient handling. All of it is the
+        ``optimizer`` component scope of a profiler trace."""
         raw_grad_norms = {}
         if self.grad_metrics:
             # RAW per-layer norms, before freeze-masking and clipping —
@@ -712,66 +750,81 @@ class Trainer:
                 it = iter(data)
                 n = 0
                 while True:
-                    t_read = time.perf_counter() if om is not None else 0.0
-                    try:
-                        batch = next(it)
-                    except StopIteration:
-                        break
-                    read_s = (time.perf_counter() - t_read
-                              if om is not None else 0.0)
-                    if om is not None:
-                        om.data_read_seconds.observe(read_s)
-                    batch = _as_batch_dict(batch)
-                    if _fault_injector().enabled:
-                        # "train.worker_kill" (SIGKILL/raise at the N-th
-                        # step — the elastic supervisor's relaunch
-                        # trigger) and "train.step_nan" poison-batch
-                        # injection points (resilience/faults.py); no-op
-                        # unless DL4J_TPU_FAULTS armed a plan
-                        _fault_injector().maybe_fail("train.worker_kill")
-                        batch = _fault_injector().maybe_poison_batch(batch)
-                    if self._batch_sharding is not None:
+                    # the iteration and its legs are spans on the
+                    # profiler's clock (observability/trace.annotate): a
+                    # device trace names the host's part of every idle gap
+                    with _annotate("train.step", step_num=host_step + 1):
+                        t_read = time.perf_counter() if om is not None else 0.0
+                        with _annotate("train.read"):
+                            try:
+                                batch = next(it)
+                            except StopIteration:
+                                break
+                        read_s = (time.perf_counter() - t_read
+                                  if om is not None else 0.0)
                         if om is not None:
-                            _record_batch_transfer(batch)
-                        batch = jax.device_put(batch, self._batch_sharding)
-                    t_step = time.perf_counter() if om is not None else 0.0
-                    if getattr(self.net, "backprop_type", "standard") == "tbptt":
-                        # ↔ TruncatedBPTT: every window is an iteration (the
-                        # reference fires iterationDone once per window).
-                        ts, wmetrics = self._fit_tbptt_batch(ts, batch)
-                    else:
-                        ts, metrics = self.train_step(ts, batch)
-                        wmetrics = [metrics]
-                    if om is not None:
-                        step_s = time.perf_counter() - t_step
-                        om.step_seconds.observe(step_s)
-                        om.steps_total.inc(len(wmetrics))
-                        feats = jax.tree_util.tree_leaves(batch["features"])
-                        om.samples_total.inc(feats[0].shape[0])
-                        tele.on_step(ts, batch, read_s, step_s,
-                                     host_step + len(wmetrics))
-                    n += 1
-                    # step boundary for an armed incident device capture
-                    # (a no-op global check unless one is pending)
-                    _incidents_note_step()
-                    # progress beacon for the elastic supervisor's hang
-                    # detector (resilience/cluster.py); a no-op global
-                    # check unless a supervisor armed a heartbeat
-                    _touch_heartbeat()
-                    # step attribution for cluster trace stitching: the
-                    # next collective's span joins THIS step's cluster-
-                    # wide trace id (runtime/distributed.py; a bare
-                    # global int store)
-                    _note_step(host_step + len(wmetrics))
-                    for wm in wmetrics:
-                        host_step += 1
-                        for lst in listeners:
-                            if lst.on_iteration(epoch, host_step, ts, wm):
-                                stop = True
-                    if steps_per_epoch is not None and n >= steps_per_epoch:
-                        break
-                    if stop:
-                        break
+                            om.data_read_seconds.observe(read_s)
+                        batch = _as_batch_dict(batch)
+                        if _fault_injector().enabled:
+                            # "train.worker_kill" (SIGKILL/raise at the N-th
+                            # step — the elastic supervisor's relaunch
+                            # trigger) and "train.step_nan" poison-batch
+                            # injection points (resilience/faults.py); no-op
+                            # unless DL4J_TPU_FAULTS armed a plan
+                            _fault_injector().maybe_fail("train.worker_kill")
+                            batch = _fault_injector().maybe_poison_batch(
+                                batch)
+                        if self._batch_sharding is not None:
+                            if om is not None:
+                                _record_batch_transfer(batch)
+                            with _annotate("train.put"):
+                                batch = jax.device_put(
+                                    batch, self._batch_sharding)
+                        t_step = time.perf_counter() if om is not None else 0.0
+                        with _annotate("train.dispatch"):
+                            if getattr(self.net, "backprop_type",
+                                       "standard") == "tbptt":
+                                # ↔ TruncatedBPTT: every window is an
+                                # iteration (the reference fires
+                                # iterationDone once per window).
+                                ts, wmetrics = self._fit_tbptt_batch(ts, batch)
+                            else:
+                                ts, metrics = self.train_step(ts, batch)
+                                wmetrics = [metrics]
+                        if om is not None:
+                            step_s = time.perf_counter() - t_step
+                            om.step_seconds.observe(step_s)
+                            om.steps_total.inc(len(wmetrics))
+                            feats = jax.tree_util.tree_leaves(
+                                batch["features"])
+                            om.samples_total.inc(feats[0].shape[0])
+                            tele.on_step(ts, batch, read_s, step_s,
+                                         host_step + len(wmetrics))
+                        n += 1
+                        # step boundary for an armed incident device capture
+                        # (a no-op global check unless one is pending)
+                        _incidents_note_step()
+                        # progress beacon for the elastic supervisor's hang
+                        # detector (resilience/cluster.py); a no-op global
+                        # check unless a supervisor armed a heartbeat
+                        _touch_heartbeat()
+                        # step attribution for cluster trace stitching: the
+                        # next collective's span joins THIS step's cluster-
+                        # wide trace id (runtime/distributed.py; a bare
+                        # global int store)
+                        _note_step(host_step + len(wmetrics))
+                        with _annotate("train.listeners"):
+                            for wm in wmetrics:
+                                host_step += 1
+                                for lst in listeners:
+                                    if lst.on_iteration(epoch, host_step, ts,
+                                                        wm):
+                                        stop = True
+                        if (steps_per_epoch is not None
+                                and n >= steps_per_epoch):
+                            break
+                        if stop:
+                            break
                 for lst in listeners:
                     if lst.on_epoch_end(epoch, ts):
                         stop = True
@@ -804,11 +857,11 @@ def _training_metrics():
 class _StepTelemetry:
     """Per-fit diagnostics feeding the shared registry + flight recorder:
 
-    - analytic-MFU gauges: the step's XLA cost-model FLOPs (computed once
-      per batch shape off-thread by ``Trainer.step_flops``) over the
-      measured host step wall-time → ``train_step_flops`` /
-      ``train_flops_per_second`` / ``train_analytic_mfu`` (the last only
-      when ``DL4J_TPU_PEAK_FLOPS`` declares the chip peak);
+    - ``train_step_flops``: the step's XLA cost-model FLOPs (computed once
+      per batch shape off-thread by ``Trainer.step_flops``). No rate is
+      derived from it here: the host sees a step's *dispatch* time, which
+      under asynchronous dispatch is not the step's time — the benchmark's
+      ``mfu_train`` reads the device trace for that;
     - data-starvation detector: when data-read latency exceeds
       ``STARVE_FRACTION`` of recent loop wall-time, the input pipeline —
       not the chip — is the bottleneck: ``train_data_starved`` flips to 1
@@ -837,10 +890,6 @@ class _StepTelemetry:
         # step_flops cache key (every leaf's shape+dtype) costs ~10 µs a
         # step — too much for a per-step hot loop once the answer is known
         self._flops_by_shape: Dict[Any, float] = {}
-        try:
-            self._peak = float(os.environ.get("DL4J_TPU_PEAK_FLOPS", "0"))
-        except ValueError:
-            self._peak = 0.0
 
     def on_step(self, ts, batch, read_s: float, step_s: float,
                 step_no: int):
@@ -849,9 +898,9 @@ class _StepTelemetry:
         )
 
         om = self.om
-        # throughput gauges refresh on the sampled cadence: a gauge is a
-        # last-value instrument, and three .set() locks per step is real
-        # money on a ~1 ms step
+        # the gauge refreshes on the sampled cadence: a gauge is a
+        # last-value instrument, and a .set() lock per step is real money
+        # on a ~1 ms step
         if step_no == 1 or step_no % self.STEP_EVENT_EVERY == 0:
             shape_key = getattr(batch.get("features"), "shape", None) \
                 if isinstance(batch, dict) else None
@@ -863,11 +912,6 @@ class _StepTelemetry:
                     self._flops_by_shape[shape_key] = flops
             if flops:
                 om.step_flops.set(flops)
-                if step_s > 0:
-                    fps = flops / step_s
-                    om.flops_per_second.set(fps)
-                    if self._peak > 0:
-                        om.analytic_mfu.set(fps / self._peak)
         # rolling read-vs-step attribution over the trailing window
         if len(self._samples) == self._samples.maxlen:
             old_r, old_s = self._samples[0]
@@ -924,3 +968,8 @@ from deeplearning4j_tpu.observability.incidents import (  # noqa: E402
 from deeplearning4j_tpu.resilience.cluster import touch_heartbeat as _touch_heartbeat  # noqa: E402
 from deeplearning4j_tpu.resilience.faults import get_fault_injector as _fault_injector  # noqa: E402
 from deeplearning4j_tpu.runtime.distributed import note_step as _note_step  # noqa: E402
+from deeplearning4j_tpu.observability.trace import annotate as _annotate  # noqa: E402
+from deeplearning4j_tpu.observability.runtime import (  # noqa: E402
+    program_table as _program_table,
+    publish_program as _publish_program,
+)
