@@ -58,10 +58,12 @@ KNOBS: Tuple[Knob, ...] = (
     # -- kernels --------------------------------------------------------------
     Knob("DL4J_TPU_FLASH_BF16", "flag", "0", "kernels",
          "Allow bf16 flash-attention off-TPU (CPU testing)."),
-    Knob("DL4J_TPU_FLASH_BLOCK_K", "int", "512", "kernels",
-         "Flash-attention KV-block tile size."),
-    Knob("DL4J_TPU_FLASH_BLOCK_Q", "int", "256", "kernels",
-         "Flash-attention query-block tile size."),
+    Knob("DL4J_TPU_FLASH_BLOCK_K", "int", "unset", "kernels",
+         "Flash-attention KV-block tile size of all three kernels "
+         "(unset: chosen per kernel from the shapes)."),
+    Knob("DL4J_TPU_FLASH_BLOCK_Q", "int", "unset", "kernels",
+         "Flash-attention query-block tile size of all three kernels "
+         "(unset: chosen per kernel from the shapes)."),
     Knob("DL4J_TPU_FLASH_FP32", "flag", "0", "kernels",
          "Force fp32 flash-attention accumulation."),
     Knob("DL4J_TPU_FLASH_MIN_SEQ", "int", "1024", "kernels",

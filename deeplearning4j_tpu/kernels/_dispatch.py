@@ -18,6 +18,7 @@ from __future__ import annotations
 import contextlib
 import os
 import threading
+from typing import NamedTuple
 
 import jax
 
@@ -79,17 +80,51 @@ def interpret() -> bool:
         "for kernel unit tests")
 
 
-def flash_block_sizes() -> tuple[int, int]:
-    """Default (block_q, block_k) for the flash kernel.
+class FlashBlocks(NamedTuple):
+    """(block_q, block_k) of each flash kernel; they need not share one."""
 
-    Tunable via DL4J_TPU_FLASH_BLOCK_Q/K so the on-chip kernels_ab sweep
-    can promote a winning geometry without a code change. 256x512 default:
-    larger kv blocks amortize the per-grid-step overhead along the
-    innermost (sequential) dimension while [block_q, block_k] score tiles
-    stay comfortably inside VMEM.
+    fwd: tuple[int, int]
+    dkv: tuple[int, int]
+    dq: tuple[int, int]
+
+
+def flash_block_sizes(seq_q: int, seq_k: int, head_dim: int,
+                      causal: bool) -> FlashBlocks:
+    """Default geometry of the three flash kernels for a call's shapes.
+
+    One rule: the largest tile, 1024 x 1024, which ``flash_attention`` cuts
+    to the sequence where that is shorter. Swept on the chip (TPU v5e,
+    ``[16, 12, 1024, 64]`` and ``[4, 12, 4096, 64]`` bf16 causal, blocks of
+    128 to 1024 each way, each kernel's device time read from a trace;
+    table in PERF.md section 5), every kernel is fastest there: their
+    per-tile work that does not grow with the tile's width (the row maxima
+    and sums, the rescale of the accumulators, the step's copies) outweighs
+    the dead pairs a large tile drags in. One exception, at the one shape
+    where it was measured: 1024 queries by 1024 keys, causal, where
+    ``flash_bwd_dkv`` at 512 x 512 skips the dead quarter (1.106 ms against
+    1.225; at 4096 it reads 3.22 against 3.08, so 1024 stands there).
+
+    Not measured, so the rule is a guess there: any ``head_dim`` but 64,
+    float32 operands, calls that are not causal, carry a ``key_mask`` or
+    have ``seq_q != seq_k``, and sequences that are no multiple of the tile
+    (padded up to whole tiles). Heads wider than 128 get 512 x 512 because
+    the chip's compiler refuses 1024 x 1024 there (``flash_bwd_dkv`` at 256
+    wide in float32 does not fit its share of VMEM); what the defaults
+    compile for is pinned in ``tests/test_flash_compiles_for_v5e.py``.
+
+    DL4J_TPU_FLASH_BLOCK_Q/K, where set, give all three kernels that one
+    geometry.
     """
-    return (int(os.environ.get("DL4J_TPU_FLASH_BLOCK_Q", "256")),
-            int(os.environ.get("DL4J_TPU_FLASH_BLOCK_K", "512")))
+    largest = 1024 if head_dim <= 128 else 512
+    big = (largest, largest)
+    dkv = (512, 512) if causal and seq_q == seq_k == 1024 else big
+    blocks = FlashBlocks(fwd=big, dkv=dkv, dq=big)
+    env_q = os.environ.get("DL4J_TPU_FLASH_BLOCK_Q")
+    env_k = os.environ.get("DL4J_TPU_FLASH_BLOCK_K")
+    if env_q or env_k:
+        blocks = FlashBlocks(*[(int(env_q or bq), int(env_k or bk))
+                               for bq, bk in blocks])
+    return blocks
 
 
 def flash_min_seq() -> int:

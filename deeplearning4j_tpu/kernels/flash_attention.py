@@ -9,35 +9,64 @@ matmuls per tile run back-to-back on the MXU.
 
 Grid: (batch*heads, q_blocks, kv_blocks), kv innermost so the running
 max/denominator/accumulator for one q block live in VMEM scratch across the
-kv sweep. Causal masking skips fully-masked kv blocks via ``pl.when``.
-Per-example key padding masks ([B,S] 1/0 — the BERT attention-mask case)
-are handled *inside* the kernel, so masked batches keep the flash path;
-only arbitrary additive ``bias`` falls back to the XLA reference.
+kv sweep. Which grid steps have a ``[block_q, block_k]`` score tile to
+work on is decided by one function of the shapes, :class:`TilePlan`: a tile
+above the causal diagonal is dead (skipped, and its index map points at a
+block already fetched, so no copy is issued for it); every other tile is
+live and builds the mask the shapes call for (the causal triangle, padded
+keys or queries, a key mask), each term only where the shapes can make it
+false. A per-example key padding mask ([B,S] 1/0 — the BERT attention-mask
+case) runs inside the kernel; only arbitrary additive ``bias`` falls back
+to the XLA reference. The head dimension is never padded (a block as wide
+as the array is legal at any width; 8 to 256 were compiled for the v5e),
+and the softmax scale goes on the ``[block_q, D]`` operand, not on the
+scores.
 
 Backward: blockwise Pallas kernels (FlashAttention-2 style). The forward
 saves the per-row logsumexp (lane-broadcast [BH,T,128] layout, the Mosaic
 tiling-friendly shape jax's own TPU flash kernel uses); backward runs two
 kernels — dk/dv with a q-block sweep per kv block, dq with a kv-block
 sweep per q block — plus one XLA pass for delta = rowsum(dO*O). Scores are
-recomputed on-chip, so backward memory stays O(T·D) like forward. The
-same kernels run everywhere: compiled on TPU, interpret-mode in CPU tests
-(via DL4J_TPU_FORCE_PALLAS=1; plain CPU callers never reach them because
-flash_attention auto-dispatches to reference_attention off-TPU, and an
-explicit ``backend="pallas"`` there raises).
+recomputed on-chip, so backward memory stays O(T·D) like forward. Each of
+the three kernels has a geometry of its own (``_dispatch.flash_block_sizes``).
+
+What the chip showed (TPU v5e, [16, 12, 1024, 64] bf16 causal, one layer's
+three calls, device time from a trace; PERF.md sections 5 and 6): 1.87 /
+1.65 / 1.50 ms before this layout, 0.70 / 1.10 / 0.88 ms with it. Nearly
+all of that is the geometry: at 256 x 512 the padding and the scale
+together gave 2%, the clamped index maps 8%. The kernels are bound by the
+matrix unit at half rate (a 64-wide head fills half of it in every product)
+and by per-tile work that does not shrink with the tile, so the largest
+tile wins, dead pairs and all. Tried and dropped, each measured: a third
+kind of tile, "interior" (every pair live, so no iota, compare or select:
+0% at 1024 keys, 0.5-2% at 4096, for a second body in every kernel);
+sweeping a grid step's keys tile by tile inside the kernel (``fori_loop``,
+bounds from the plan: 6% off ``flash_bwd_dq``, the other two slower); and
+building ``flash_bwd_dkv``'s score tile key-major so that its two
+transposed products become plain ones (1.096 against 1.106 ms: nothing).
+
+The same kernels run everywhere: compiled on TPU, interpret-mode in CPU
+tests (via DL4J_TPU_FORCE_PALLAS=1; plain CPU callers never reach them
+because flash_attention auto-dispatches to reference_attention off-TPU, and
+an explicit ``backend="pallas"`` there raises).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from deeplearning4j_tpu.kernels._dispatch import (
+    FlashBlocks,
     active_kernel_mesh as _active_kernel_mesh,
     flash_block_sizes as _flash_block_sizes,
     flash_min_seq as _flash_min_seq,
@@ -110,9 +139,136 @@ def reference_attention(q, k, v, *, causal=False, bias=None, key_mask=None,
     return jnp.einsum("bhts,bhsd->bhtd", p, v)
 
 
+@dataclasses.dataclass(frozen=True)
+class TilePlan:
+    """Which score tiles of a ``[seq_q, seq_k]`` attention a kernel with
+    ``[block_q, block_k]`` tiles has to touch.
+
+    The one place that knows it: the kernels ask it per grid step (``qi``,
+    ``ki`` are then traced ``program_id``s), the index maps ask it where a
+    dead step should point, the flight event and the tests ask it with
+    numbers. Every method takes ints, numpy arrays or traced values.
+
+    A tile is dead when every pair of it lies above the causal diagonal: it
+    is skipped and nothing is fetched for it. Every other tile is live.
+    """
+
+    seq_q: int
+    seq_k: int
+    block_q: int
+    block_k: int
+    causal: bool
+
+    @property
+    def n_q(self) -> int:
+        return -(-self.seq_q // self.block_q)
+
+    @property
+    def n_k(self) -> int:
+        return -(-self.seq_k // self.block_k)
+
+    @property
+    def offset(self) -> int:
+        """Query row i sees keys j <= i + offset (the causal offset of
+        cross-shaped calls)."""
+        return self.seq_k - self.seq_q
+
+    @property
+    def pads_q(self) -> bool:
+        return self.seq_q % self.block_q != 0
+
+    @property
+    def pads_k(self) -> bool:
+        return self.seq_k % self.block_k != 0
+
+    @property
+    def rows_can_be_empty(self) -> bool:
+        """Whether a real query row can have no live key at all: under
+        causal masking alone only when there are fewer keys than queries
+        (otherwise every row's first live block holds key 0)."""
+        return self.causal and self.seq_k < self.seq_q
+
+    def live(self, qi, ki):
+        if not self.causal:
+            return True
+        return (qi + 1) * self.block_q - 1 + self.offset >= ki * self.block_k
+
+    def last_live_k(self, qi):
+        """The last live key block of query block ``qi``, inside the array."""
+        if not self.causal:
+            return self.n_k - 1
+        return _xp(qi).clip(((qi + 1) * self.block_q - 1 + self.offset)
+                            // self.block_k, 0, self.n_k - 1)
+
+    def first_live_q(self, ki):
+        """The first live query block of key block ``ki``, inside the array."""
+        if not self.causal:
+            return 0
+        return _xp(ki).clip((ki * self.block_k - self.offset) // self.block_q,
+                            0, self.n_q - 1)
+
+    def fetch_k(self, qi, ki):
+        """The key block a (qi, ki) grid step fetches: its own while live,
+        the row's last live one after it, so a dead step copies nothing."""
+        if not self.causal:
+            return ki
+        return _xp(qi, ki).minimum(ki, self.last_live_k(qi))
+
+    def fetch_q(self, qi, ki):
+        """The same for the key-major sweep of ``flash_bwd_dkv``: dead steps
+        come first there and point at the column's first live block."""
+        if not self.causal:
+            return qi
+        return _xp(qi, ki).maximum(qi, self.first_live_q(ki))
+
+    def live_tiles(self) -> np.ndarray:
+        """``[n_q, n_k]`` of bool."""
+        qi = np.arange(self.n_q)[:, None]
+        ki = np.arange(self.n_k)[None, :]
+        return np.broadcast_to(self.live(qi, ki), (self.n_q, self.n_k))
+
+    def counts(self) -> dict:
+        live = int(self.live_tiles().sum())
+        return {"dead": self.n_q * self.n_k - live, "live": live}
+
+
+def _xp(*xs):
+    """jnp where any of ``xs`` is traced (a grid index), else numpy."""
+    return jnp if any(isinstance(x, jax.Array) for x in xs) else np
+
+
+def _tile_mask(plan, qi, ki, km_ref, shape):
+    """The mask of a live tile: key padding, the per-example key mask,
+    query padding (the backward's padded rows carry no residuals) and the
+    causal triangle; each term only where the shapes can make it false,
+    and None where none can."""
+    key_idx = ki * plan.block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    terms = []
+    if plan.pads_k:
+        terms.append(key_idx < plan.seq_k)
+    if km_ref is not None:
+        terms.append(km_ref[0] > 0)  # [1, bk] broadcasts over rows
+    if plan.causal or plan.pads_q:
+        query_idx = qi * plan.block_q + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 0)
+        if plan.pads_q:
+            terms.append(query_idx < plan.seq_q)
+        if plan.causal:
+            terms.append(query_idx + plan.offset >= key_idx)
+    if not terms:
+        return None
+    return jnp.broadcast_to(functools.reduce(operator.and_, terms), shape)
+
+
+def _scaled(q_ref, scale, mm):
+    """q times the softmax scale, on the ``[block_q, D]`` operand and not on
+    the ``[block_q, block_k]`` scores: in float32 before the cast, so it is
+    exact where the scale is a power of two (64 ** -0.5 is)."""
+    return (q_ref[0].astype(jnp.float32) * scale).astype(mm)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, lse_ref, m_scr, l_scr,
-                  acc_scr, *,
-                  scale, causal, has_mask, block_q, block_k, seq_q, seq_k):
+                  acc_scr, *, scale, plan):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     n_k = pl.num_programs(2)
@@ -123,36 +279,23 @@ def _flash_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, lse_ref, m_scr, l_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # Causal: a kv block whose smallest key index exceeds the largest query
-    # index is fully masked — skip its compute entirely.
-    q_hi = (qi + 1) * block_q - 1 + (seq_k - seq_q)
-    k_lo = ki * block_k
-    run = (not causal) or (q_hi >= k_lo)
-
-    @pl.when(run)
+    @pl.when(plan.live(qi, ki))
     def _compute():
         mm = _matmul_dtype(q_ref.dtype)
-        q = q_ref[0].astype(mm)
-        k = k_ref[0].astype(mm)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [bq, bk]
-        # Mask key padding (seq_k tail + per-example mask) and the causal
-        # triangle.
-        key_idx = k_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = key_idx < seq_k
-        if has_mask:
-            mask = mask & (km_ref[0] > 0)  # [1, bk] broadcasts over rows
-        if causal:
-            query_idx = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            mask = mask & (query_idx + (seq_k - seq_q) >= key_idx)
-        s = jnp.where(mask, s, _NEG_INF)
-
+            _scaled(q_ref, scale, mm), k_ref[0].astype(mm),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [bq, bk]
+        mask = _tile_mask(plan, qi, ki, km_ref, s.shape)
+        if mask is not None:
+            s = jnp.where(mask, s, _NEG_INF)
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # Explicitly zero masked probabilities: in a fully-masked block
-        # m_new stays _NEG_INF and exp(s - m_new) would be 1, not 0.
-        p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
+        p = jnp.exp(s - m_new)
+        if km_ref is not None or plan.rows_can_be_empty:
+            # A row masked whole so far keeps m_new at _NEG_INF, and
+            # exp(s - m_new) would be 1 there, not 0.
+            p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
@@ -188,138 +331,116 @@ def _pad_to(x, axis, multiple):
     return jnp.pad(x, widths)
 
 
-def _prep_blocks(q, k, v, key_mask, block_q, block_k):
-    """Tile-align block sizes and pad operands — shared by fwd and bwd so
-    their block geometry can never desynchronize."""
-    b, h, t, d = q.shape
-    s_len = k.shape[2]
-    # Blocks stay (8,128)-tile-aligned even for short sequences.
-    block_q = min(block_q, _round_up(t, 8))
-    block_k = min(block_k, _round_up(s_len, 128))
+def _clamp_blocks(block_q, block_k, t, s_len):
+    """Blocks stay (8,128)-tile-aligned even for short sequences."""
+    return min(block_q, _round_up(t, 8)), min(block_k, _round_up(s_len, 128))
 
-    qp = _pad_to(_pad_to(q.reshape(b * h, t, d), 1, block_q), 2, 128)
-    kp = _pad_to(_pad_to(k.reshape(b * h, s_len, d), 1, block_k), 2, 128)
-    vp = _pad_to(_pad_to(v.reshape(b * h, s_len, d), 1, block_k), 2, 128)
 
-    if key_mask is not None:
-        km = _pad_to(key_mask.astype(jnp.float32), 1, block_k)  # [B, tk]
-        # [B*H, 1, tk] — tiny; the unit middle dim keeps the Mosaic block
-        # shape (1, 1, block_k) legal (second-minor equals the array dim).
-        km = jnp.repeat(km, h, axis=0)[:, None, :]
-        km_block = block_k
-    else:
-        km = jnp.ones((b * h, 1, 1), jnp.float32)  # placeholder operand
-        km_block = 1
-    return qp, kp, vp, km, km_block, block_q, block_k
+def _rows(x, block):
+    """``[B, H, L, D]`` as the kernels take it: ``[B*H, L', D]`` with the
+    sequence padded to whole blocks (no copy where it already is) and the
+    head dimension as it came: a block whose last dimension is the array's
+    is legal in Mosaic at any width."""
+    b, h, length, d = x.shape
+    return _pad_to(x.reshape(b * h, length, d), 1, block)
+
+
+def _key_mask_rows(key_mask, heads, block_k):
+    """``[B, S]`` 1/0 as ``[B*H, 1, S']``: tiny; the unit middle dim keeps
+    the Mosaic block shape (1, 1, block_k) legal."""
+    km = _pad_to(key_mask.astype(jnp.float32), 1, block_k)
+    return jnp.repeat(km, heads, axis=0)[:, None, :]
 
 
 def _flash_fwd(q, k, v, key_mask, *, causal, scale, block_q, block_k,
                save_lse=False):
     b, h, t, d = q.shape
     s_len = k.shape[2]
-    qp, kp, vp, km, km_block, block_q, block_k = _prep_blocks(
-        q, k, v, key_mask, block_q, block_k)
-    dp = qp.shape[-1]
-    tq, tk = qp.shape[1], kp.shape[1]
-    has_mask = key_mask is not None
+    plan = TilePlan(t, s_len, block_q, block_k, causal)
+    qp, kp, vp = _rows(q, block_q), _rows(k, block_k), _rows(v, block_k)
+    tq = qp.shape[1]
 
-    params = dict(scale=scale, causal=causal, has_mask=has_mask,
-                  block_q=block_q, block_k=block_k, seq_q=t, seq_k=s_len)
+    def q_index(bh, qi, ki):
+        return (bh, qi, 0)
+
+    def kv_index(bh, qi, ki):
+        return (bh, plan.fetch_k(qi, ki), 0)
+
+    operands = [qp, kp, vp]
+    in_specs = [pl.BlockSpec((1, block_q, d), q_index),
+                pl.BlockSpec((1, block_k, d), kv_index),
+                pl.BlockSpec((1, block_k, d), kv_index)]
+    if key_mask is not None:
+        operands.append(_key_mask_rows(key_mask, h, block_k))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, block_k), lambda bh, qi, ki: (bh, 0, plan.fetch_k(qi, ki))))
+    out_specs = [pl.BlockSpec((1, block_q, d), q_index)]
+    out_shape = [jax.ShapeDtypeStruct((b * h, tq, d), q.dtype)]
     if save_lse:
-        kernel = functools.partial(_flash_kernel, **params)
-        out_specs = [
-            pl.BlockSpec((1, block_q, dp), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda bh, qi, ki: (bh, qi, 0)),
-        ]
-        out_shape = [
-            jax.ShapeDtypeStruct((b * h, tq, dp), q.dtype),
-            jax.ShapeDtypeStruct((b * h, tq, 128), jnp.float32),
-        ]
-    else:
-        def kernel(q_ref, k_ref, v_ref, km_ref, o_ref, m_scr, l_scr, acc_scr):
-            return _flash_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, None,
-                                 m_scr, l_scr, acc_scr, **params)
+        out_specs.append(pl.BlockSpec((1, block_q, 128), q_index))
+        out_shape.append(jax.ShapeDtypeStruct((b * h, tq, 128), jnp.float32))
 
-        out_specs = pl.BlockSpec((1, block_q, dp),
-                                 lambda bh, qi, ki: (bh, qi, 0))
-        out_shape = jax.ShapeDtypeStruct((b * h, tq, dp), q.dtype)
+    def kernel(*refs):
+        q_ref, k_ref, v_ref, *rest = refs
+        km_ref = rest.pop(0) if key_mask is not None else None
+        o_ref = rest.pop(0)
+        lse_ref = rest.pop(0) if save_lse else None
+        _flash_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, lse_ref, *rest,
+                      scale=scale, plan=plan)
 
-    km_index = (lambda bh, qi, ki: (bh, 0, ki)) if has_mask else (
-        lambda bh, qi, ki: (bh, 0, 0)
-    )
-    grid = (b * h, tq // block_q, tk // block_k)
     res = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, dp), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, dp), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, dp), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, 1, km_block), km_index),
-        ],
+        grid=(b * h, plan.n_q, plan.n_k),
+        in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, dp), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=_interpret(),
         name="flash_fwd",
-    )(qp, kp, vp, km)
-    out, lse = res if save_lse else (res, None)
-    return out[:, :t, :d].reshape(b, h, t, d), lse
+    )(*operands)
+    out = res[0][:, :t].reshape(b, h, t, d)
+    return out, (res[1] if save_lse else None)
 
 
 def _bwd_recompute(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref, delta_ref,
-                   qi, ki, *, scale, causal, has_mask, block_q, block_k,
-                   seq_q, seq_k):
+                   qi, ki, *, scale, plan):
     """Recompute p and ds for one (q-block, kv-block) pair — the math both
-    backward kernels share. Returns (q, k, g, p, ds); matmul inputs in the
-    MXU compute dtype (see _matmul_dtype), p/ds stats in fp32."""
+    backward kernels share. Returns (k, g, p, ds) in the MXU compute dtype
+    (see _matmul_dtype); ds lacks the softmax scale, which each kernel puts
+    on its ``[*, D]`` accumulator at the end."""
     mm = _matmul_dtype(q_ref.dtype)
-    q = q_ref[0].astype(mm)
     k = k_ref[0].astype(mm)
-    v = v_ref[0].astype(mm)
     g = g_ref[0].astype(mm)
-    # Clamp: padded / fully-masked rows carry lse ≈ -1e30; after the
-    # query-validity mask below their scores are -1e30 too, so the
-    # clamped difference underflows exp to exactly 0 (no inf·0 NaNs).
+    # Clamp: fully-masked rows carry lse ≈ -1e30; their scores are -1e30
+    # too, so the clamped difference underflows exp to exactly 0 (no
+    # inf·0 NaNs).
     lse = jnp.maximum(lse_ref[0][:, :1], -1e20)
     delta = delta_ref[0][:, :1]
 
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    k_lo = ki * block_k
-    key_idx = k_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    query_idx = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    mask = (key_idx < seq_k) & (query_idx < seq_q)
-    if has_mask:
-        mask = mask & (km_ref[0] > 0)
-    if causal:
-        mask = mask & (query_idx + (seq_k - seq_q) >= key_idx)
-    s = jnp.where(mask, s, _NEG_INF)
+        _scaled(q_ref, scale, mm), k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    mask = _tile_mask(plan, qi, ki, km_ref, s.shape)
+    if mask is not None:
+        s = jnp.where(mask, s, _NEG_INF)
     p = jnp.exp(s - lse)  # [bq, bk]; exactly 0 where masked
     dp = jax.lax.dot_general(
-        g, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    ds = p * (dp - delta) * scale
+        g, v_ref[0].astype(mm), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = p * (dp - delta)
     # p/ds feed straight into MXU matmuls at the call sites — hand them
     # over in the compute dtype (fp32 accumulation happens there).
-    return q, k, g, p.astype(mm), ds.astype(mm)
-
-
-def _causal_block_live(qi, ki, *, causal, block_q, block_k, seq_q, seq_k):
-    """False only for kv blocks entirely above the causal diagonal."""
-    q_hi = (qi + 1) * block_q - 1 + (seq_k - seq_q)
-    return (not causal) or (q_hi >= ki * block_k)
+    return k, g, p.astype(mm), ds.astype(mm)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref,
-                          delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                          **params):
+                          delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
+                          scale, plan):
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     n_q = pl.num_programs(2)
@@ -329,27 +450,27 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(_causal_block_live(qi, ki, **{k: params[k] for k in (
-        "causal", "block_q", "block_k", "seq_q", "seq_k")}))
+    @pl.when(plan.live(qi, ki))
     def _compute():
-        q, k, g, p, ds = _bwd_recompute(
+        _, g, p, ds = _bwd_recompute(
             q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref, delta_ref,
-            qi, ki, **params)
+            qi, ki, scale=scale, plan=plan)
         dv_scr[:] += jax.lax.dot_general(
             p, g, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         dk_scr[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds, q_ref[0].astype(ds.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32
         )
 
     @pl.when(qi == n_q - 1)
     def _finish():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref,
-                         delta_ref, dq_ref, dq_scr, **params):
+                         delta_ref, dq_ref, dq_scr, *, scale, plan):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     n_k = pl.num_programs(2)
@@ -358,120 +479,137 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(_causal_block_live(qi, ki, **{k: params[k] for k in (
-        "causal", "block_q", "block_k", "seq_q", "seq_k")}))
+    @pl.when(plan.live(qi, ki))
     def _compute():
-        q, k, g, p, ds = _bwd_recompute(
+        k, _, _, ds = _bwd_recompute(
             q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref, delta_ref,
-            qi, ki, **params)
+            qi, ki, scale=scale, plan=plan)
         dq_scr[:] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
     @pl.when(ki == n_k - 1)
     def _finish():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
-def _flash_bwd_impl(q, k, v, key_mask, out, lse, g, *, causal, scale,
-                    block_q, block_k):
-    """Blockwise backward; block geometry shared with fwd via _prep_blocks."""
+def _flash_bwd_call(kernel_fn, q, k, v, key_mask, g, lse, delta, *,
+                    causal, scale, block_q, block_k, key_major):
+    """One backward kernel at its own geometry, as (kernel, the keyword
+    arguments of its ``pallas_call``, operands). ``key_major``: the grid is
+    (bh, ki, qi), the query sweep innermost (``flash_bwd_dkv``); else
+    (bh, qi, ki) (``flash_bwd_dq``). The outputs come back padded."""
     b, h, t, d = q.shape
     s_len = k.shape[2]
-    qp, kp, vp, km, km_block, block_q, block_k = _prep_blocks(
-        q, k, v, key_mask, block_q, block_k)
-    gp = _pad_to(_pad_to(g.reshape(b * h, t, d), 1, block_q), 2, 128)
-    dp = qp.shape[-1]
-    tq, tk = qp.shape[1], kp.shape[1]
-    has_mask = key_mask is not None
+    plan = TilePlan(t, s_len, block_q, block_k, causal)
 
+    def ids(bh, i, j):
+        return (bh, j, i) if key_major else (bh, i, j)  # -> (bh, qi, ki)
+
+    def q_index(*grid):
+        bh, qi, ki = ids(*grid)
+        return (bh, plan.fetch_q(qi, ki) if key_major else qi, 0)
+
+    def kv_index(*grid):
+        bh, qi, ki = ids(*grid)
+        return (bh, ki if key_major else plan.fetch_k(qi, ki), 0)
+
+    def km_index(*grid):
+        bh, block, _ = kv_index(*grid)
+        return (bh, 0, block)
+
+    def rows_q(x):  # [BH, T(+), 128] residuals at this kernel's padding
+        return _pad_to(x[:, :t], 1, block_q)
+
+    q_spec = pl.BlockSpec((1, block_q, d), q_index)
+    kv_spec = pl.BlockSpec((1, block_k, d), kv_index)
+    row_spec = pl.BlockSpec((1, block_q, 128), q_index)
+    operands = [_rows(q, block_q), _rows(k, block_k), _rows(v, block_k)]
+    in_specs = [q_spec, kv_spec, kv_spec]
+    if key_mask is not None:
+        operands.append(_key_mask_rows(key_mask, h, block_k))
+        in_specs.append(pl.BlockSpec((1, 1, block_k), km_index))
+    operands += [_rows(g, block_q), rows_q(lse), rows_q(delta)]
+    in_specs += [q_spec, row_spec, row_spec]
+    tq, tk = operands[0].shape[1], operands[1].shape[1]
+
+    if key_major:
+        grid = (b * h, plan.n_k, plan.n_q)
+        out_specs = [kv_spec, kv_spec]
+        out_shape = [jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
+                     jax.ShapeDtypeStruct((b * h, tk, d), v.dtype)]
+        scratch = [pltpu.VMEM((block_k, d), jnp.float32)] * 2
+    else:
+        grid = (b * h, plan.n_q, plan.n_k)
+        out_specs = [q_spec]
+        out_shape = [jax.ShapeDtypeStruct((b * h, tq, d), q.dtype)]
+        scratch = [pltpu.VMEM((block_q, d), jnp.float32)]
+
+    def kernel(*refs):
+        q_ref, k_ref, v_ref, *rest = refs
+        km_ref = rest.pop(0) if key_mask is not None else None
+        kernel_fn(q_ref, k_ref, v_ref, km_ref, *rest, scale=scale, plan=plan)
+
+    return kernel, dict(
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
+        interpret=_interpret(),
+    ), operands
+
+
+def _flash_bwd_impl(q, k, v, key_mask, out, lse, g, *, causal, scale, blocks):
+    """Blockwise backward: two kernels, each at its own geometry."""
+    b, h, t, d = q.shape
+    s_len = k.shape[2]
     delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
-    delta = _pad_to(delta.reshape(b * h, t), 1, block_q)
-    delta = jnp.broadcast_to(delta[:, :, None], (b * h, tq, 128))
-
-    common = dict(scale=scale, causal=causal, has_mask=has_mask,
-                  block_q=block_q, block_k=block_k, seq_q=t, seq_k=s_len)
-    n_q, n_k = tq // block_q, tk // block_k
-
-    km_index_kq = (lambda bh, ki, qi: (bh, 0, ki)) if has_mask else (
-        lambda bh, ki, qi: (bh, 0, 0)
-    )
+    delta = jnp.broadcast_to(delta.reshape(b * h, t)[:, :, None],
+                             (b * h, t, 128))
+    args = (q, k, v, key_mask, g, lse, delta)
+    kernel, call, operands = _flash_bwd_call(
+        _flash_bwd_dkv_kernel, *args, causal=causal, scale=scale,
+        block_q=blocks.dkv[0], block_k=blocks.dkv[1], key_major=True)
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, **common),
-        grid=(b * h, n_k, n_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, dp), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, dp), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, dp), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, 1, km_block), km_index_kq),
-            pl.BlockSpec((1, block_q, dp), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda bh, ki, qi: (bh, qi, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, dp), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, dp), lambda bh, ki, qi: (bh, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, tk, dp), k.dtype),
-            jax.ShapeDtypeStruct((b * h, tk, dp), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, dp), jnp.float32),
-            pltpu.VMEM((block_k, dp), jnp.float32),
-        ],
-        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
-        interpret=_interpret(),
+        kernel,
         name="flash_bwd_dkv",
-    )(qp, kp, vp, km, gp, lse, delta)
-
-    km_index_qk = (lambda bh, qi, ki: (bh, 0, ki)) if has_mask else (
-        lambda bh, qi, ki: (bh, 0, 0)
-    )
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **common),
-        grid=(b * h, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, dp), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, dp), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, dp), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, 1, km_block), km_index_qk),
-            pl.BlockSpec((1, block_q, dp), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda bh, qi, ki: (bh, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, dp), lambda bh, qi, ki: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, tq, dp), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
-        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
-        interpret=_interpret(),
+        **call,
+    )(*operands)
+    kernel, call, operands = _flash_bwd_call(
+        _flash_bwd_dq_kernel, *args, causal=causal, scale=scale,
+        block_q=blocks.dq[0], block_k=blocks.dq[1], key_major=False)
+    dq, = pl.pallas_call(
+        kernel,
         name="flash_bwd_dq",
-    )(qp, kp, vp, km, gp, lse, delta)
+        **call,
+    )(*operands)
+    return (dq[:, :t].reshape(b, h, t, d),
+            dk[:, :s_len].reshape(b, h, s_len, d),
+            dv[:, :s_len].reshape(b, h, s_len, d))
 
-    dq = dq[:, :t, :d].reshape(b, h, t, d)
-    dk = dk[:, :s_len, :d].reshape(b, h, s_len, d)
-    dv = dv[:, :s_len, :d].reshape(b, h, s_len, d)
-    return dq, dk, dv
 
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, key_mask, causal, scale, block_q, block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash(q, k, v, key_mask, causal, scale, blocks):
+    """``blocks``: a ``FlashBlocks`` already clamped to the shapes."""
     out, _ = _flash_fwd(q, k, v, key_mask, causal=causal, scale=scale,
-                        block_q=block_q, block_k=block_k)
+                        block_q=blocks.fwd[0], block_k=blocks.fwd[1])
     return out
 
 
-def _flash_vjp_fwd(q, k, v, key_mask, causal, scale, block_q, block_k):
+def _flash_vjp_fwd(q, k, v, key_mask, causal, scale, blocks):
     out, lse = _flash_fwd(q, k, v, key_mask, causal=causal, scale=scale,
-                          block_q=block_q, block_k=block_k, save_lse=True)
+                          block_q=blocks.fwd[0], block_k=blocks.fwd[1],
+                          save_lse=True)
     return out, (q, k, v, key_mask, out, lse)
 
 
-def _flash_vjp_bwd(causal, scale, block_q, block_k, res, g):
+def _flash_vjp_bwd(causal, scale, blocks, res, g):
     q, k, v, key_mask, out, lse = res
     dq, dk, dv = _flash_bwd_impl(
         q, k, v, key_mask, out, lse, g,
-        causal=causal, scale=scale, block_q=block_q, block_k=block_k,
+        causal=causal, scale=scale, blocks=blocks,
     )
     dkm = jnp.zeros_like(key_mask) if key_mask is not None else None
     return dq, dk, dv, dkm
@@ -480,7 +618,7 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, res, g):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def _flash_on_mesh(mesh, q, k, v, key_mask, causal, scale, block_q, block_k):
+def _flash_on_mesh(mesh, q, k, v, key_mask, causal, scale, blocks):
     """The kernel under a multi-device mesh: inside ``shard_map``, batch
     split over the data-like axes and heads over the model axis (attention
     is independent across both, so no collective is needed); a dimension
@@ -501,8 +639,7 @@ def _flash_on_mesh(mesh, q, k, v, key_mask, causal, scale, block_q, block_k):
         specs.append(P(batch_axes or None, None))
 
     def local(q, k, v, *km):
-        return _flash(q, k, v, km[0] if km else None, causal, scale,
-                      block_q, block_k)
+        return _flash(q, k, v, km[0] if km else None, causal, scale, blocks)
 
     return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
                          out_specs=qkv_spec, check_vma=False)(*args)
@@ -528,9 +665,6 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None, bias=None,
     scale = (d ** -0.5) if scale is None else scale
     if backend not in (None, "pallas", "xla"):
         raise ValueError(f"backend must be None|'pallas'|'xla', got {backend!r}")
-    default_bq, default_bk = _flash_block_sizes()
-    block_q = default_bq if block_q is None else block_q
-    block_k = default_bk if block_k is None else block_k
     can_pallas = bias is None and q.shape[2] >= 8 and _use_pallas()
     if backend == "pallas" and not can_pallas:
         # an explicit request is a contract: a caller that asked for the
@@ -547,8 +681,28 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None, bias=None,
     if backend == "xla":
         return reference_attention(q, k, v, causal=causal, bias=bias,
                                    key_mask=key_mask, scale=scale)
+    t, s_len = q.shape[2], k.shape[2]
+    # an explicit block_q / block_k gives all three kernels that geometry
+    blocks = FlashBlocks(*[
+        _clamp_blocks(block_q or bq, block_k or bk, t, s_len)
+        for bq, bk in _flash_block_sizes(t, s_len, d, causal)])
+    _record_plan(t, s_len, d, causal, key_mask is not None, blocks)
     mesh = _active_kernel_mesh()
     if mesh is not None:
-        return _flash_on_mesh(mesh, q, k, v, key_mask, causal, scale,
-                              block_q, block_k)
-    return _flash(q, k, v, key_mask, causal, scale, block_q, block_k)
+        return _flash_on_mesh(mesh, q, k, v, key_mask, causal, scale, blocks)
+    return _flash(q, k, v, key_mask, causal, scale, blocks)
+
+
+def _record_plan(seq_q, seq_k, head_dim, causal, has_mask, blocks):
+    """One ``kernel.flash_plan`` flight event per call, at trace time (a
+    jitted step traces its calls once, so this costs a run nothing): the
+    geometry of each kernel and how many of its tiles are dead (skipped,
+    nothing fetched) and live."""
+    from deeplearning4j_tpu.observability.flightrecorder import record_event
+
+    record_event(
+        "kernel.flash_plan", seq_q=seq_q, seq_k=seq_k, head_dim=head_dim,
+        causal=causal, key_mask=has_mask,
+        **{name: {"block_q": bq, "block_k": bk,
+                  **TilePlan(seq_q, seq_k, bq, bk, causal).counts()}
+           for name, (bq, bk) in blocks._asdict().items()})

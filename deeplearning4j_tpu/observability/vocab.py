@@ -125,6 +125,11 @@ COMPILE_KINDS = frozenset({
     "compile_cache.stale_metadata",
 })
 
+# Pallas kernels, at trace time (kernels/flash_attention.py)
+KERNEL_KINDS = frozenset({
+    "kernel.flash_plan",
+})
+
 # observability plane's own events (sentinel, SLO, profiling, recorder)
 OBSERVABILITY_KINDS = frozenset({
     "anomaly.transition",
@@ -176,7 +181,7 @@ TELEMETRY_KINDS = frozenset({
 
 EVENT_KINDS = frozenset().union(
     SERVING_KINDS, GENERATION_KINDS, ROUTER_KINDS, TRAIN_KINDS,
-    RESILIENCE_KINDS, COMPILE_KINDS, OBSERVABILITY_KINDS,
+    RESILIENCE_KINDS, COMPILE_KINDS, KERNEL_KINDS, OBSERVABILITY_KINDS,
     SANITIZER_KINDS, CACHE_KINDS, REPLAY_KINDS, TELEMETRY_KINDS,
     AUTOSCALER_KINDS)
 

@@ -84,7 +84,11 @@ def _max_rel_err(a, b):
     return float(np.abs(a - b).max() / denom)
 
 
-def _flash_ab(iters=30, B=8, H=12, T=512, D=64, causal=False):
+def _flash_ab(iters=30, B=8, H=12, T=512, D=64, causal=False,
+              dtype="float32", masked=True):
+    """``masked``: a key-padding mask of random lengths rides along (the
+    BERT case); without it and in bfloat16 the leg is a training cell's own
+    call. The oracle always computes from the same values in float32."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -95,19 +99,24 @@ def _flash_ab(iters=30, B=8, H=12, T=512, D=64, causal=False):
     )
 
     r = np.random.default_rng(0)
-    q = jnp.asarray(r.normal(size=(B, H, T, D)), jnp.float32)
-    k = jnp.asarray(r.normal(size=(B, H, T, D)), jnp.float32)
-    v = jnp.asarray(r.normal(size=(B, H, T, D)), jnp.float32)
+    q = jnp.asarray(r.normal(size=(B, H, T, D)), dtype)
+    k = jnp.asarray(r.normal(size=(B, H, T, D)), dtype)
+    v = jnp.asarray(r.normal(size=(B, H, T, D)), dtype)
     lens = r.integers(T // 2, T + 1, B)
     key_mask = jnp.asarray(
-        (np.arange(T)[None, :] < lens[:, None]).astype(np.float32))
+        (np.arange(T)[None, :] < lens[:, None]).astype(np.float32)
+    ) if masked else None
 
-    out = {"shape": f"B{B} H{H} T{T} D{D}", "iters": iters}
+    out = {"shape": f"B{B} H{H} T{T} D{D} {dtype}", "iters": iters}
+
+    def reference(q, k, v):
+        return reference_attention(
+            *(x.astype(jnp.float32) for x in (q, k, v)),
+            key_mask=key_mask, causal=causal)
 
     flash_f = jax.jit(lambda q, k, v: flash_attention(
         q, k, v, key_mask=key_mask, causal=causal, backend="pallas"))
-    ref_f = jax.jit(lambda q, k, v: reference_attention(
-        q, k, v, key_mask=key_mask, causal=causal))
+    ref_f = jax.jit(reference)
 
     of, orf = flash_f(q, k, v), ref_f(q, k, v)
     # Padded key rows of the reference produce uniform-attention outputs that
@@ -119,11 +128,10 @@ def _flash_ab(iters=30, B=8, H=12, T=512, D=64, causal=False):
     def loss_flash(q, k, v):
         return jnp.sum(flash_attention(
             q, k, v, key_mask=key_mask, causal=causal,
-            backend="pallas") ** 2)
+            backend="pallas").astype(jnp.float32) ** 2)
 
     def loss_ref(q, k, v):
-        return jnp.sum(reference_attention(
-            q, k, v, key_mask=key_mask, causal=causal) ** 2)
+        return jnp.sum(reference(q, k, v) ** 2)
 
     gflash = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))
     gref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))
@@ -225,9 +233,10 @@ def _gru_ab(iters=30):
 def _flash_tune(iters=8, B=8, H=12, T=512, D=64, causal=False):
     """On-chip block-size sweep for the flash kernel.
 
-    Times fwd+bwd at each (block_q, block_k) geometry and reports the best;
-    the dispatch defaults (kernels/_dispatch.flash_block_sizes) can then be
-    promoted via DL4J_TPU_FLASH_BLOCK_Q/K without a code change.
+    Times fwd+bwd with all three kernels at each (block_q, block_k) and
+    reports the best. The dispatch defaults
+    (kernels/_dispatch.flash_block_sizes) came from a per-kernel sweep of
+    the same grid read from a profiler trace (PERF.md section 5).
     """
     import jax
     import jax.numpy as jnp
@@ -299,6 +308,11 @@ def run_kernels_ab(diag: dict, include_tune: bool = True,
     # a recorded number rather than interpolation.
     flash_1024 = lambda: _flash_ab(iters=15, B=4, H=12, T=1024, D=64,
                                    causal=True)
+    # gpt2_small.train_s1024's own call (PERF.md section 4): bf16, causal,
+    # no key mask, the geometry the dispatch chooses for it.
+    flash_cell = lambda: _flash_ab(iters=10, B=16, H=12, T=1024, D=64,
+                                   causal=True, dtype="bfloat16",
+                                   masked=False)
     tune_long = lambda: _flash_tune(iters=6, B=2, H=8, T=2048, D=64,
                                     causal=True)
     tune_1024 = lambda: _flash_tune(iters=8, B=4, H=12, T=1024, D=64,
@@ -308,6 +322,7 @@ def run_kernels_ab(diag: dict, include_tune: bool = True,
                  ("flash_tune_2048", tune_long)] if include_tune else []
     legs = ([("flash_attention", _flash_ab),
              ("flash_attention_1024", flash_1024),
+             ("flash_attention_cell", flash_cell),
              ("flash_attention_long", flash_long)]
             + tune_legs
             + [("lstm_scan", _lstm_ab), ("gru_scan", _gru_ab)])

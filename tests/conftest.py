@@ -29,6 +29,17 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """The flash kernel's dispatch as on the chip, so that lowering (or
+    compiling) for the TPU platform from this CPU host reaches Mosaic."""
+    from deeplearning4j_tpu.kernels import flash_attention as fa
+
+    for name in ("_use_pallas", "_on_tpu"):
+        monkeypatch.setattr(fa, name, lambda: True)
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+
+
 # -- shared mixed predict+generation server ------------------------------------
 #
 # ONE tiny-GPT engine + one batched predict model behind one ModelServer,

@@ -68,6 +68,46 @@ def test_flash_causal_key_mask_matches_reference(monkeypatch):
     )
 
 
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_key_mask_with_rows_masked_whole(monkeypatch, causal):
+    """A ``key_mask`` can mask a row whole. Example 0 loses its
+    first 40 keys (under ``causal`` its first 40 rows then see no key at
+    all), example 1 its last 28, example 2 every key: rows masked whole
+    read 0, every other row and all three gradients follow the reference."""
+    monkeypatch.setenv("DL4J_TPU_FORCE_PALLAS", "1")
+    t = 128
+    ks = jax.random.split(jax.random.key(7), 3)
+    q, k, v = (jax.random.normal(kk, (3, 2, t, 64)) for kk in ks)
+    mask = jnp.ones((3, t)).at[0, :40].set(0.0).at[1, 100:].set(0.0)
+    mask = mask.at[2].set(0.0)
+    seen = np.ones((3, 1, t, 1), bool)  # rows with at least one live key
+    seen[2] = False
+    if causal:
+        seen[0, :, :40] = False
+    w = jnp.asarray(seen, jnp.float32) * jnp.cos(
+        jnp.arange(3 * 2 * t * 64, dtype=jnp.float32)).reshape(3, 2, t, 64)
+
+    def grads(fn):
+        def loss(q, k, v):
+            return jnp.sum(fn(q, k, v, causal=causal, key_mask=mask) * w)
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    def flash(*a, **kw):
+        return flash_attention(*a, block_q=32, block_k=32, **kw)
+
+    out = np.asarray(flash(q, k, v, causal=causal, key_mask=mask))
+    want = np.asarray(reference_attention(q, k, v, causal=causal,
+                                          key_mask=mask))
+    keep = np.broadcast_to(seen, out.shape)
+    np.testing.assert_allclose(out[keep], want[keep], atol=5e-5, rtol=1e-4)
+    assert not out[~keep].any()
+    for g, r in zip(jax.tree_util.tree_leaves(grads(flash)),
+                    jax.tree_util.tree_leaves(grads(reference_attention))):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=5e-4, rtol=1e-3)
+
+
 def test_self_attention_shapes_and_mask():
     layer = SelfAttention(num_heads=4, out_size=32)
     rng = jax.random.key(0)

@@ -1,0 +1,80 @@
+"""The flash kernels at their default geometry, compiled for a described
+TPU v5e (no chip attached): what Mosaic refuses (a tile that does not fit
+VMEM, a block it cannot lay out) fails here and not on the chip. Nothing
+runs, so nothing here is a time or a result. One file, one fixture: the
+worker that is given this file is the only one that loads the TPU's
+compiler."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.kernels import flash_attention as fa
+from deeplearning4j_tpu.kernels._dispatch import FlashBlocks, flash_block_sizes
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler installed here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# (batch, heads, queries, keys, head width), dtype, causal, key mask, and
+# optionally the caller's own blocks and environment
+_CALLS = {
+    "gpt2_small.train_s1024": ((16, 12, 1024, 1024, 64), "bfloat16", True,
+                               False),
+    "long_context_4096": ((2, 12, 4096, 4096, 64), "bfloat16", True, False),
+    "bert_like_masked_float32": ((2, 8, 2048, 2048, 128), "float32", False,
+                                 True),
+    "causal_masked_float32_128_wide": ((2, 8, 2048, 2048, 128), "float32",
+                                       True, True),
+    "float32_products_128_wide": ((2, 8, 2048, 2048, 128), "float32", True,
+                                  True, None, {"DL4J_TPU_FLASH_FP32": "1"}),
+    "ragged_1000_masked": ((2, 8, 1000, 1000, 64), "bfloat16", True, True),
+    "cross_512_by_2048": ((2, 8, 512, 2048, 64), "bfloat16", True, False),
+    "wide_heads_float32": ((2, 4, 2048, 2048, 256), "float32", True, False),
+    # parallel/sequence.py::ulysses_attention: the whole sequence, a
+    # quarter of the heads, its own 256 x 256 blocks, the gathered mask
+    "ulysses_local_call": ((2, 3, 4096, 4096, 64), "bfloat16", True, True,
+                           (256, 256)),
+    # the same call left to the default geometry, not causal
+    "whole_sequence_not_causal_masked": ((2, 3, 4096, 4096, 64), "bfloat16",
+                                         False, True),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_CALLS))
+def test_forward_and_backward_compile_at_the_default_geometry(
+        call, one_chip, as_on_tpu, monkeypatch):
+    (b, h, t, s, d), dtype, causal, masked, *rest = _CALLS[call]
+    own_blocks, env = (rest + [None, None])[:2]
+    for name, value in (env or {}).items():
+        monkeypatch.setenv(name, value)
+    blocks = flash_block_sizes(t, s, d, causal)
+    if own_blocks:
+        blocks = FlashBlocks(*[own_blocks] * 3)
+    blocks = FlashBlocks(*[fa._clamp_blocks(bq, bk, t, s)
+                           for bq, bk in blocks])
+    q = jax.ShapeDtypeStruct((b, h, t, d), dtype, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, h, s, d), dtype, sharding=one_chip)
+    args = (q, k, k)
+    if masked:
+        args += (jax.ShapeDtypeStruct((b, s), jnp.float32,
+                                      sharding=one_chip),)
+
+    def loss(q, k, v, *key_mask):
+        out = fa._flash(q, k, v, key_mask[0] if key_mask else None, causal,
+                        d ** -0.5, blocks)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3

@@ -1,0 +1,151 @@
+"""The flash kernels' tile plan (kernels/flash_attention.py::TilePlan): the
+one function that says which score tiles are dead and which are live,
+checked here against the dense mask it stands for; and the flight event
+that reports it at trace time."""
+
+import itertools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu.kernels import flash_attention as fa
+from deeplearning4j_tpu.kernels._dispatch import flash_block_sizes
+from deeplearning4j_tpu.observability import vocab
+from deeplearning4j_tpu.observability.flightrecorder import (
+    FlightRecorder,
+    get_flight_recorder,
+    set_flight_recorder,
+)
+
+_SEQS = (8, 24, 100, 128, 256)
+_BLOCKS = (8, 32, 128)
+
+
+def _dense_mask(plan):
+    i = np.arange(plan.seq_q)[:, None]
+    j = np.arange(plan.seq_k)[None, :]
+    return (j <= i + plan.offset) if plan.causal else np.ones(
+        (plan.seq_q, plan.seq_k), bool)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("seq_q,seq_k", itertools.product(_SEQS, _SEQS))
+def test_the_plan_is_the_dense_mask(seq_q, seq_k, causal):
+    for block_q, block_k in itertools.product(_BLOCKS, _BLOCKS):
+        plan = fa.TilePlan(seq_q, seq_k, block_q, block_k, causal)
+        dense = _dense_mask(plan)
+        live = plan.live_tiles()
+        assert live.shape == (plan.n_q, plan.n_k)
+        assert plan.counts() == {"dead": int((~live).sum()),
+                                 "live": int(live.sum())}
+        rebuilt = np.zeros_like(dense)
+        for qi, ki in np.ndindex(*live.shape):
+            rows = slice(qi * block_q, (qi + 1) * block_q)
+            cols = slice(ki * block_k, (ki + 1) * block_k)
+            if live[qi, ki]:  # what it contributes once its mask is applied
+                rebuilt[rows, cols] = dense[rows, cols]
+            else:
+                assert not dense[rows, cols].any()
+        np.testing.assert_array_equal(rebuilt, dense)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("seq_q,seq_k", itertools.product(_SEQS, _SEQS))
+def test_a_dead_step_fetches_nothing_new(seq_q, seq_k, causal):
+    for block_q, block_k in itertools.product(_BLOCKS, _BLOCKS):
+        plan = fa.TilePlan(seq_q, seq_k, block_q, block_k, causal)
+        live = plan.live_tiles()
+        qi = np.arange(plan.n_q)[:, None]
+        ki = np.arange(plan.n_k)[None, :]
+        fetch_k = np.broadcast_to(plan.fetch_k(qi, ki), live.shape)
+        fetch_q = np.broadcast_to(plan.fetch_q(qi, ki), live.shape)
+        for fetched, n in ((fetch_k, plan.n_k), (fetch_q, plan.n_q)):
+            assert fetched.min() >= 0 and fetched.max() < n
+        np.testing.assert_array_equal(fetch_k[live], np.broadcast_to(
+            ki, live.shape)[live])
+        np.testing.assert_array_equal(fetch_q[live], np.broadcast_to(
+            qi, live.shape)[live])
+        # query-major sweeps (flash_fwd, flash_bwd_dq): dead steps follow
+        # the row's live ones and keep pointing at the last block fetched
+        dead_after = ~live[:, 1:] & live[:, :1].repeat(plan.n_k - 1, 1)
+        np.testing.assert_array_equal(fetch_k[:, 1:][dead_after],
+                                      fetch_k[:, :-1][dead_after])
+        # key-major sweep (flash_bwd_dkv): dead steps come first and point
+        # at the block the column's first live step will want
+        dead_before = ~live[:-1] & live[-1:].repeat(plan.n_q - 1, 0)
+        np.testing.assert_array_equal(fetch_q[:-1][dead_before],
+                                      fetch_q[1:][dead_before])
+
+
+def test_the_plan_answers_traced_indices_like_numbers():
+    plan = fa.TilePlan(100, 128, 32, 32, True)
+    qi, ki = np.meshgrid(np.arange(plan.n_q), np.arange(plan.n_k),
+                         indexing="ij")
+
+    def answers(qi, ki):
+        return (plan.live(qi, ki), plan.fetch_k(qi, ki),
+                plan.fetch_q(qi, ki))
+
+    for got, want in zip(jax.jit(answers)(jnp.asarray(qi), jnp.asarray(ki)),
+                         answers(qi, ki)):
+        np.testing.assert_array_equal(
+            np.asarray(got), np.broadcast_to(want, qi.shape))
+
+
+@pytest.fixture
+def flight(monkeypatch):
+    monkeypatch.setenv("DL4J_TPU_FORCE_PALLAS", "1")
+    before = get_flight_recorder()
+    yield set_flight_recorder(FlightRecorder())
+    set_flight_recorder(before)
+
+
+def test_the_plan_is_a_flight_event_of_every_traced_call(flight):
+    assert "kernel.flash_plan" in vocab.known_event_kinds()
+    q = jnp.ones((1, 2, 128, 16), jnp.float32)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, block_q=32,
+                                 block_k=64)
+        return jnp.sum(fa.flash_attention(out, k, v, causal=False,
+                                          block_q=64, block_k=128))
+
+    step = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    step.lower(q, q, q)  # trace only
+    first, second = flight.events(kinds=["kernel.flash_plan"])  # two layers
+    data = first["data"]
+    assert (data["seq_q"], data["seq_k"], data["head_dim"]) == (128, 128, 16)
+    assert data["causal"] is True and data["key_mask"] is False
+    want = {"block_q": 32, "block_k": 64, "dead": 2, "live": 6}
+    assert data["fwd"] == data["dkv"] == data["dq"] == want
+    assert second["data"]["causal"] is False
+    assert second["data"]["dq"] == {"block_q": 64, "block_k": 128,
+                                    "dead": 0, "live": 2}
+    # a recorder armed later sees the plans of the next trace as well
+    later = set_flight_recorder(FlightRecorder())
+    jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
+    assert len(later.events(kinds=["kernel.flash_plan"])) == 2
+
+
+def test_the_default_plan_at_the_benchmarks_shape(flight):
+    """The counts PERF.md quotes for ``gpt2_small.train_s1024``, from the
+    geometry ``flash_block_sizes`` chose on the chip."""
+    blocks = flash_block_sizes(1024, 1024, 64, True)
+    q = jax.ShapeDtypeStruct((16, 12, 1024, 64), jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+                   q, q, q)
+    data = flight.events(kinds=["kernel.flash_plan"])[0]["data"]
+    for name, (block_q, block_k) in blocks._asdict().items():
+        counts = fa.TilePlan(1024, 1024, block_q, block_k, True).counts()
+        assert data[name] == {"block_q": block_q, "block_k": block_k,
+                              **counts}
+    assert _QUOTED == {name: data[name] for name in ("fwd", "dkv", "dq")}
+
+
+_QUOTED = {
+    "fwd": {"block_q": 1024, "block_k": 1024, "dead": 0, "live": 1},
+    "dkv": {"block_q": 512, "block_k": 512, "dead": 1, "live": 3},
+    "dq": {"block_q": 1024, "block_k": 1024, "dead": 0, "live": 1},
+}

@@ -67,7 +67,8 @@ class TestInterpretOnlyByFlag:
         monkeypatch.delenv("DL4J_TPU_FORCE_PALLAS", raising=False)
         q, k, v = _qkv()
         with pytest.raises(RuntimeError, match="DL4J_TPU_FORCE_PALLAS"):
-            fa._flash(q, k, v, None, False, 0.25, 32, 128)
+            fa._flash(q, k, v, None, False, 0.25,
+                      _dispatch.FlashBlocks(*[(32, 128)] * 3))
 
 
 class TestExplicitPallasIsAContract:
@@ -105,19 +106,57 @@ class TestExplicitPallasIsAContract:
             rtol=2e-5, atol=2e-6)
 
 
+class TestTheStepLowersForTheChip:
+    """The two benchmark steps at tiny depth and the cells' own sequence
+    lengths and head width (64), lowered for the TPU platform."""
+
+    @staticmethod
+    def _lowered(model, batch):
+        from deeplearning4j_tpu.train.trainer import Trainer
+
+        trainer = Trainer(model)
+        return trainer.train_step.trace(trainer.init_state(), batch).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    def test_gpt2_shaped_step_feeds_the_kernels_64_wide(self, as_on_tpu):
+        """3 ``tpu_custom_call``s a block, by name, q, k and v 64 wide."""
+        import re
+
+        from deeplearning4j_tpu.models.gpt import gpt_tiny
+
+        model = gpt_tiny(hidden=128, num_heads=2, max_position=1024)
+        text = self._lowered(model, {"features": {
+            "token_ids": np.zeros((2, 1024), np.int32)}})
+        calls = [line for line in text.splitlines()
+                 if "@tpu_custom_call" in line]
+        names = [re.search(r'kernel_name = "(\w+)"', c).group(1)
+                 for c in calls]
+        assert sorted(names) == sorted(
+            ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+            * model.config.num_layers)
+        for call in calls:  # q, k, v come first: [batch x heads, T, 64]
+            operands = re.search(r" : \(([^)]*)\) -> ", call).group(1)
+            qkv = re.findall(r"tensor<([0-9x]+)x\w+>", operands)[:3]
+            assert qkv == ["4x1024x64"] * 3, call[-400:]
+        assert not [line for line in text.splitlines()
+                    if "stablehlo.pad" in line
+                    and "-> tensor<4x1024x128x" in line]
+
+    def test_bert_shaped_step_at_128_holds_no_kernel(self, as_on_tpu):
+        from deeplearning4j_tpu.models.bert import bert_tiny, make_mlm_batch
+
+        model = bert_tiny()  # heads of 64, T = 128 < flash_min_seq()
+        batch = make_mlm_batch(0, batch_size=2, seq_len=128,
+                               vocab_size=model.config.vocab_size,
+                               max_predictions=8, pad_frac=0.2)
+        assert "tpu_custom_call" not in self._lowered(model, batch)
+
+
 class TestFlashUnderAMesh:
     @pytest.fixture(scope="class")
     def mesh(self):
         return build_mesh(MeshSpec(data=-1, model=2),
                           devices_=jax.devices()[:4])
-
-    @pytest.fixture
-    def as_on_tpu(self, monkeypatch):
-        """Dispatch as on the chip, so that lowering for the TPU platform
-        from this CPU host reaches Mosaic's own lowering."""
-        for name in ("_use_pallas", "_on_tpu"):
-            monkeypatch.setattr(fa, name, lambda: True)
-        monkeypatch.setattr(fa, "_interpret", lambda: False)
 
     def test_unpublished_mesh_is_refused_by_mosaic_lowering(
             self, mesh, as_on_tpu):

@@ -12,6 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deeplearning4j_tpu.kernels import flash_attention as fa
+from deeplearning4j_tpu.kernels._dispatch import FlashBlocks
 from deeplearning4j_tpu.kernels.flash_attention import (
     flash_attention,
     reference_attention,
@@ -32,11 +34,14 @@ def _qkv(seed, b=2, h=2, t=32, s=None, d=16):
     return q, k, v
 
 
-def _grads(fn, q, k, v):
-    # Scalar loss with a fixed weighting so every output element matters.
+def _grads(fn, q, k, v, first_row=0):
+    # Scalar loss with a fixed weighting so every output element matters
+    # (from query row ``first_row`` on: the rows before it see no key, and
+    # there the reference attends uniformly where the kernel writes 0).
     w = jnp.cos(jnp.arange(q.shape[0] * q.shape[1] * q.shape[2] * v.shape[-1],
                            dtype=jnp.float32)).reshape(
         q.shape[0], q.shape[1], q.shape[2], v.shape[-1])
+    w = w * (jnp.arange(q.shape[2]) >= first_row)[:, None]
 
     def loss(q, k, v):
         return jnp.sum(fn(q, k, v) * w)
@@ -104,6 +109,58 @@ def test_flash_single_kv_iteration_block(causal):
     ref = functools.partial(reference_attention, causal=causal)
     out = fn(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
+                               atol=5e-5, rtol=1e-4)
+    _assert_grads_close(_grads(fn, q, k, v), _grads(ref, q, k, v))
+
+
+# name: (t, s, d, causal, block, (dead, live) tiles of the plan, first row
+# that sees a key). Blocks of 32 x 32 make a 4 x 4 grid of T = S = 128: 6
+# tiles above the diagonal, 4 on it, 6 under it.
+_TILE_CASES = {
+    "d64_dead_interior_diagonal": (128, 128, 64, True, 32, (6, 10), 0),
+    "d40_no_multiple_of_64": (128, 128, 40, True, 32, (6, 10), 0),
+    "ragged_queries_and_keys_causal": (100, 100, 16, True, 32, (6, 10), 0),
+    "ragged_not_causal": (100, 72, 16, False, 32, (0, 12), 0),
+    "offset_more_keys_than_queries": (64, 128, 16, True, 32, (1, 7), 0),
+    "offset_fewer_keys_rows_without_a_key": (128, 64, 16, True, 32,
+                                             (5, 3), 64),
+    "whole_blocks_not_causal": (64, 96, 16, False, 32, (0, 6), 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TILE_CASES))
+def test_flash_every_tile_kind_matches_reference(case):
+    """Forward and all three gradients where one call holds dead tiles,
+    tiles under the diagonal and tiles the diagonal crosses, at head widths
+    that are never padded."""
+    t, s, d, causal, block, (dead, live), first_row = _TILE_CASES[case]
+    plan = fa.TilePlan(t, s, block, block, causal)
+    assert plan.counts() == {"dead": dead, "live": live}
+    q, k, v = _qkv(5, b=1, t=t, s=s, d=d)
+    fn = functools.partial(flash_attention, causal=causal,
+                           block_q=block, block_k=block)
+    ref = functools.partial(reference_attention, causal=causal)
+    got = fn(q, k, v)
+    np.testing.assert_allclose(np.asarray(got)[:, :, first_row:],
+                               np.asarray(ref(q, k, v))[:, :, first_row:],
+                               atol=5e-5, rtol=1e-4)
+    assert not np.asarray(got)[:, :, :first_row].any()
+    _assert_grads_close(_grads(fn, q, k, v, first_row),
+                        _grads(ref, q, k, v, first_row))
+
+
+def test_flash_kernels_at_three_geometries():
+    """Each kernel at its own blocks, none dividing T: the residuals are
+    re-padded per kernel."""
+    q, k, v = _qkv(6, b=1, t=100, d=16)
+    blocks = FlashBlocks(fwd=(32, 64), dkv=(64, 32), dq=(16, 48))
+
+    def fn(q, k, v):
+        return fa._flash(q, k, v, None, True, 0.25, blocks)
+
+    ref = functools.partial(reference_attention, causal=True)
+    np.testing.assert_allclose(np.asarray(fn(q, k, v)),
+                               np.asarray(ref(q, k, v)),
                                atol=5e-5, rtol=1e-4)
     _assert_grads_close(_grads(fn, q, k, v), _grads(ref, q, k, v))
 
@@ -185,9 +242,11 @@ class TestLstmBackward:
         self._compare(2, use_peep=False, forget_bias=1.0)
 
 
-def test_flash_bwd_bf16_finite():
-    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(4))
-    dq, dk, dv = _grads(flash_attention, q, k, v)
+@pytest.mark.parametrize("d,causal", [(16, False), (64, True)])
+def test_flash_bwd_bf16_finite(d, causal):
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(4, t=64, d=d))
+    dq, dk, dv = _grads(functools.partial(
+        flash_attention, causal=causal, block_q=32, block_k=32), q, k, v)
     for g in (dq, dk, dv):
         assert g.dtype == jnp.bfloat16
         assert bool(jnp.all(jnp.isfinite(g.astype(jnp.float32))))

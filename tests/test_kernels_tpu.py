@@ -77,6 +77,16 @@ def test_flash_attention_long_context_parity(ab_result):
     assert fl["parity"], fl
 
 
+def test_flash_attention_cell_shape_parity(ab_result):
+    """``gpt2_small.train_s1024``'s own call, [16, 12, 1024, 64] bf16
+    causal, at the geometry the dispatch chooses for it."""
+    fc = ab_result.get("flash_attention_cell")
+    assert fc is not None, sorted(ab_result)
+    assert fc["parity"], fc
+    assert fc["fwd_max_rel_err"] < 2e-2
+    assert fc["bwd_max_rel_err"] < 2e-2
+
+
 def test_gru_compiled_parity(ab_result):
     gs = ab_result["gru_scan"]
     assert "error" not in gs, gs

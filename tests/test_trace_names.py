@@ -187,7 +187,8 @@ def test_the_flash_kernels_are_called_by_name(monkeypatch):
     q = jnp.ones((1, 2, 128, 16), jnp.float32)
 
     def loss(q, k, v):
-        return jnp.sum(fa._flash(q, k, v, None, True, 0.25, 128, 128))
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True, scale=0.25,
+                                          block_q=128, block_k=128))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, q, q).as_text(debug_info=True)
