@@ -26,7 +26,11 @@ from deeplearning4j_tpu.kernels.flash_attention import flash_attention
 from deeplearning4j_tpu.nn.activations import get_activation
 from deeplearning4j_tpu.nn.config import LayerConfig, register_config
 from deeplearning4j_tpu.nn.initializers import get_initializer
-from deeplearning4j_tpu.observability.vocab import SCOPE_ATTN, SCOPE_MLP
+from deeplearning4j_tpu.observability.vocab import (
+    SCOPE_ATTN,
+    SCOPE_CCA_MIX,
+    SCOPE_MLP,
+)
 from deeplearning4j_tpu.ops import nn as opsnn
 
 
@@ -66,6 +70,91 @@ def _attend_tail(y_heads, params, *, dropout, train, rng, project=True):
     if project:
         y = opsnn.linear(y, params["Wo"], params.get("bo"))
     return y
+
+
+def _shift(x, by):
+    """``x`` [N,T,...] moved ``by`` positions later, zeros coming in."""
+    if by == 0:
+        return x
+    pad = [(0, 0), (by, 0)] + [(0, 0)] * (x.ndim - 2)
+    return jnp.pad(x, pad)[:, : x.shape[1]]
+
+
+def _causal_convs(x, w0, w1):
+    """``x`` [N,T,heads,d] through a depthwise causal convolution along
+    the sequence (``w0`` [taps, heads * d]) and then a causal convolution
+    grouped by head (``w1`` [taps, heads, d, d]); each one's last tap
+    reads the token itself."""
+    heads, d = x.shape[2:]
+    w0 = w0.reshape(-1, heads, d)
+    y = sum(_shift(x, len(w0) - 1 - j) * w0[j] for j in range(len(w0)))
+    return sum(jnp.einsum("nthi,hio->ntho", _shift(y, len(w1) - 1 - j), w1[j])
+               for j in range(len(w1)))
+
+
+def _rotary(x, theta, share):
+    """Rotary positions on the first ``share`` of the last axis of ``x``
+    [N,T,heads,d] (float32); dimension i is paired with i + half."""
+    t, d = x.shape[1], x.shape[-1]
+    turned = int(d * share)
+    half = turned // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / turned)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None]
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    a, b, rest = x[..., :half], x[..., half:turned], x[..., turned:]
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin, rest], axis=-1)
+
+
+def cca_attention(params, h, *, num_heads: int, num_kv_heads: int,
+                  rope_theta: float, rotary_share: float):
+    """Compressed convolutional attention (CCA, arXiv:2510.04476) over
+    ``h`` [N,T,E], already normed: causal attention wholly inside a
+    latent of ``num_heads`` query heads and 2 key-value heads.
+
+    q~ = h Wq and k~ = h Wk are each mixed along the sequence by two
+    causal convolutions with no function between them, added to the mean
+    of q~ and k~ over each key-value head's group of query heads,
+    L2-normalised per head to length sqrt(d) (the keys times a learned
+    temperature ``tau``) and turned by rotary positions on the first
+    ``rotary_share`` of each head. Key-value head 0's value reads the
+    token (``Wva``), head 1's the token before it (``Wvb``). The
+    score-and-value product is ``flash_attention``'s, with k and v
+    repeated to the query heads; ``Wo`` projects the latent back.
+    Everything but that call and ``Wo`` is the ``cca_mix`` sub-scope.
+    """
+    if num_kv_heads != 2:
+        raise ValueError("CCA's value shift is written for 2 key-value "
+                         f"heads, got {num_kv_heads}")
+    n, t, _ = h.shape
+    group = num_heads // num_kv_heads
+    f32 = jnp.float32
+    with jax.named_scope(SCOPE_CCA_MIX):
+        q0 = opsnn.linear(h, params["Wq"]).reshape(n, t, num_heads, -1)
+        k0 = opsnn.linear(h, params["Wk"]).reshape(n, t, num_kv_heads, -1)
+        d = q0.shape[-1]
+        v = jnp.stack([opsnn.linear(h, params["Wva"]),
+                       opsnn.linear(_shift(h, 1), params["Wvb"])], axis=1)
+        q = _causal_convs(q0, params["conv0_q"], params["conv1_q"])
+        k = _causal_convs(k0, params["conv0_k"], params["conv1_k"])
+        q0, k0 = q0.astype(f32), k0.astype(f32)
+        q = q.astype(f32) + (q0 + jnp.repeat(k0, group, axis=2)) / 2
+        k = k.astype(f32) + (
+            jnp.mean(q0.reshape(n, t, num_kv_heads, group, d), axis=3)
+            + k0) / 2
+
+        def unit(x):
+            return x * (d ** 0.5) * jax.lax.rsqrt(
+                jnp.sum(jnp.square(x), axis=-1, keepdims=True))
+
+        q = unit(q)
+        k = unit(k) * params["tau"].astype(f32)[:, None]
+        q = _rotary(q, rope_theta, rotary_share).astype(h.dtype)
+        k = _rotary(k, rope_theta, rotary_share).astype(h.dtype)
+        q, k = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    y = flash_attention(q, k, v, causal=True)
+    return opsnn.linear(_merge_heads(y), params["Wo"])
 
 
 @register_config

@@ -14,19 +14,31 @@ parameter server for; see parallel/specs.expert_parallel_plan.
 Tokens routed beyond an expert's capacity are dropped (standard MoE
 semantics — the residual path carries them); ``load_balance_loss`` exposes
 the GShard auxiliary loss for callers that want to regularize routing.
+
+``RoutedExperts``, beside it on the same ``[E, H, I]`` stacks, is what a
+language model of today runs: drop-free top-1 routing with no capacity
+and no dispatch tensor (the tokens are sorted by expert and the experts'
+products run grouped over the sorted rows), told which of the experts it
+holds, so that it is one chip's share of an expert-parallel layer.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from deeplearning4j_tpu.kernels._dispatch import interpret, use_pallas
 from deeplearning4j_tpu.nn.activations import get_activation
 from deeplearning4j_tpu.nn.config import LayerConfig, register_config
 from deeplearning4j_tpu.nn.initializers import get_initializer
+from deeplearning4j_tpu.observability.vocab import (
+    SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_ROUTE,
+)
 
 
 @register_config
@@ -145,6 +157,167 @@ class MoEBlock(LayerConfig):
         new_state["router_probs_mean"] = stats[0]
         new_state["expert_fraction"] = stats[1]
         return y.reshape(shape), new_state
+
+
+@jax.custom_vjp
+def _take_rows(x, index, inverse):
+    """``x[index]`` for ``index`` a permutation of the rows and ``inverse``
+    its inverse, so that the gradient is a gather too and not a scatter."""
+    return x[index]
+
+
+_take_rows.defvjp(
+    lambda x, index, inverse: (x[index], inverse),
+    lambda inverse, g: (g[inverse], None, None))
+
+# The grouped product on the chip: megablox ``gmm`` (Pallas; JAX ships it),
+# chosen over ``jax.lax.ragged_dot`` by traces on a v5e (PERF.md section 6,
+# PR 28): faster at every load read, zeros for the rows of no group (XLA's
+# ragged-dot kernels leave those rows as they find them), and its calls
+# keep the scope they are traced in. Tiles are rows x inner x columns: the
+# fastest read where the groups' edges fall inside tiles, as a router's do.
+GROUPED_TILES = (256, 2048, 512)
+
+
+def _grouped(rows, weights, sizes):
+    """``rows`` [M,K], sorted by group, times each group's own matrix of
+    ``weights`` [G,K,N]. ``sizes`` [G+1] counts the rows of each group and
+    last the rows of no group here, which give zeros. Off the TPU the
+    product is XLA's own ``ragged_dot`` (``kernels/_dispatch.py``), which
+    is only valid where it gives those rows zeros, as the CPU's does
+    (``tests/test_zaya.py`` holds it to that); the TPU's lowering leaves
+    them as it found them (PERF.md section 6, PR 28), which is one reason
+    the chip runs ``gmm``."""
+    if not use_pallas():
+        return jax.lax.ragged_dot(rows, weights, sizes[:-1])
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m = rows.shape[0]
+    tiles = _tiles(m, rows.shape[1], weights.shape[2])
+    spare = -m % tiles[0]  # gmm wants whole tiles of rows: more of no group
+    if spare:
+        rows = jnp.pad(rows, ((0, spare), (0, 0)))
+        sizes = sizes.at[-1].add(spare)
+    return gmm(rows, weights, sizes, preferred_element_type=rows.dtype,
+               tiling=tiles, interpret=interpret())[:m]
+
+
+def _tiles(m, k, n):
+    return tuple(min(tile, size) for tile, size in zip(
+        GROUPED_TILES, (-(-m // 8) * 8, k, n)))
+
+
+def _record_grouped_product(m, k, n, groups):
+    """One ``kernel.grouped_product`` flight event per layer, at trace
+    time: which product the experts run through, and its tiles."""
+    from deeplearning4j_tpu.observability.flightrecorder import record_event
+
+    on_chip = use_pallas()
+    record_event(
+        "kernel.grouped_product",
+        product="megablox.gmm" if on_chip else "jax.lax.ragged_dot",
+        tiles=list(_tiles(m, k, n)) if on_chip else None,
+        rows=m, inner=k, columns=n, groups=groups)
+
+
+@register_config
+@dataclass
+class RoutedExperts(LayerConfig):
+    """Drop-free top-1 routing over ``experts_total`` SiLU-gated experts,
+    of which this layer holds ``experts_held`` (expert parallelism: the
+    other chips hold the rest).
+
+    The router is a small MLP over a ``router_hidden``-wide state that
+    crosses layers (ZAYA1, arXiv:2511.17127): r = x Wr + gamma r_before,
+    z = Wc gelu(Wb gelu(Wa r)), p = softmax(z) over ALL the experts
+    whatever is held, e* = argmax(p + bias); the bias balances load and no
+    gradient reaches it. It runs in float32 from ``Wr`` on. The tokens are
+    sorted by expert, the experts' three products run grouped over the
+    sorted rows (``[E, H, I]`` stacks, as ``MoEBlock``'s), and the results
+    go back weighted by p[e*]. A token whose expert is held elsewhere gets
+    zeros here; no token is dropped for want of room, because there is no
+    capacity: the sorted rows are all the tokens.
+
+    ``apply`` takes the router's state of the layer before under
+    ``state["router"]`` (absent in the first layer, which has no
+    ``gamma``) and returns its own there, beside ``tokens_here``: how many
+    tokens landed on each expert held.
+    """
+
+    experts_total: int = 16
+    experts_held: Tuple[int, ...] = tuple(range(16))
+    units: int = 0            # the experts' inner width; 0 -> the input's
+    router_hidden: int = 256
+    carries_router: bool = True   # False in the first layer: no gamma
+
+    def output_shape(self, input_shape):
+        return tuple(input_shape)
+
+    def init(self, rng, input_shape, dtype):
+        h = input_shape[-1]
+        i, r, held = self.units or h, self.router_hidden, len(self.experts_held)
+        w_init = get_initializer("xavier")
+        ks = jax.random.split(rng, 7)
+        params = {
+            "Wr": w_init(ks[0], (h, r), dtype),
+            "Wa": w_init(ks[1], (r, r), dtype),
+            "Wb": w_init(ks[2], (r, r), dtype),
+            "Wc": w_init(ks[3], (r, self.experts_total), dtype),
+            "bias": jnp.zeros((self.experts_total,), dtype),
+            "gate": w_init(ks[4], (held, h, i), dtype),
+            "up": w_init(ks[5], (held, h, i), dtype),
+            "down": w_init(ks[6], (held, i, h), dtype),
+        }
+        if self.carries_router:
+            params["gamma"] = jnp.zeros((), dtype)
+        return params, {}
+
+    def route(self, params, tokens, carried):
+        """The router's state [M,R], each token's expert and its share
+        p[e*], in float32."""
+        f32, exact = jnp.float32, jax.lax.Precision.HIGHEST
+
+        def product(x, name):
+            return jnp.matmul(x, params[name].astype(f32), precision=exact)
+
+        r = product(tokens.astype(f32), "Wr")
+        if "gamma" in params:
+            r = r + params["gamma"].astype(f32) * carried
+        z = product(jax.nn.gelu(product(jax.nn.gelu(product(r, "Wa")), "Wb")),
+                    "Wc")
+        prob = jax.nn.softmax(z, axis=-1)
+        chosen = jnp.argmax(
+            prob + jax.lax.stop_gradient(params["bias"].astype(f32)), axis=-1)
+        share = jnp.take_along_axis(prob, chosen[:, None], axis=-1)[:, 0]
+        return r, chosen, share
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        shape = x.shape
+        tokens = x.reshape(-1, shape[-1])
+        held = len(self.experts_held)
+        # an expert's place among those held; ``held`` for one held elsewhere
+        place = np.full((self.experts_total,), held, np.int32)
+        place[list(self.experts_held)] = np.arange(held)
+        with jax.named_scope(SCOPE_MOE_ROUTE):
+            r, chosen, share = self.route(
+                params, tokens, state.get("router"))
+            local = jnp.asarray(place)[chosen]
+            order = jnp.argsort(local)
+            inverse = jnp.argsort(order)
+            sizes = jnp.sum(local[:, None] == jnp.arange(held + 1)[None, :],
+                            axis=0, dtype=jnp.int32)
+            rows = _take_rows(tokens, order, inverse)
+        _record_grouped_product(tokens.shape[0], shape[-1],
+                                params["gate"].shape[-1], held)
+        with jax.named_scope(SCOPE_MOE_EXPERTS):
+            inner = (jax.nn.silu(_grouped(rows, params["gate"], sizes))
+                     * _grouped(rows, params["up"], sizes))
+            out = _grouped(inner, params["down"], sizes)
+        with jax.named_scope(SCOPE_MOE_ROUTE):
+            weight = jnp.where(local < held, share, 0.0)[order]
+            out = (out * weight[:, None]).astype(x.dtype)
+            y = _take_rows(out, inverse, order)
+        return y.reshape(shape), {"router": r, "tokens_here": sizes[:held]}
 
 
 def load_balance_loss(probs, dispatch) -> jnp.ndarray:

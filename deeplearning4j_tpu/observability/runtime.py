@@ -45,10 +45,10 @@ from __future__ import annotations
 
 import re
 import threading
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, List, Optional
 
 from deeplearning4j_tpu.observability import metrics as _metrics
-from deeplearning4j_tpu.observability.vocab import scope_of
+from deeplearning4j_tpu.observability.vocab import scope_of, subscope_of
 
 # memory_stats keys worth a gauge (present on TPU PJRT; CPU returns {}).
 _MEMORY_STATS = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit",
@@ -225,7 +225,7 @@ _HLO_CALLED = re.compile(
     r"false_computation)=%?([^\s,)}]+)|branch_computations=\{([^}]*)\}")
 
 
-def scopes_of_hlo(text: str):
+def scopes_of_hlo(text: str, scope_of=scope_of):
     """``(module name, {instruction: component scope or None})`` of an
     optimised HLO module's text (``compiled.as_text()``): every
     instruction of the entry computation and of the computations it runs
@@ -233,7 +233,15 @@ def scopes_of_hlo(text: str):
     names. The scope is read from the instruction's own ``op_name``
     metadata (``observability.vocab.scope_of``); a fusion without one
     takes its root's, and where the root has none either, the scope most
-    of the fused instructions carry."""
+    of the fused instructions carry. With ``scope_of=subscope_of`` the
+    same walk gives every instruction's innermost sub-scope."""
+    module, (scopes,) = _scopes_of_hlo(text, (scope_of,))
+    return module, scopes
+
+
+def _scopes_of_hlo(text: str, scope_fns):
+    """One walk of the text for several readings of an ``op_name``:
+    ``(module name, [{instruction: scope or None} for each function])``."""
     module = None
     comps: Dict[str, list] = {}   # computation -> [(name, line, is_root)]
     entry = None
@@ -265,22 +273,26 @@ def scopes_of_hlo(text: str):
                 if name.strip():
                     yield name.strip().lstrip("%")
 
-    def own_scope(line):
-        m = _HLO_OP_NAME.search(line)
-        return scope_of(m.group(1)) if m is not None else None
+    nothing = (None,) * len(scope_fns)
 
-    def fused_scope(comp):
+    def own_scopes(line):
+        m = _HLO_OP_NAME.search(line)
+        if m is None:
+            return nothing
+        return tuple(fn(m.group(1)) for fn in scope_fns)
+
+    def fused_scope(comp, i):
         """Of a fused computation: its root's scope, else the scope that
         most of its instructions carry."""
         body = comps.get(comp, ())
-        root = next((own_scope(ln) for _, ln, is_root in body if is_root),
+        root = next((own_scopes(ln)[i] for _, ln, is_root in body if is_root),
                     None)
         if root is not None:
             return root
-        inner = [sc for sc in (own_scope(ln) for _, ln, _ in body) if sc]
+        inner = [sc for sc in (own_scopes(ln)[i] for _, ln, _ in body) if sc]
         return max(inner, key=inner.count) if inner else None
 
-    scopes: Dict[str, Optional[str]] = {}
+    found: List[Dict[str, Optional[str]]] = [{} for _ in scope_fns]
     todo, seen = [entry], set()
     while todo:
         comp = todo.pop()
@@ -288,28 +300,31 @@ def scopes_of_hlo(text: str):
             continue
         seen.add(comp)
         for name, line, _ in comps[comp]:
-            scope = own_scope(line)
+            scopes = own_scopes(line)
             opcode = _HLO_OPCODE.search(line.split(" = ", 1)[1])
-            if opcode is not None and opcode.group(1) == "fusion":
-                if scope is None:
-                    scope = next(filter(None, map(fused_scope,
-                                                  called(line))), None)
-            else:
+            fusion = opcode is not None and opcode.group(1) == "fusion"
+            if not fusion:
                 todo.extend(called(line))
-            scopes[name] = scope
-    return module, scopes
+            for i, scope in enumerate(scopes):
+                if fusion and scope is None:
+                    scope = next(filter(None, (fused_scope(c, i)
+                                               for c in called(line))), None)
+                found[i][name] = scope
+    return module, found
 
 
 def publish_program(module: str, *, flops: Optional[float],
                     scopes: Optional[Dict[str, Optional[str]]] = None,
+                    subscopes: Optional[Dict[str, Optional[str]]] = None,
                     text: Optional[Callable[[], str]] = None,
                     carries: Optional[str] = None):
     """Record what the compiled program ``module`` is made of; a later
     compile under the same name (another batch shape) replaces it.
 
-    Either the ``scopes`` themselves, or ``text``: a function that
-    returns the optimised module's text (``compiled.as_text()``), called
-    once, when the table is first read. ``carries`` names a scope that
+    Either the ``scopes`` themselves (and the ``subscopes``, where the
+    program has any), or ``text``: a function that returns the optimised
+    module's text (``compiled.as_text()``), called once, when the table
+    is first read. ``carries`` names a scope that
     the publisher knows the program to carry; a text without it marks the
     entry ``stale``: the executable's metadata are not this program's.
     jax keys its persistent compilation cache on the program *without*
@@ -320,6 +335,8 @@ def publish_program(module: str, *, flops: Optional[float],
     entry = {"flops": flops, "stale": False}
     if scopes is not None:
         entry["scopes"] = dict(scopes)
+        if subscopes is not None:
+            entry["subscopes"] = dict(subscopes)
     else:
         entry["text"], entry["carries"] = text, carries
     with _programs_lock:
@@ -328,10 +345,12 @@ def publish_program(module: str, *, flops: Optional[float],
 
 def _resolve(module: str, entry: dict):
     """Fetch and parse a pending entry's text, in place."""
-    _, scopes = scopes_of_hlo(entry["text"]())
+    text = entry["text"]()
+    _, (scopes, subscopes) = _scopes_of_hlo(text, (scope_of, subscope_of))
     carries = entry["carries"]
     del entry["text"], entry["carries"]
     entry["scopes"] = scopes
+    entry["subscopes"] = {k: v for k, v in subscopes.items() if v}
     entry["stale"] = carries is not None and carries not in scopes.values()
     if entry["stale"]:
         from deeplearning4j_tpu.observability.flightrecorder import (
@@ -342,8 +361,9 @@ def _resolve(module: str, entry: dict):
 
 
 def program_table() -> Dict[str, dict]:
-    """Module name -> ``{"flops", "scopes", "stale"}`` of every program
-    published in this process. The first read after a program was
+    """Module name -> ``{"flops", "scopes", "subscopes", "stale"}`` of
+    every program published in this process (``subscopes`` holds only
+    the instructions that are in one). The first read after a program was
     published with its ``text`` fetches and parses it, which can take
     seconds (and a compile, where no compilation cache holds the
     program)."""
@@ -355,6 +375,26 @@ def program_table() -> Dict[str, dict]:
                 except Exception:  # noqa: BLE001 - a text that cannot be
                     del _PROGRAMS[module]  # had is no table, not a crash
         return dict(_PROGRAMS)
+
+
+# -- counters of the last step of a fit -----------------------------------------
+
+_STEP_COUNTERS: Dict[str, object] = {}
+
+
+def publish_step_counters(values: Dict[str, object]):
+    """Record the counters a step carries in its metrics
+    (``observability.vocab.STEP_COUNTERS``), as ``Trainer.fit`` fetched
+    them from its last step; they outlive the trainer."""
+    with _programs_lock:
+        _STEP_COUNTERS.update(values)
+
+
+def step_counters() -> Dict[str, object]:
+    """Counter name -> the value of the last fit's last step (a number,
+    or nested lists of them)."""
+    with _programs_lock:
+        return dict(_STEP_COUNTERS)
 
 
 def _reset():

@@ -125,9 +125,10 @@ COMPILE_KINDS = frozenset({
     "compile_cache.stale_metadata",
 })
 
-# Pallas kernels, at trace time (kernels/flash_attention.py)
+# kernels, at trace time (kernels/flash_attention.py, nn/layers/moe.py)
 KERNEL_KINDS = frozenset({
     "kernel.flash_plan",
+    "kernel.grouped_product",
 })
 
 # observability plane's own events (sentinel, SLO, profiling, recorder)
@@ -209,6 +210,23 @@ SCOPE_OPTIMIZER = "optimizer"  # Trainer._finish_step + the master-weight cast
 COMPONENT_SCOPES = (SCOPE_EMBED, SCOPE_ATTN, SCOPE_MLP, SCOPE_HEAD,
                     SCOPE_OPTIMIZER)
 
+# Sub-scopes, opened inside a component scope by the layers that have
+# parts worth timing apart; an instruction's sub-scope is the innermost
+# one in its ``op_name`` (``subscope_of``), its component stays what
+# ``scope_of`` says.
+SCOPE_CCA_MIX = "cca_mix"          # in attn: CCA's projections, value shift,
+                                   # convolutions, q-k mean, norm, rotary
+SCOPE_MOE_ROUTE = "moe_route"      # in mlp: router, argmax, sort, gather,
+                                   # scatter and weighting
+SCOPE_MOE_EXPERTS = "moe_experts"  # in mlp: the experts' grouped products
+SUB_SCOPES = (SCOPE_CCA_MIX, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS)
+
+# Metrics of the step that ``Trainer.fit`` fetches once as it returns (the
+# last step's) and publishes in ``observability/runtime.step_counters``.
+COUNTER_MOE_TOKENS_HERE = "moe.tokens_here"        # [layers, experts held]
+COUNTER_MOE_LOAD = "moe.load_max_over_mean"        # mean over the layers
+STEP_COUNTERS = (COUNTER_MOE_TOKENS_HERE, COUNTER_MOE_LOAD)
+
 # ``name=`` of each Pallas kernel: the custom call reads ``jvp(flash_fwd)``
 # where an unnamed one reads ``jvp()``.
 KERNEL_NAMES = frozenset({
@@ -231,16 +249,29 @@ GENERATION_PROGRAMS = ("generation_prefill", "generation_decode",
 _TRANSFORM = re.compile(r"^[A-Za-z_]+\((.*)\)$")
 
 
+def _bare(part: str) -> str:
+    """A path element with its transform wrappers (``jvp(...)``,
+    ``transpose(jvp(...))``) taken off."""
+    while True:
+        m = _TRANSFORM.match(part)
+        if m is None:
+            return part
+        part = m.group(1)
+
+
 def scope_of(op_name: str):
     """The component scope of an HLO ``op_name``, or None: the outermost
-    path element that, with its transform wrappers (``jvp(...)``,
-    ``transpose(jvp(...))``) taken off, is one of ``COMPONENT_SCOPES``."""
+    path element that, bare, is one of ``COMPONENT_SCOPES``."""
     for part in op_name.split("/")[1:]:
-        while True:
-            m = _TRANSFORM.match(part)
-            if m is None:
-                break
-            part = m.group(1)
-        if part in COMPONENT_SCOPES:
-            return part
+        if _bare(part) in COMPONENT_SCOPES:
+            return _bare(part)
+    return None
+
+
+def subscope_of(op_name: str):
+    """The innermost path element of an HLO ``op_name`` that, bare, is one
+    of ``SUB_SCOPES``, or None."""
+    for part in reversed(op_name.split("/")[1:]):
+        if _bare(part) in SUB_SCOPES:
+            return _bare(part)
     return None

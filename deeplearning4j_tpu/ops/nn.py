@@ -88,6 +88,14 @@ def layer_norm(x, gamma=None, beta=None, axis=-1, eps=1e-5):
     return y
 
 
+def rms_norm(x, weight, eps=1e-5):
+    """x / rms(x) * weight over the last axis, the statistics in float32."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+                        + eps)
+    return (y * weight).astype(x.dtype)
+
+
 def batch_norm_inference(x, mean, var, gamma, beta, eps=1e-5, channel_axis=-1):
     shape = [1] * x.ndim
     shape[channel_axis] = x.shape[channel_axis]

@@ -26,7 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from deeplearning4j_tpu.kernels._dispatch import kernel_mesh as _kernel_mesh
 from deeplearning4j_tpu.nn.config import NeuralNetConfiguration
-from deeplearning4j_tpu.observability.vocab import SCOPE_OPTIMIZER
+from deeplearning4j_tpu.observability.vocab import SCOPE_OPTIMIZER, STEP_COUNTERS
 from deeplearning4j_tpu.ops import math as opsmath
 from deeplearning4j_tpu.train.updaters import apply_updates, resolve_updater
 
@@ -713,6 +713,7 @@ class Trainer:
         steps_per_epoch: Optional[int] = None,
     ) -> TrainState:
         listeners = listeners or []
+        wmetrics: List[Dict[str, jax.Array]] = []
         # persistent compile cache (JAX_COMPILATION_CACHE_DIR): a
         # supervisor-relaunched or re-expanded worker restores its step
         # programs from disk instead of recompiling — activation is
@@ -843,6 +844,13 @@ class Trainer:
             _incidents_exit_training()
             for lst in listeners:
                 lst.on_fit_end(self, ts)
+        # the counters the last step carries in its metrics (the experts'
+        # load): one read as the fit returns, none inside the loop
+        counters = {k: wmetrics[-1][k] for k in STEP_COUNTERS
+                    if wmetrics and k in wmetrics[-1]}
+        if counters:
+            _publish_step_counters({k: v.tolist() for k, v in
+                                    jax.device_get(counters).items()})
         return ts
 
 
@@ -972,4 +980,5 @@ from deeplearning4j_tpu.observability.trace import annotate as _annotate  # noqa
 from deeplearning4j_tpu.observability.runtime import (  # noqa: E402
     program_table as _program_table,
     publish_program as _publish_program,
+    publish_step_counters as _publish_step_counters,
 )

@@ -32,6 +32,9 @@ _CALLS = {
     "gpt2_small.train_s1024": ((16, 12, 1024, 1024, 64), "bfloat16", True,
                                False),
     "long_context_4096": ((2, 12, 4096, 4096, 64), "bfloat16", True, False),
+    # CCA's call: 8 query heads of 128, k and v repeated to them
+    "zaya1_8b.train_s4096": ((2, 8, 4096, 4096, 128), "bfloat16", True,
+                             False),
     "bert_like_masked_float32": ((2, 8, 2048, 2048, 128), "float32", False,
                                  True),
     "causal_masked_float32_128_wide": ((2, 8, 2048, 2048, 128), "float32",

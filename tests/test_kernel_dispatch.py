@@ -111,12 +111,13 @@ class TestTheStepLowersForTheChip:
     lengths and head width (64), lowered for the TPU platform."""
 
     @staticmethod
-    def _lowered(model, batch):
+    def _lowered(model, batch, names=False):
+        """The step's text; with ``names`` every operation's ``loc``."""
         from deeplearning4j_tpu.train.trainer import Trainer
 
         trainer = Trainer(model)
         return trainer.train_step.trace(trainer.init_state(), batch).lower(
-            lowering_platforms=("tpu",)).as_text()
+            lowering_platforms=("tpu",)).as_text(debug_info=names)
 
     def test_gpt2_shaped_step_feeds_the_kernels_64_wide(self, as_on_tpu):
         """3 ``tpu_custom_call``s a block, by name, q, k and v 64 wide."""
@@ -141,6 +142,63 @@ class TestTheStepLowersForTheChip:
         assert not [line for line in text.splitlines()
                     if "stablehlo.pad" in line
                     and "-> tensor<4x1024x128x" in line]
+
+    def test_gpt2_shaped_step_holds_nothing_new(self, as_on_tpu):
+        """What ZAYA1 brought (sub-scopes, a grouped product) is not in the
+        ``gpt2_small`` step: its scopes are the five components, its custom
+        calls the three flash kernels, as before."""
+        import re
+
+        from deeplearning4j_tpu.models.gpt import gpt_tiny
+        from deeplearning4j_tpu.observability import vocab
+
+        model = gpt_tiny(hidden=128, num_heads=2, max_position=1024)
+        text = self._lowered(model, {"features": {
+            "token_ids": np.zeros((2, 1024), np.int32)}}, names=True)
+        assert set(re.findall(r"stablehlo\.custom_call @(\w+)", text)) <= {
+            "tpu_custom_call", "Sharding"}
+        assert set(re.findall(r'kernel_name = "(\w+)"', text)) == {
+            "flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"}
+        names = set(re.findall(r'loc\("([^"]+)"', text))
+        assert any("attn" in n for n in names)
+        for sub in vocab.SUB_SCOPES:
+            assert not [n for n in names if sub in n], sub
+        assert "gmm" not in text and "ragged" not in text
+
+    def test_zaya_shaped_step_runs_flash_and_the_grouped_product(
+            self, as_on_tpu, monkeypatch):
+        """Heads of 128 at T = 1024: 3 flash calls a layer, q at 4 heads
+        and k, v repeated to them; 9 megablox calls a layer under
+        ``moe_experts``; every sub-scope on the step."""
+        import re
+
+        from deeplearning4j_tpu.models.zaya import zaya_tiny
+        from deeplearning4j_tpu.nn.layers import moe
+        from deeplearning4j_tpu.observability import vocab
+
+        monkeypatch.setattr(moe, "use_pallas", lambda: True)
+        monkeypatch.setattr(moe, "interpret", lambda: False)
+        model = zaya_tiny(hidden=256, head_dim=128, expert_units=256,
+                          experts_held=(0, 2))
+        text = self._lowered(model, {"features": {
+            "token_ids": np.zeros((2, 1024), np.int32)}}, names=True)
+        calls = [line for line in text.splitlines()
+                 if "@tpu_custom_call" in line]
+        flash = [c for c in calls if 'kernel_name = "flash_' in c]
+        assert len(flash) == 3 * model.config.num_layers
+        for call in flash:  # q, k, v: [batch x query heads, T, 128]
+            operands = re.search(r" : \(([^)]*)\) -> ", call).group(1)
+            qkv = re.findall(r"tensor<([0-9x]+)x\w+>", operands)[:3]
+            assert qkv == ["8x1024x128"] * 3, call[-400:]
+        # megablox's three kernels, each a function of its own, called
+        # for gate, up and down, forward and in both gradients
+        assert len(calls) - len(flash) == 3
+        sites = re.findall(r"call @(t?gmm)(?:_\d+)?\(", text)
+        assert len(sites) == 9 * model.config.num_layers
+        assert sites.count("tgmm") == 3 * model.config.num_layers
+        names = set(re.findall(r'loc\("([^"]+)"', text))
+        for sub in vocab.SUB_SCOPES:
+            assert [n for n in names if sub in n], sub
 
     def test_bert_shaped_step_at_128_holds_no_kernel(self, as_on_tpu):
         from deeplearning4j_tpu.models.bert import bert_tiny, make_mlm_batch

@@ -120,6 +120,38 @@ def test_the_parse_gives_each_traced_instruction_its_scope():
     }
 
 
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(train_step)/jvp(attn)/jvp(cca_mix)/dot_general", "cca_mix"),
+    ("jit(train_step)/transpose(jvp(attn))/transpose(jvp(cca_mix))/mul",
+     "cca_mix"),
+    ("jit(train_step)/jvp(mlp)/moe_experts/jit(gmm)/pallas_call",
+     "moe_experts"),
+    ("jit(train_step)/jvp(mlp)/jvp(moe_route)/sort", "moe_route"),
+    ("jit(train_step)/jvp(attn)/jvp(flash_fwd)/pallas_call", None),
+    ("jit(train_step)/jvp(mlp)/dot_general", None),
+    ("jit(moe_route)/mul", None),  # the program's own name is no scope
+])
+def test_subscope_of_an_op_name(op_name, want):
+    assert vocab.subscope_of(op_name) == want
+    if want is not None:  # the component is still what scope_of says
+        assert vocab.scope_of(op_name) in ("attn", "mlp")
+
+
+def test_the_parse_gives_the_innermost_subscope_beside_the_scope():
+    text = HLO.replace("jit(step)/transpose(jvp(attn))/mul",
+                       "jit(step)/transpose(jvp(attn))/jvp(cca_mix)/mul")
+    _, scopes = runtime.scopes_of_hlo(text)
+    _, subscopes = runtime.scopes_of_hlo(text, vocab.subscope_of)
+    assert scopes == runtime.scopes_of_hlo(HLO)[1]  # read as before
+    assert {k for k, v in subscopes.items() if v} == {"fusion.14"}
+    assert subscopes["fusion.14"] == "cca_mix"
+    runtime.publish_program("jit_sub", flops=None, text=lambda: text,
+                            carries="optimizer")
+    entry = runtime.program_table()["jit_sub"]
+    assert entry["subscopes"] == {"fusion.14": "cca_mix"}
+    assert entry["scopes"]["fusion.14"] == "attn" and not entry["stale"]
+
+
 def test_the_table_keeps_the_last_program_published_under_a_name():
     runtime.publish_program("jit_t", flops=1.0, scopes={"a": "attn"})
     runtime.publish_program("jit_t", flops=2.0, scopes={"b": None})
