@@ -169,14 +169,16 @@ class Gpt:
         mask = features.get("mask")
         h = self.encode(params, ids, train=True, rng=rng, mask=mask)
         with jax.named_scope(SCOPE_HEAD):
-            lg = self.logits(params, h)[:, :-1]
             labels = batch.get("labels")
             if labels is None:
                 labels = ids[:, 1:]
             w = (jnp.ones(labels.shape, jnp.float32) if mask is None
                  else mask[:, 1:].astype(jnp.float32))
-            per_tok = losses.sparse_softmax_cross_entropy(lg, labels,
-                                                          reduction="none")
+            # the last position has no next token: cut from the hidden
+            # state, so that no logits are made for it
+            per_tok = losses.linear_softmax_cross_entropy(
+                h[:, :-1], params["embeddings"]["word"], labels,
+                params["final"]["out_b"])
             loss = jnp.sum(per_tok * w) / jnp.maximum(jnp.sum(w), 1.0)
         return loss, (state, {"loss": loss})
 

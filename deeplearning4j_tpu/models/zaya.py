@@ -170,9 +170,8 @@ class Zaya:
         ids = _token_ids(batch["features"])
         h, tokens_here = self.encode(params, ids)
         with jax.named_scope(SCOPE_HEAD):
-            lg = self.logits(params, h)[:, :-1]
-            loss = jnp.mean(losses.sparse_softmax_cross_entropy(
-                lg, ids[:, 1:], reduction="none").astype(jnp.float32))
+            loss = jnp.mean(losses.linear_softmax_cross_entropy(
+                h[:, :-1], params["embeddings"]["word"], ids[:, 1:]))
         load = tokens_here.astype(jnp.float32)
         metrics = {
             "loss": loss,

@@ -125,8 +125,10 @@ COMPILE_KINDS = frozenset({
     "compile_cache.stale_metadata",
 })
 
-# kernels, at trace time (kernels/flash_attention.py, nn/layers/moe.py)
+# kernels and operations with a backward rule of their own, at trace time
+# (kernels/flash_attention.py, nn/layers/moe.py, ops/loss.py)
 KERNEL_KINDS = frozenset({
+    "head.linear_cross_entropy",
     "kernel.flash_plan",
     "kernel.grouped_product",
 })

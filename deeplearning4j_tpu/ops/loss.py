@@ -18,6 +18,7 @@ Conventions (matching the reference):
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -81,6 +82,73 @@ def sparse_softmax_cross_entropy(logits, label_ids, weights=None, reduction="mea
     logp = jax.nn.log_softmax(logits, axis=-1)
     ce = -jnp.take_along_axis(logp, label_ids[..., None], axis=-1)[..., 0]
     return _reduce(ce, reduction, weights)
+
+
+def linear_softmax_cross_entropy(hidden, weight, labels, bias=None):
+    """Per-position cross entropy of ``softmax(hidden @ weight.T + bias)``
+    at ``labels``, in float32: the loss of a softmax over a linear layer's
+    output at a language model's vocabulary, as one operation with its own
+    backward rule. Its value and gradients are those of
+    ``sparse_softmax_cross_entropy(einsum("...h,vh->...v", hidden, weight)
+    + bias, labels, reduction="none")``; what the rule leaves out is work:
+    no ``[rows, vocabulary]`` array of log-probabilities, no pass of its
+    own for the bias's gradient, and the logits' gradient in a form the
+    compiler can take into the two products that read it.
+
+    hidden [..., H], weight [V, H] and bias [V] in the compute dtype, in
+    which the logits are held; labels [...] integer. The row statistic
+    (``logsumexp``) and the result are float32."""
+    from deeplearning4j_tpu.observability.flightrecorder import record_event
+
+    record_event(
+        "head.linear_cross_entropy", rows=math.prod(labels.shape),
+        vocabulary=weight.shape[0],
+        logits_dtype=str(jnp.result_type(hidden, weight)),
+        bias=bias is not None, logsumexp="max_then_sum_float32")
+    return _linear_cross_entropy(hidden, weight, bias, labels)
+
+
+@jax.custom_vjp
+def _linear_cross_entropy(hidden, weight, bias, labels):
+    return _linear_cross_entropy_fwd(hidden, weight, bias, labels)[0]
+
+
+def _linear_cross_entropy_fwd(hidden, weight, bias, labels):
+    logits = jnp.einsum("...h,vh->...v", hidden, weight,
+                        preferred_element_type=jnp.float32)
+    if bias is not None:
+        logits = logits + bias.astype(jnp.float32)
+    # written once, in the compute dtype; every later pass reads this array
+    logits = logits.astype(jnp.result_type(hidden, weight))
+    lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+    at_label = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    return (lse - at_label.astype(jnp.float32),
+            (hidden, weight, bias, logits, lse, labels))
+
+
+def _linear_cross_entropy_bwd(residuals, g):
+    hidden, weight, bias, logits, lse, labels = residuals
+    classes = jax.lax.broadcasted_iota(labels.dtype, logits.shape,
+                                       logits.ndim - 1)
+    d_logits = ((jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+                 - (classes == labels[..., None])) * g[..., None]
+                ).astype(logits.dtype)
+    d_hidden = jnp.einsum("...v,vh->...h", d_logits, weight).astype(
+        hidden.dtype)
+    if bias is None:
+        d_weight = jnp.einsum("...v,...h->vh", d_logits, hidden)
+        return d_hidden, d_weight.astype(weight.dtype), None, None
+    # the bias's gradient is the logits' summed over the rows: a column of
+    # ones beside the hidden state takes that sum in the weight's product
+    ones = jnp.ones(hidden.shape[:-1] + (1,), hidden.dtype)
+    d_both = jnp.einsum("...v,...h->vh", d_logits,
+                        jnp.concatenate([hidden, ones], axis=-1))
+    return (d_hidden, d_both[:, :-1].astype(weight.dtype),
+            d_both[:, -1].astype(bias.dtype), None)
+
+
+_linear_cross_entropy.defvjp(_linear_cross_entropy_fwd,
+                             _linear_cross_entropy_bwd)
 
 
 @register_loss("xent")

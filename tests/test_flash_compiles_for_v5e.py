@@ -5,6 +5,9 @@ runs, so nothing here is a time or a result. One file, one fixture: the
 worker that is given this file is the only one that loads the TPU's
 compiler."""
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -81,3 +84,52 @@ def test_forward_and_backward_compile_at_the_default_geometry(
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         *args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_the_gpt2_head_writes_its_logits_and_no_other_array_of_their_size(
+        one_chip, as_on_tpu):
+    """A count of the compiled program, not a time: the one-layer
+    ``gpt2_small`` step at the published head widths (16 x 1,024 positions,
+    768 wide, 50,257 classes). At most two top-level operations write an
+    array of rows x vocabulary elements (the logits, and their gradient
+    where the compiler does not take it into the products that read it),
+    none of them in float32, none of them log-probabilities, and the head
+    bias's gradient has no pass of its own."""
+    from deeplearning4j_tpu.models.gpt import gpt2_small
+    from deeplearning4j_tpu.nn.config import NeuralNetConfiguration
+    from deeplearning4j_tpu.train.trainer import Trainer
+    from deeplearning4j_tpu.train.updaters import Adam
+
+    rows, seq, vocabulary = 16, 1024, 50257
+    trainer = Trainer(gpt2_small(
+        num_layers=1, dropout=0.0, attention_dropout=0.0,
+        net=NeuralNetConfiguration(updater=Adam(lr=1e-4),
+                                   mixed_precision=True, rng_impl="rbg")))
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    batch = {"features": {"token_ids": jax.ShapeDtypeStruct(
+        (rows, seq), jnp.int32)}}
+    text = jax.jit(trainer._raw_step, **trainer._jit_kwargs).lower(
+        described(jax.eval_shape(lambda: trainer.init_state(seed=0))),
+        described(batch)).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    least = rows * (seq - 1) * vocabulary
+    large = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if m is None or m.group(3) in ("get-tuple-element", "bitcast",
+                                       "parameter", "tuple"):
+            continue
+        for dtype, dims in re.findall(r"(\w+)\[([\d,]+)\]", m.group(2)):
+            if math.prod(int(d) for d in dims.split(",")) >= least:
+                large.append((m.group(1), dtype, line))
+    assert 1 <= len(large) <= 2, [name for name, _, _ in large]
+    assert all(dtype == "bf16" for _, dtype, _ in large), large
+    assert not any("log_softmax" in line for _, _, line in large)
+    # the bias's gradient: no top-level reduction over the rows to [50257]
+    assert not re.search(
+        r'= \w+\[50257\]\S* fusion\(.*op_name="[^"]*head[^"]*/reduce_sum"', entry)

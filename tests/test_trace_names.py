@@ -210,6 +210,78 @@ def test_every_scope_is_on_the_forward_and_the_backward_pass(make):
     assert any(f"/jvp({vocab.SCOPE_OPTIMIZER})/" in n for n in names)
 
 
+# -- the head's loss, an operation with a backward rule of its own ------------
+
+def _zaya():
+    from deeplearning4j_tpu.models.zaya import zaya_tiny
+
+    batch = {"features": {"token_ids": np.zeros((2, 16), np.int32)}}
+    return Trainer(zaya_tiny(net=_net())), batch
+
+
+# the operation's three products carry their einsum's subscripts in their
+# ``op_name``: the logits, the hidden state's and the weight's gradient
+_HEAD_PRODUCTS = ("/jvp(head)/...h,vh->...v/",
+                  "/transpose(jvp(head))/...v,vh->...h/",
+                  "/transpose(jvp(head))/...v,...h->vh/")
+
+
+@pytest.mark.parametrize("make", [_gpt, _zaya], ids=["gpt", "zaya"])
+def test_the_heads_operation_is_under_head_forward_and_backward(make):
+    trainer, batch = make()
+    trainer.fit(trainer.init_state(), [batch])
+    _join_cost_analysis()
+    scopes = trainer.step_description()["scopes"]
+    text = jax.jit(trainer._raw_step).lower(
+        jax.eval_shape(trainer.init_state), batch).compile().as_text()
+    traced = re.findall(
+        r'^\s*(?:ROOT )?%?([\w.\-]+) = .*op_name="([^"]*)"', text, re.M)
+    for product in _HEAD_PRODUCTS:
+        found = [name for name, op_name in traced if product in op_name]
+        assert found, product
+        assert any(scopes.get(name) == vocab.SCOPE_HEAD for name in found)
+        for name in found:  # in the table, or inside a fusion that is
+            assert scopes.get(name, vocab.SCOPE_HEAD) == vocab.SCOPE_HEAD
+    # the rule's own exp (the softmax from the stored logits) is in the
+    # backward pass under ``head``, and no log-softmax is left in the step
+    backward = {op_name.rsplit("/", 1)[-1] for _, op_name in traced
+                if "/transpose(jvp(head))/" in op_name}
+    assert {"exp", "dot_general"} <= backward
+    assert not any("log_softmax" in op_name for _, op_name in traced)
+    # and none of the rule's primitives fell out of the scope: an exp, an
+    # iota or a concatenate of the backward pass with no component
+    assert not [op_name for _, op_name in traced
+                if op_name.rsplit("/", 1)[-1] in ("exp", "iota", "concatenate")
+                and "transpose(" in op_name
+                and vocab.scope_of(op_name) is None]
+
+
+@pytest.mark.parametrize("make,bias,vocabulary,rows", [
+    (_gpt, True, 128, 4 * 15), (_zaya, False, 96, 2 * 15)],
+    ids=["gpt", "zaya"])
+def test_one_flight_event_a_trace_says_which_head_the_step_holds(
+        make, bias, vocabulary, rows):
+    from deeplearning4j_tpu.observability.flightrecorder import (
+        FlightRecorder,
+        get_flight_recorder,
+        set_flight_recorder,
+    )
+
+    trainer, batch = make()
+    before = get_flight_recorder()
+    flight = set_flight_recorder(FlightRecorder())
+    try:
+        jax.jit(trainer._raw_step).lower(
+            jax.eval_shape(trainer.init_state), batch)
+    finally:
+        set_flight_recorder(before)
+    event, = flight.events(kinds=["head.linear_cross_entropy"])
+    assert event["data"] == {
+        "rows": rows, "vocabulary": vocabulary, "logits_dtype": "bfloat16",
+        "bias": bias, "logsumexp": "max_then_sum_float32"}
+    assert "head.linear_cross_entropy" in vocab.known_event_kinds()
+
+
 # -- names in lowered text -----------------------------------------------------
 
 def test_the_flash_kernels_are_called_by_name(monkeypatch):
