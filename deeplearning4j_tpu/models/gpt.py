@@ -12,11 +12,14 @@ generation in nn/generation.py (one dispatch per sequence, not per
 token; through a ~69 ms-round-trip interconnect that is the difference
 between usable and unusable sampling).
 
-Training reuses TransformerEncoderBlock (pre-LN, causal=True) so every
-Trainer feature (donation, bf16 policy, NaN guard, chained bench
-windows) applies unchanged; the cached decode step re-implements the
-block's forward over the same param tree, and a parity test pins its
-logits to the full forward's at every position
+One block, TransformerEncoderBlock (pre-LN, causal=True), and one walk
+over the layers serve every entry point: training and ``encode`` run it
+with the block's own attention (so every Trainer feature — donation,
+bf16 policy, NaN guard — applies unchanged); ``decode_step``,
+``decode_step_slots`` and ``prefill_chunk`` hand it one of three cache
+attends (write at a scalar position, write per row, write nowhere and
+hand the keys and values back). Parity tests pin the cached logits to
+the full forward's at every position
 (tests/test_gpt.py::test_cached_decode_matches_full_forward).
 """
 
@@ -28,15 +31,9 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.nn.activations import get_activation
 from deeplearning4j_tpu.nn.config import NeuralNetConfiguration, register_config
 from deeplearning4j_tpu.nn.layers.attention import TransformerEncoderBlock
-from deeplearning4j_tpu.observability.vocab import (
-    SCOPE_ATTN,
-    SCOPE_EMBED,
-    SCOPE_HEAD,
-    SCOPE_MLP,
-)
+from deeplearning4j_tpu.observability.vocab import SCOPE_EMBED, SCOPE_HEAD
 from deeplearning4j_tpu.ops import loss as losses
 from deeplearning4j_tpu.ops import nn as opsnn
 from deeplearning4j_tpu.train.updaters import Adam
@@ -124,22 +121,37 @@ class Gpt:
 
     def encode(self, params, ids, *, train=False, rng=None, mask=None):
         """[N,T] int32 → hidden [N,T,H] (pre-head LN applied)."""
+        return self._walk(params, ids, train=train, rng=rng, mask=mask)[0]
+
+    def _walk(self, params, ids, *, positions=None, attends=None,
+              train=False, rng=None, mask=None):
+        """The one walk over the layers: ids [N,T] → (hidden [N,T,H] with
+        the pre-head LN applied, what each layer's attend kept).
+        ``positions`` (a scalar, or [N,T]) index the position table where
+        the tokens are not at 0..T-1; ``attends`` holds one
+        ``SelfAttention.apply`` attend a layer."""
         c = self.config
-        t = ids.shape[1]
         emb = params["embeddings"]
         with jax.named_scope(SCOPE_EMBED):
             x = opsnn.embedding_lookup(emb["word"], ids)
-            x = x + emb["position"][:t][None, :, :]
+            if positions is None:
+                # a slice, not a gather: the table's gradient stays a pad
+                x = x + emb["position"][:ids.shape[1]][None, :, :]
+            else:
+                x = x + emb["position"][positions]
             if train and c.dropout > 0.0 and rng is not None:
                 x = opsnn.dropout(x, c.dropout, jax.random.fold_in(rng, 999))
+        kept = []
         for i in range(c.num_layers):
             lrng = jax.random.fold_in(rng, i) if rng is not None else None
-            x, _ = self._block.apply(params[f"layer_{i}"], {}, x,
-                                     train=train, rng=lrng, mask=mask)
+            x, k = self._block.apply(
+                params[f"layer_{i}"], {}, x, train=train, rng=lrng, mask=mask,
+                attend=attends[i] if attends else None)
+            kept.append(k)
         f = params["final"]
         with jax.named_scope(SCOPE_HEAD):
             return opsnn.layer_norm(x, f["ln_gamma"], f["ln_beta"],
-                                    eps=c.eps)
+                                    eps=c.eps), kept
 
     def logits(self, params, hidden):
         with jax.named_scope(SCOPE_HEAD):
@@ -216,144 +228,24 @@ class Gpt:
         return [{"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
                 for _ in range(c.num_layers)]
 
-    def _block_step(self, p, cache, x_t, pos):
-        """One token through one block with cached K/V.
-
-        x_t: [N,H]; pos: scalar int32 (0-based position of this token).
-        Re-implements TransformerEncoderBlock._forward (pre-LN branch) —
-        parity pinned by test_cached_decode_matches_full_forward.
-        """
-        c = self.config
-        h = c.num_heads
-        eps = c.eps
-
-        def ln(v, which):
-            return opsnn.layer_norm(v, p[f"{which}_gamma"],
-                                    p[f"{which}_beta"], eps=eps)
-
-        ap = p["attention"]
-        with jax.named_scope(SCOPE_ATTN):
-            a_in = ln(x_t, "ln1")  # [N,H]
-            n, e = a_in.shape
-            hd = e // h
-
-            def heads(z):
-                return z.reshape(n, h, 1, hd)  # [N,h,1,hd] from [N, h*hd]
-
-            q = heads(opsnn.linear(a_in, ap["Wq"], ap.get("bq")))
-            k = heads(opsnn.linear(a_in, ap["Wk"], ap.get("bk")))
-            v = heads(opsnn.linear(a_in, ap["Wv"], ap.get("bv")))
-            kc = jax.lax.dynamic_update_slice(cache["k"], k, (0, 0, pos, 0))
-            vc = jax.lax.dynamic_update_slice(cache["v"], v, (0, 0, pos, 0))
-            scores = jnp.einsum("nhqd,nhld->nhql", q, kc) / jnp.sqrt(
-                jnp.asarray(hd, q.dtype))
-            # causal-by-construction: only slots <= pos are live
-            live = (jnp.arange(kc.shape[2]) <= pos)[None, None, None, :]
-            scores = jnp.where(live, scores, jnp.finfo(scores.dtype).min)
-            att = jax.nn.softmax(scores, axis=-1)
-            y = jnp.einsum("nhql,nhld->nhqd", att, vc).reshape(n, e)
-            a = opsnn.linear(y, ap["Wo"], ap.get("bo"))
-            x = x_t + a
-        with jax.named_scope(SCOPE_MLP):
-            f_in = ln(x, "ln2")
-            f = opsnn.linear(f_in, p["W1"], p["b1"])
-            f = get_activation(c.activation)(f)
-            f = opsnn.linear(f, p["W2"], p["b2"])
-            return x + f, {"k": kc, "v": vc}
-
     def decode_step(self, params, caches, ids_t, pos):
         """One decode step: ids_t [N] int32 at position pos → (logits [N,V],
         updated caches)."""
-        c = self.config
-        emb = params["embeddings"]
-        with jax.named_scope(SCOPE_EMBED):
-            x = opsnn.embedding_lookup(emb["word"], ids_t)  # [N,H]
-            x = x + jax.lax.dynamic_slice_in_dim(
-                emb["position"], pos, 1, 0)[0]
-        new_caches = []
-        for i in range(c.num_layers):
-            x, cc = self._block_step(params[f"layer_{i}"], caches[i], x, pos)
-            new_caches.append(cc)
-        f = params["final"]
-        with jax.named_scope(SCOPE_HEAD):
-            hfin = opsnn.layer_norm(x, f["ln_gamma"], f["ln_beta"],
-                                    eps=c.eps)
-            lg = hfin @ params["embeddings"]["word"].T + f["out_b"]
-        return lg, new_caches
-
-    # -- continuous-batching decode (serving/generation.py) ----------------
-
-    def _block_step_slots(self, p, cache, x_t, pos):
-        """One token through one block with cached K/V and PER-ROW
-        positions — the continuous-batching twin of :meth:`_block_step`,
-        where every row of the batch is an independent sequence at its
-        own depth (``pos`` is [N] int32, not a scalar). Parity with the
-        scalar path is pinned by
-        tests/test_generation_serving.py::test_slot_decode_matches_scalar.
-        """
-        c = self.config
-        h = c.num_heads
-        eps = c.eps
-
-        def ln(v, which):
-            return opsnn.layer_norm(v, p[f"{which}_gamma"],
-                                    p[f"{which}_beta"], eps=eps)
-
-        ap = p["attention"]
-        with jax.named_scope(SCOPE_ATTN):
-            a_in = ln(x_t, "ln1")  # [N,H]
-            n, e = a_in.shape
-            hd = e // h
-
-            def heads(z):
-                return z.reshape(n, h, hd)  # [N,h,hd] from [N, h*hd]
-
-            q = heads(opsnn.linear(a_in, ap["Wq"], ap.get("bq")))
-            k = heads(opsnn.linear(a_in, ap["Wk"], ap.get("bk")))
-            v = heads(opsnn.linear(a_in, ap["Wv"], ap.get("bv")))
-            rows = jnp.arange(n)
-            # per-row scatter: row i's new K/V lands at its own pos[i]
-            kc = cache["k"].at[rows, :, pos, :].set(k)
-            vc = cache["v"].at[rows, :, pos, :].set(v)
-            scores = jnp.einsum("nhd,nhld->nhl", q, kc) / jnp.sqrt(
-                jnp.asarray(hd, q.dtype))
-            # causal-by-construction, per row: only slots <= pos[i] are live
-            live = (jnp.arange(kc.shape[2])[None, None, :]
-                    <= pos[:, None, None])
-            scores = jnp.where(live, scores, jnp.finfo(scores.dtype).min)
-            att = jax.nn.softmax(scores, axis=-1)
-            y = jnp.einsum("nhl,nhld->nhd", att, vc).reshape(n, e)
-            a = opsnn.linear(y, ap["Wo"], ap.get("bo"))
-            x = x_t + a
-        with jax.named_scope(SCOPE_MLP):
-            f_in = ln(x, "ln2")
-            f = opsnn.linear(f_in, p["W1"], p["b1"])
-            f = get_activation(c.activation)(f)
-            f = opsnn.linear(f, p["W2"], p["b2"])
-            return x + f, {"k": kc, "v": vc}
+        h, caches = self._walk(params, ids_t[:, None], positions=pos,
+                               attends=[_attend_at(c, pos) for c in caches])
+        return self.logits(params, h)[:, 0], caches
 
     def decode_step_slots(self, params, caches, ids_t, pos):
         """One iteration-level decode step over independent sequences:
         ids_t [N] int32, pos [N] int32 (each row's own 0-based position)
         → (logits [N,V], updated caches). Rows are decode *slots* —
         sequences at different depths batched into one device step, the
-        core primitive of the continuous-batching serving engine."""
-        c = self.config
-        emb = params["embeddings"]
-        with jax.named_scope(SCOPE_EMBED):
-            x = opsnn.embedding_lookup(emb["word"], ids_t)  # [N,H]
-            x = x + emb["position"][pos]                # per-row gather
-        new_caches = []
-        for i in range(c.num_layers):
-            x, cc = self._block_step_slots(params[f"layer_{i}"], caches[i],
-                                           x, pos)
-            new_caches.append(cc)
-        f = params["final"]
-        with jax.named_scope(SCOPE_HEAD):
-            hfin = opsnn.layer_norm(x, f["ln_gamma"], f["ln_beta"],
-                                    eps=c.eps)
-            lg = hfin @ params["embeddings"]["word"].T + f["out_b"]
-        return lg, new_caches
+        core primitive of the continuous-batching serving engine
+        (serving/generation.py)."""
+        h, caches = self._walk(
+            params, ids_t[:, None], positions=pos[:, None],
+            attends=[_attend_at_rows(c, pos) for c in caches])
+        return self.logits(params, h)[:, 0], caches
 
     def prefill_chunk(self, params, ids):
         """Whole-prompt prefill with full causal self-attention:
@@ -361,60 +253,11 @@ class Gpt:
         ``[{"k": [N,h,P,hd], "v": ...}]``). One matmul-bound program
         instead of a P-step decode scan — the compute-shaped half of the
         prefill/decode split (decode is memory-bound; cuDNN-paper
-        batched-primitive framing). Re-implements the pre-LN block over
-        the same param tree; logits parity with the cached decode scan
-        is pinned by tests/test_generation_serving.py."""
-        c = self.config
-        h = c.num_heads
-        emb = params["embeddings"]
-        n, pl = ids.shape
-        with jax.named_scope(SCOPE_EMBED):
-            x = opsnn.embedding_lookup(emb["word"], ids)
-            x = x + emb["position"][:pl][None, :, :]
-        causal = jnp.tril(jnp.ones((pl, pl), bool))[None, None]
-        kvs = []
-        for i in range(c.num_layers):
-            p = params[f"layer_{i}"]
-
-            def ln(v, which, p=p):
-                return opsnn.layer_norm(v, p[f"{which}_gamma"],
-                                        p[f"{which}_beta"], eps=c.eps)
-
-            ap = p["attention"]
-            with jax.named_scope(SCOPE_ATTN):
-                a_in = ln(x, "ln1")                      # [N,P,E]
-                e = a_in.shape[-1]
-                hd = e // h
-
-                def heads(z):
-                    # [N,P,h*hd] -> [N,h,P,hd]; feature layout head-major,
-                    # matching _block_step's reshape(n, h, 1, hd)
-                    return z.reshape(n, pl, h, hd).transpose(0, 2, 1, 3)
-
-                q = heads(opsnn.linear(a_in, ap["Wq"], ap.get("bq")))
-                k = heads(opsnn.linear(a_in, ap["Wk"], ap.get("bk")))
-                v = heads(opsnn.linear(a_in, ap["Wv"], ap.get("bv")))
-                scores = jnp.einsum("nhqd,nhkd->nhqk", q, k) / jnp.sqrt(
-                    jnp.asarray(hd, q.dtype))
-                scores = jnp.where(causal, scores,
-                                   jnp.finfo(scores.dtype).min)
-                att = jax.nn.softmax(scores, axis=-1)
-                y = jnp.einsum("nhqk,nhkd->nhqd", att, v)
-                y = y.transpose(0, 2, 1, 3).reshape(n, pl, e)
-                x = x + opsnn.linear(y, ap["Wo"], ap.get("bo"))
-            with jax.named_scope(SCOPE_MLP):
-                f_in = ln(x, "ln2")
-                f = opsnn.linear(f_in, p["W1"], p["b1"])
-                f = get_activation(c.activation)(f)
-                x = x + opsnn.linear(f, p["W2"], p["b2"])
-            kvs.append({"k": k, "v": v})
-        fin = params["final"]
-        with jax.named_scope(SCOPE_HEAD):
-            hfin = opsnn.layer_norm(x, fin["ln_gamma"], fin["ln_beta"],
-                                    eps=c.eps)
-            lg = (jnp.einsum("nth,vh->ntv", hfin, emb["word"])
-                  + fin["out_b"])
-        return lg, kvs
+        batched-primitive framing). Logits parity with the cached decode
+        scan is pinned by tests/test_generation_serving.py."""
+        h, kvs = self._walk(params, ids,
+                            attends=[_attend_causal] * self.config.num_layers)
+        return self.logits(params, h), kvs
 
     def generate(self, variables, prime_ids, *, n_steps: int, rng,
                  temperature: float = 1.0, top_k: Optional[int] = None,
@@ -498,6 +341,47 @@ class Gpt:
             self, t0, n_steps, total, int(beam_size),
             float(length_penalty), eos_id))
         return fn(params, jnp.asarray(prime_ids, jnp.int32))
+
+
+def _attend_over(q, k, v, live):
+    """Softmax attention of q [N,h,Q,hd] over the keys and values
+    [N,h,L,hd] that ``live`` (broadcast to [N,h,Q,L]) lets each query see."""
+    scores = jnp.einsum("nhqd,nhld->nhql", q, k) / jnp.sqrt(
+        jnp.asarray(q.shape[-1], q.dtype))
+    scores = jnp.where(live, scores, jnp.finfo(scores.dtype).min)
+    return jnp.einsum("nhql,nhld->nhqd", jax.nn.softmax(scores, axis=-1), v)
+
+
+def _attend_at(cache, pos):
+    """The attend of one token a row, all rows at the scalar position
+    ``pos``: written into the cache there, slots <= pos live."""
+    def attend(q, k, v):
+        kc = jax.lax.dynamic_update_slice(cache["k"], k, (0, 0, pos, 0))
+        vc = jax.lax.dynamic_update_slice(cache["v"], v, (0, 0, pos, 0))
+        live = jnp.arange(kc.shape[2]) <= pos
+        return _attend_over(q, kc, vc, live), {"k": kc, "v": vc}
+
+    return attend
+
+
+def _attend_at_rows(cache, pos):
+    """The attend of one token a row, row i at its own ``pos[i]``: a
+    per-row scatter into the cache, slots <= pos[i] live for row i."""
+    def attend(q, k, v):
+        rows = jnp.arange(pos.shape[0])
+        kc = cache["k"].at[rows, :, pos, :].set(k[:, :, 0])
+        vc = cache["v"].at[rows, :, pos, :].set(v[:, :, 0])
+        live = jnp.arange(kc.shape[2]) <= pos[:, None, None, None]
+        return _attend_over(q, kc, vc, live), {"k": kc, "v": vc}
+
+    return attend
+
+
+def _attend_causal(q, k, v):
+    """The attend of a whole prompt from position 0: nothing is written,
+    the causal triangle is live, the keys and values are handed back."""
+    live = jnp.tril(jnp.ones((q.shape[2], k.shape[2]), bool))
+    return _attend_over(q, k, v, live), {"k": k, "v": v}
 
 
 def _jit_cache(model, attr: str, key, build):

@@ -207,13 +207,20 @@ class SelfAttention(LayerConfig):
         return _init_qkv(rng, (e, e, e), proj, out, dtype, w_init,
                          self.use_bias), {}
 
-    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None,
+              attend=None):
+        """``attend(qh, kh, vh) -> (y_heads, kept)``, where given, takes
+        the place of the attention between the projections (a KV cache is
+        one: it writes the new keys and values and attends over what it
+        holds); ``kept`` is then returned in the place of ``state``."""
         q = opsnn.linear(x, params["Wq"], params.get("bq"))
         k = opsnn.linear(x, params["Wk"], params.get("bk"))
         v = opsnn.linear(x, params["Wv"], params.get("bv"))
         h = self.num_heads
         qh, kh, vh = _split_heads(q, h), _split_heads(k, h), _split_heads(v, h)
-        if self.sequence_parallel:
+        if attend is not None:
+            y, state = attend(qh, kh, vh)
+        elif self.sequence_parallel:
             from deeplearning4j_tpu.parallel.sequence import sharded_attention
 
             y = sharded_attention(qh, kh, vh, impl=self.sequence_parallel,
@@ -505,15 +512,20 @@ class TransformerEncoderBlock(LayerConfig):
         }
         return params, {}
 
-    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        if self.remat:
-            fwd = jax.checkpoint(
-                lambda p, h, r, m: self._forward(p, h, train=train, rng=r,
-                                                 mask=m))
-            return fwd(params, x, rng, mask), state
-        return self._forward(params, x, train=train, rng=rng, mask=mask), state
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None,
+              attend=None):
+        """``attend`` is ``SelfAttention.apply``'s; what it keeps is then
+        returned in the place of ``state``."""
+        def fwd(p, h, r, m):
+            return self._forward(p, h, train=train, rng=r, mask=m,
+                                 attend=attend)
 
-    def _forward(self, params, x, *, train, rng, mask):
+        if self.remat:
+            fwd = jax.checkpoint(fwd)
+        x, kept = fwd(params, x, rng, mask)
+        return x, (state if attend is None else kept)
+
+    def _forward(self, params, x, *, train, rng, mask, attend=None):
         att = SelfAttention(
             num_heads=self.num_heads, causal=self.causal,
             dropout=self.attention_dropout,
@@ -528,38 +540,29 @@ class TransformerEncoderBlock(LayerConfig):
                 h, params[f"{which}_gamma"], params[f"{which}_beta"], eps=self.eps
             )
 
+        def sublayer(x, which, r, f):
+            """``ln(x + f(x))`` in original BERT's order (post-LN),
+            ``x + f(ln(x))`` pre-LN (more stable for deep stacks); ``f``
+            returns its output and what it keeps beside it."""
+            y, kept = f(x if self.post_ln else ln(x, which))
+            if train and self.dropout > 0.0 and r is not None:
+                y = opsnn.dropout(y, self.dropout, r)
+            return (ln(x + y, which) if self.post_ln else x + y), kept
+
+        def mlp(h):
+            h = opsnn.linear(h, params["W1"], params["b1"])
+            h = get_activation(self.activation)(h)
+            return opsnn.linear(h, params["W2"], params["b2"]), None
+
         # each sub-layer, with its norm and its residual add, is one
         # component scope of the profiler trace (observability/vocab.py)
-        if self.post_ln:  # original-BERT residual order
-            with jax.named_scope(SCOPE_ATTN):
-                a, _ = att.apply(params["attention"], {}, x, train=train,
-                                 rng=r1, mask=mask)
-                if train and self.dropout > 0.0 and r2 is not None:
-                    a = opsnn.dropout(a, self.dropout, r2)
-                x = ln(x + a, "ln1")
-            with jax.named_scope(SCOPE_MLP):
-                f = opsnn.linear(x, params["W1"], params["b1"])
-                f = get_activation(self.activation)(f)
-                f = opsnn.linear(f, params["W2"], params["b2"])
-                if train and self.dropout > 0.0 and r3 is not None:
-                    f = opsnn.dropout(f, self.dropout, r3)
-                return ln(x + f, "ln2")
-        # pre-LN (more stable for deep stacks)
         with jax.named_scope(SCOPE_ATTN):
-            a_in = ln(x, "ln1")
-            a, _ = att.apply(params["attention"], {}, a_in, train=train,
-                             rng=r1, mask=mask)
-            if train and self.dropout > 0.0 and r2 is not None:
-                a = opsnn.dropout(a, self.dropout, r2)
-            x = x + a
+            x, kept = sublayer(x, "ln1", r2, lambda h: att.apply(
+                params["attention"], {}, h, train=train, rng=r1, mask=mask,
+                attend=attend))
         with jax.named_scope(SCOPE_MLP):
-            f_in = ln(x, "ln2")
-            f = opsnn.linear(f_in, params["W1"], params["b1"])
-            f = get_activation(self.activation)(f)
-            f = opsnn.linear(f, params["W2"], params["b2"])
-            if train and self.dropout > 0.0 and r3 is not None:
-                f = opsnn.dropout(f, self.dropout, r3)
-            return x + f
+            x, _ = sublayer(x, "ln2", r3, mlp)
+        return x, kept
 
 
 @register_config

@@ -121,6 +121,45 @@ class TestCachedDecode:
                            rng=jax.random.key(0))
 
 
+def _ids(*shape):
+    return jnp.zeros(shape, jnp.int32)
+
+
+# every forward of a dense model, traced at a tiny size
+_ENTRY_POINTS = {
+    "encode": lambda m, p: m.encode(p, _ids(2, 8)),
+    "decode_step": lambda m, p: m.decode_step(
+        p, m.init_cache(2, 8), _ids(2), jnp.int32(3)),
+    "decode_step_slots": lambda m, p: m.decode_step_slots(
+        p, m.init_cache(2, 8), _ids(2), jnp.asarray([3, 5], jnp.int32)),
+    "prefill_chunk": lambda m, p: m.prefill_chunk(p, _ids(2, 8)),
+    "bert_encode": lambda m, p: m.encode(p, {"token_ids": _ids(2, 8)}),
+}
+
+
+@pytest.mark.parametrize("entry", sorted(_ENTRY_POINTS))
+def test_every_entry_point_runs_the_block_that_trains(entry, monkeypatch):
+    """One transformer block: training's, decode's, slot decode's and
+    prefill's forward each trace through TransformerEncoderBlock._forward
+    once a layer, the cache being only the attend handed to it."""
+    from deeplearning4j_tpu.models.bert import bert_tiny
+    from deeplearning4j_tpu.nn.layers.attention import TransformerEncoderBlock
+
+    model = bert_tiny() if entry == "bert_encode" else gpt_tiny()
+    calls = []
+    forward = TransformerEncoderBlock._forward
+
+    def spy(self, *args, **kwargs):
+        calls.append(self)
+        return forward(self, *args, **kwargs)
+
+    monkeypatch.setattr(TransformerEncoderBlock, "_forward", spy)
+    params = jax.eval_shape(model.init)["params"]
+    jax.eval_shape(lambda p: _ENTRY_POINTS[entry](model, p), params)
+    assert len(calls) == model.config.num_layers
+    assert all(block is model._block for block in calls)
+
+
 class TestLongContext:
     import pytest as _pytest
 

@@ -314,7 +314,9 @@ def test_every_pallas_call_of_the_package_has_a_declared_name():
     assert found == vocab.KERNEL_NAMES
 
 
-def test_the_engines_programs_have_their_own_module_names():
+@pytest.mark.parametrize("program", ["generation_decode",
+                                     "generation_prefill"])
+def test_the_engines_programs_have_their_own_module_names(program):
     from deeplearning4j_tpu.serving import GenerationEngine
 
     model = gpt_tiny()
@@ -338,11 +340,12 @@ def test_the_engines_programs_have_their_own_module_names():
     assert tuple(lowered) == vocab.GENERATION_PROGRAMS
     for name, low in lowered.items():
         assert f"module @jit_{name}" in low.as_text(), name
-    # and the serving copies of the block carry the component scopes
-    text = lowered["generation_decode"].as_text(debug_info=True)
+    # and the block, walked with the cache's attend, carries the component
+    # scopes in the programs that run the model
+    text = lowered[program].as_text(debug_info=True)
     for scope in (vocab.SCOPE_EMBED, vocab.SCOPE_ATTN, vocab.SCOPE_MLP,
                   vocab.SCOPE_HEAD):
-        assert f"jit(generation_decode)/{scope}/" in text, scope
+        assert f"jit({program})/{scope}/" in text, scope
 
 
 # -- host spans on the profiler's clock ---------------------------------------
