@@ -99,26 +99,28 @@ def flash_block_sizes(seq_q: int, seq_k: int, head_dim: int,
     table in PERF.md section 5), every kernel is fastest there: their
     per-tile work that does not grow with the tile's width (the row maxima
     and sums, the rescale of the accumulators, the step's copies) outweighs
-    the dead pairs a large tile drags in. One exception, at the one shape
-    where it was measured: 1024 queries by 1024 keys, causal, where
-    ``flash_bwd_dkv`` at 512 x 512 skips the dead quarter (1.106 ms against
-    1.225; at 4096 it reads 3.22 against 3.08, so 1024 stands there).
+    the dead pairs a large tile drags in, and a tile that the causal
+    diagonal crosses corner to corner no longer computes its dead half
+    (``flash_attention.TilePlan``: four row sub-blocks of 256 at this
+    size). That ended the one exception, ``flash_bwd_dkv`` at 512 x 512 for
+    1024 causal queries and keys, which skipped the dead quarter through
+    the grid: 0.821 ms a layer at one tile in four sub-blocks against 1.042
+    at 512 x 512 tiles in two each and 1.051 whole (PR 32, the same trace).
 
-    Not measured, so the rule is a guess there: any ``head_dim`` but 64,
-    float32 operands, calls that are not causal, carry a ``key_mask`` or
-    have ``seq_q != seq_k``, and sequences that are no multiple of the tile
-    (padded up to whole tiles). Heads wider than 128 get 512 x 512 because
-    the chip's compiler refuses 1024 x 1024 there (``flash_bwd_dkv`` at 256
-    wide in float32 does not fit its share of VMEM); what the defaults
-    compile for is pinned in ``tests/test_flash_compiles_for_v5e.py``.
+    Not measured, so the rule is a guess there: any ``head_dim`` but 64 and
+    128, float32 operands, calls that are not causal, carry a ``key_mask``
+    or have ``seq_q != seq_k``, and sequences that are no multiple of the
+    tile (padded up to whole tiles). Heads wider than 128 get 512 x 512
+    because the chip's compiler refuses 1024 x 1024 there (``flash_bwd_dkv``
+    at 256 wide in float32 does not fit its share of VMEM); what the
+    defaults compile for is pinned in
+    ``tests/test_flash_compiles_for_v5e.py``.
 
     DL4J_TPU_FLASH_BLOCK_Q/K, where set, give all three kernels that one
-    geometry.
+    geometry; the split follows from it.
     """
     largest = 1024 if head_dim <= 128 else 512
-    big = (largest, largest)
-    dkv = (512, 512) if causal and seq_q == seq_k == 1024 else big
-    blocks = FlashBlocks(fwd=big, dkv=dkv, dq=big)
+    blocks = FlashBlocks(*[(largest, largest)] * 3)
     env_q = os.environ.get("DL4J_TPU_FLASH_BLOCK_Q")
     env_k = os.environ.get("DL4J_TPU_FLASH_BLOCK_K")
     if env_q or env_k:

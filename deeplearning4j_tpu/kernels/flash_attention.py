@@ -15,9 +15,15 @@ above the causal diagonal is dead (skipped, and its index map points at a
 block already fetched, so no copy is issued for it); every other tile is
 live and builds the mask the shapes call for (the causal triangle, padded
 keys or queries, a key mask), each term only where the shapes can make it
-false. A per-example key padding mask ([B,S] 1/0 — the BERT attention-mask
-case) runs inside the kernel; only arbitrary additive ``bias`` falls back
-to the XLA reference. The head dimension is never padded (a block as wide
+false. A live tile that the diagonal crosses corner to corner is not
+computed whole: inside its one grid step it is swept in static row
+sub-blocks of ``_SUB_BLOCK`` rows, each against only the key prefix of the
+tile that its rows can see (slices known at trace time: straight-line code
+to Mosaic, no new grid step, copy or scratch). The pairs skipped are those
+the causal mask sets to -1e30, whose p and ds are exactly 0, so only the
+order of the additions changes. A per-example key padding mask ([B,S] 1/0
+— the BERT attention-mask case) runs inside the kernel; only arbitrary
+additive ``bias`` falls back to the XLA reference. The head dimension is never padded (a block as wide
 as the array is legal at any width; 8 to 256 were compiled for the v5e),
 and the softmax scale goes on the ``[block_q, D]`` operand, not on the
 scores.
@@ -37,13 +43,25 @@ all of that is the geometry: at 256 x 512 the padding and the scale
 together gave 2%, the clamped index maps 8%. The kernels are bound by the
 matrix unit at half rate (a 64-wide head fills half of it in every product)
 and by per-tile work that does not shrink with the tile, so the largest
-tile wins, dead pairs and all. Tried and dropped, each measured: a third
-kind of tile, "interior" (every pair live, so no iota, compare or select:
-0% at 1024 keys, 0.5-2% at 4096, for a second body in every kernel);
-sweeping a grid step's keys tile by tile inside the kernel (``fori_loop``,
-bounds from the plan: 6% off ``flash_bwd_dq``, the other two slower); and
-building ``flash_bwd_dkv``'s score tile key-major so that its two
-transposed products become plain ones (1.096 against 1.106 ms: nothing).
+tile wins. The dead half of that tile then went through the split above
+(PR 32; the same calls, whole / in 2 / 4 / 8 sub-blocks): ``flash_bwd_dq``
+0.874 / 0.678 / 0.580 / 0.574 ms, ``flash_bwd_dkv`` at one 1024 tile 1.223
+/ 0.961 / 0.821 / 0.890 (its 512 x 512 tiles of before: 1.051), so four
+sub-blocks of 256 rows; ``flash_fwd`` 0.698 / 0.863 / 0.838 / 0.694, a
+*loss*, until its running maximum and sum were read as the lane-broadcast
+rows they are stored as (read by their first column they cost a lane
+broadcast a use: 3,700 of the kernel's 4,900 instruction bundles held a
+cross-lane operation): 0.679 / 0.521 / 0.515 / 0.591. At ``[2, 8, 4096,
+128]`` (4 diagonal tiles of 10 live) the three read 0.739 / 1.024 / 0.877
+whole and 0.615 / 0.888 / 0.783 in four. The body that computes a tile
+whole, which a grid of several key tiles still needs under the diagonal,
+builds no causal term there (tried alone before: 0-2%). Tried and dropped,
+each measured: ``flash_bwd_dkv`` split by key columns against the query
+suffix (0.885 against 0.821 ms); sweeping a grid step's keys tile by tile
+inside the kernel (``fori_loop``, bounds from the plan: 6% off
+``flash_bwd_dq``, the other two slower); and building ``flash_bwd_dkv``'s
+score tile key-major so that its two transposed products become plain ones
+(1.096 against 1.106 ms: nothing).
 
 The same kernels run everywhere: compiled on TPU, interpret-mode in CPU
 tests (via DL4J_TPU_FORCE_PALLAS=1; plain CPU callers never reach them
@@ -139,6 +157,12 @@ def reference_attention(q, k, v, *, causal=False, bias=None, key_mask=None,
     return jnp.einsum("bhts,bhsd->bhtd", p, v)
 
 
+# Rows of a diagonal tile's sub-block (TilePlan.sub_blocks): of 512, 256 and
+# 128, the fastest in all three kernels at head widths 64 and 128 (PERF.md
+# section 5); every sub-block is a copy of the body in the kernel's code.
+_SUB_BLOCK = 256
+
+
 @dataclasses.dataclass(frozen=True)
 class TilePlan:
     """Which score tiles of a ``[seq_q, seq_k]`` attention a kernel with
@@ -150,7 +174,14 @@ class TilePlan:
     numbers. Every method takes ints, numpy arrays or traced values.
 
     A tile is dead when every pair of it lies above the causal diagonal: it
-    is skipped and nothing is fetched for it. Every other tile is live.
+    is skipped and nothing is fetched for it. Every other tile is live. A
+    live tile is diagonal when the causal diagonal runs from its first
+    corner to its last (square blocks, and its first query row's last
+    visible key is its first key): nearly half of its pairs are dead, so
+    it is computed in ``sub_blocks`` row sub-blocks, each against only the
+    key prefix its rows can see (:meth:`parts`). Where some tile is
+    diagonal, every other live tile lies under the diagonal whole and has
+    no causal term to build.
     """
 
     seq_q: int
@@ -188,10 +219,44 @@ class TilePlan:
         (otherwise every row's first live block holds key 0)."""
         return self.causal and self.seq_k < self.seq_q
 
+    @property
+    def sub_blocks(self) -> int:
+        """Row sub-blocks of a diagonal tile; 1 where no tile is diagonal
+        (not causal, unequal blocks, an offset that no tile edge meets) or
+        the block holds no two sub-blocks of ``_SUB_BLOCK`` rows."""
+        if not (self.causal and self.block_q == self.block_k
+                and self.offset % self.block_q == 0
+                and self.block_q % _SUB_BLOCK == 0):
+            return 1
+        return self.block_q // _SUB_BLOCK
+
     def live(self, qi, ki):
         if not self.causal:
             return True
         return (qi + 1) * self.block_q - 1 + self.offset >= ki * self.block_k
+
+    def diagonal(self, qi, ki):
+        """Whether the (qi, ki) tile is run by the split body."""
+        if self.sub_blocks == 1:
+            return False
+        return qi * self.block_q + self.offset == ki * self.block_k
+
+    def whole(self, qi, ki):
+        """Whether the (qi, ki) tile is live and computed whole."""
+        if self.sub_blocks == 1:
+            return self.live(qi, ki)
+        return qi * self.block_q + self.offset > ki * self.block_k
+
+    def parts(self, diagonal: bool) -> list:
+        """The rectangles of a live tile that a kernel computes, each as
+        (first row, rows, keys from the tile's first, whether the causal
+        term can be false in it)."""
+        if not diagonal:
+            return [(0, self.block_q, self.block_k,
+                     self.causal and self.sub_blocks == 1)]
+        size = self.block_q // self.sub_blocks
+        return [(r * size, size, (r + 1) * size, True)
+                for r in range(self.sub_blocks)]
 
     def last_live_k(self, qi):
         """The last live key block of query block ``qi``, inside the array."""
@@ -221,15 +286,34 @@ class TilePlan:
             return qi
         return _xp(qi, ki).maximum(qi, self.first_live_q(ki))
 
-    def live_tiles(self) -> np.ndarray:
-        """``[n_q, n_k]`` of bool."""
+    def _of_every_tile(self, answer) -> np.ndarray:
         qi = np.arange(self.n_q)[:, None]
         ki = np.arange(self.n_k)[None, :]
-        return np.broadcast_to(self.live(qi, ki), (self.n_q, self.n_k))
+        return np.broadcast_to(answer(qi, ki), (self.n_q, self.n_k))
+
+    def live_tiles(self) -> np.ndarray:
+        """``[n_q, n_k]`` of bool."""
+        return self._of_every_tile(self.live)
+
+    def diagonal_tiles(self) -> np.ndarray:
+        """``[n_q, n_k]`` of bool."""
+        return self._of_every_tile(self.diagonal)
 
     def counts(self) -> dict:
+        """What the flight event says of a kernel: its tiles by kind, and
+        the pairs it computes over the pairs the mask of the shapes leaves
+        (padding counts as computed, not as required)."""
         live = int(self.live_tiles().sum())
-        return {"dead": self.n_q * self.n_k - live, "live": live}
+        diagonal = int(self.diagonal_tiles().sum())
+        touched = ((live - diagonal) * self.block_q * self.block_k
+                   + diagonal * sum(rows * keys for _, rows, keys, _
+                                    in self.parts(True)))
+        required = int(np.clip(np.arange(self.seq_q) + self.offset + 1, 0,
+                               self.seq_k).sum()
+                       ) if self.causal else self.seq_q * self.seq_k
+        return {"dead": self.n_q * self.n_k - live, "live": live,
+                "diagonal": diagonal, "sub_blocks": self.sub_blocks,
+                "pairs_touched_over_required": round(touched / required, 4)}
 
 
 def _xp(*xs):
@@ -237,84 +321,125 @@ def _xp(*xs):
     return jnp if any(isinstance(x, jax.Array) for x in xs) else np
 
 
-def _tile_mask(plan, qi, ki, km_ref, shape):
-    """The mask of a live tile: key padding, the per-example key mask,
-    query padding (the backward's padded rows carry no residuals) and the
-    causal triangle; each term only where the shapes can make it false,
-    and None where none can."""
+def _when(condition):
+    """``pl.when``, decided while tracing where the condition is a number
+    (a grid of one tile asks the plan with numbers)."""
+    if isinstance(condition, jax.Array):
+        return pl.when(condition)
+    return (lambda body: body()) if condition else (lambda body: None)
+
+
+def _grid_ids(plan, q_axis, k_axis):
+    """(qi, ki) of a grid step; 0 and not traced along an axis of one tile."""
+    return (pl.program_id(q_axis) if plan.n_q > 1 else 0,
+            pl.program_id(k_axis) if plan.n_k > 1 else 0)
+
+
+def _on_live_tile(plan, qi, ki, body):
+    """Run ``body(first_row, rows, keys, causal)`` on each rectangle that
+    the (qi, ki) step has to compute: a diagonal tile's sub-blocks, any
+    other live tile whole, a dead tile not at all."""
+    for diagonal, here in ((True, plan.diagonal(qi, ki)),
+                           (False, plan.whole(qi, ki))):
+        @_when(here)
+        def _parts():
+            for part in plan.parts(diagonal):
+                body(*part)
+
+
+def _tile_mask(plan, qi, ki, km_ref, first_row, rows, keys, causal):
+    """The mask of a rectangle of a live tile: key padding, the per-example
+    key mask, query padding (the backward's padded rows carry no residuals)
+    and the causal triangle; each term only where the shapes can make it
+    false, and None where none can."""
+    shape = (rows, keys)
     key_idx = ki * plan.block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     terms = []
     if plan.pads_k:
         terms.append(key_idx < plan.seq_k)
-    if km_ref is not None:
-        terms.append(km_ref[0] > 0)  # [1, bk] broadcasts over rows
-    if plan.causal or plan.pads_q:
-        query_idx = qi * plan.block_q + jax.lax.broadcasted_iota(
-            jnp.int32, shape, 0)
+    if km_ref is not None:  # [1, keys] broadcasts over rows
+        terms.append(km_ref[0, :, :keys] > 0)
+    if causal or plan.pads_q:
+        query_idx = (qi * plan.block_q + first_row
+                     + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
         if plan.pads_q:
             terms.append(query_idx < plan.seq_q)
-        if plan.causal:
+        if causal:
             terms.append(query_idx + plan.offset >= key_idx)
     if not terms:
         return None
     return jnp.broadcast_to(functools.reduce(operator.and_, terms), shape)
 
 
-def _scaled(q_ref, scale, mm):
-    """q times the softmax scale, on the ``[block_q, D]`` operand and not on
-    the ``[block_q, block_k]`` scores: in float32 before the cast, so it is
+def _scaled(q, scale, mm):
+    """q times the softmax scale, on the ``[rows, D]`` operand and not on
+    the ``[rows, keys]`` scores: in float32 before the cast, so it is
     exact where the scale is a power of two (64 ** -0.5 is)."""
-    return (q_ref[0].astype(jnp.float32) * scale).astype(mm)
+    return (q.astype(jnp.float32) * scale).astype(mm)
+
+
+def _lanes(x, width):
+    """``[rows, 128]`` whose lanes are equal, as ``[rows, width]``."""
+    if width <= x.shape[1]:
+        return x[:, :width]
+    return jnp.tile(x, (1, -(-width // x.shape[1])))[:, :width]
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, lse_ref, m_scr, l_scr,
                   acc_scr, *, scale, plan):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    n_k = pl.num_programs(2)
+    qi, ki = _grid_ids(plan, 1, 2)
 
-    @pl.when(ki == 0)
+    @_when(ki == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(plan.live(qi, ki))
-    def _compute():
+    def _compute(first_row, n_rows, n_keys, causal):
+        rows = slice(first_row, first_row + n_rows)
         mm = _matmul_dtype(q_ref.dtype)
         s = jax.lax.dot_general(
-            _scaled(q_ref, scale, mm), k_ref[0].astype(mm),
+            _scaled(q_ref[0, rows, :], scale, mm),
+            k_ref[0, :n_keys, :].astype(mm),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bq, bk]
-        mask = _tile_mask(plan, qi, ki, km_ref, s.shape)
+        )  # [rows, keys]
+        mask = _tile_mask(plan, qi, ki, km_ref, first_row, n_rows, n_keys,
+                          causal)
         if mask is not None:
             s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_scr[:, :1]
+        # The running maximum and sum stay the lane-broadcast [rows, 128]
+        # they are stored as: read by their first column they cost a lane
+        # broadcast each, which a diagonal tile's sub-blocks do not repay.
+        m_prev = m_scr[rows, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        if km_ref is not None or plan.rows_can_be_empty:
+        p = jnp.exp(s - _lanes(m_new, n_keys))
+        if mask is not None and (km_ref is not None
+                                 or plan.rows_can_be_empty):
             # A row masked whole so far keeps m_new at _NEG_INF, and
             # exp(s - m_new) would be 1 there, not 0.
             p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(mm), v_ref[0].astype(mm), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        l_scr[rows, :] = (l_scr[rows, :] * alpha
+                          + jnp.sum(p, axis=1, keepdims=True))
+        acc_scr[rows, :] = (
+            acc_scr[rows, :] * _lanes(alpha, acc_scr.shape[1])
+            + jax.lax.dot_general(
+                p.astype(mm), v_ref[0, :n_keys, :].astype(mm),
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32))
+        m_scr[rows, :] = m_new
 
-    @pl.when(ki == n_k - 1)
+    _on_live_tile(plan, qi, ki, _compute)
+
+    @_when(ki == plan.n_k - 1)
     def _finish():
         # Fully-masked rows: l == 0 → output 0 (callers never read them).
-        o_ref[0] = (
-            acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)
-        ).astype(o_ref.dtype)
+        l = jnp.maximum(l_scr[:], 1e-30)
+        o_ref[0] = (acc_scr[:] / _lanes(l, acc_scr.shape[1])
+                    ).astype(o_ref.dtype)
         if lse_ref is not None:
             # Row logsumexp, lane-broadcast — the backward residual. Fully
             # masked / padded rows get ~-1e30; backward clamps before exp.
-            lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
+            lse_ref[0] = m_scr[:] + jnp.log(l)
 
 
 def _round_up(x, m):
@@ -408,62 +533,63 @@ def _flash_fwd(q, k, v, key_mask, *, causal, scale, block_q, block_k,
 
 
 def _bwd_recompute(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref, delta_ref,
-                   qi, ki, *, scale, plan):
-    """Recompute p and ds for one (q-block, kv-block) pair — the math both
-    backward kernels share. Returns (k, g, p, ds) in the MXU compute dtype
-    (see _matmul_dtype); ds lacks the softmax scale, which each kernel puts
-    on its ``[*, D]`` accumulator at the end."""
+                   qi, ki, first_row, n_rows, n_keys, causal, *, scale, plan):
+    """Recompute p and ds for one rectangle of a (q-block, kv-block) pair —
+    the math both backward kernels share. Returns (q, k, g, p, ds) in the
+    MXU compute dtype (see _matmul_dtype); ds lacks the softmax scale, which
+    each kernel puts on its ``[*, D]`` accumulator at the end."""
+    rows = slice(first_row, first_row + n_rows)
     mm = _matmul_dtype(q_ref.dtype)
-    k = k_ref[0].astype(mm)
-    g = g_ref[0].astype(mm)
+    q = q_ref[0, rows, :]
+    k = k_ref[0, :n_keys, :].astype(mm)
+    g = g_ref[0, rows, :].astype(mm)
     # Clamp: fully-masked rows carry lse ≈ -1e30; their scores are -1e30
     # too, so the clamped difference underflows exp to exactly 0 (no
     # inf·0 NaNs).
-    lse = jnp.maximum(lse_ref[0][:, :1], -1e20)
-    delta = delta_ref[0][:, :1]
+    lse = jnp.maximum(lse_ref[0, rows, :1], -1e20)
+    delta = delta_ref[0, rows, :1]
 
     s = jax.lax.dot_general(
-        _scaled(q_ref, scale, mm), k, (((1,), (1,)), ((), ())),
+        _scaled(q, scale, mm), k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    mask = _tile_mask(plan, qi, ki, km_ref, s.shape)
+    mask = _tile_mask(plan, qi, ki, km_ref, first_row, n_rows, n_keys, causal)
     if mask is not None:
         s = jnp.where(mask, s, _NEG_INF)
-    p = jnp.exp(s - lse)  # [bq, bk]; exactly 0 where masked
+    p = jnp.exp(s - lse)  # [rows, keys]; exactly 0 where masked
     dp = jax.lax.dot_general(
-        g, v_ref[0].astype(mm), (((1,), (1,)), ((), ())),
+        g, v_ref[0, :n_keys, :].astype(mm), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     ds = p * (dp - delta)
     # p/ds feed straight into MXU matmuls at the call sites — hand them
     # over in the compute dtype (fp32 accumulation happens there).
-    return k, g, p.astype(mm), ds.astype(mm)
+    return q.astype(mm), k, g, p.astype(mm), ds.astype(mm)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref,
                           delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                           scale, plan):
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    n_q = pl.num_programs(2)
+    qi, ki = _grid_ids(plan, 2, 1)
 
-    @pl.when(qi == 0)
+    @_when(qi == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(plan.live(qi, ki))
-    def _compute():
-        _, g, p, ds = _bwd_recompute(
+    def _compute(first_row, n_rows, n_keys, causal):
+        q, _, g, p, ds = _bwd_recompute(
             q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref, delta_ref,
-            qi, ki, scale=scale, plan=plan)
-        dv_scr[:] += jax.lax.dot_general(
+            qi, ki, first_row, n_rows, n_keys, causal, scale=scale, plan=plan)
+        dv_scr[:n_keys, :] += jax.lax.dot_general(
             p, g, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q_ref[0].astype(ds.dtype), (((0,), (0,)), ((), ())),
+        dk_scr[:n_keys, :] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32
         )
 
-    @pl.when(qi == n_q - 1)
+    _on_live_tile(plan, qi, ki, _compute)
+
+    @_when(qi == plan.n_q - 1)
     def _finish():
         dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -471,24 +597,24 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref,
                          delta_ref, dq_ref, dq_scr, *, scale, plan):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    n_k = pl.num_programs(2)
+    qi, ki = _grid_ids(plan, 1, 2)
 
-    @pl.when(ki == 0)
+    @_when(ki == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(plan.live(qi, ki))
-    def _compute():
-        k, _, _, ds = _bwd_recompute(
+    def _compute(first_row, n_rows, n_keys, causal):
+        _, k, _, _, ds = _bwd_recompute(
             q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref, delta_ref,
-            qi, ki, scale=scale, plan=plan)
-        dq_scr[:] += jax.lax.dot_general(
+            qi, ki, first_row, n_rows, n_keys, causal, scale=scale, plan=plan)
+        rows = slice(first_row, first_row + n_rows)
+        dq_scr[rows, :] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(ki == n_k - 1)
+    _on_live_tile(plan, qi, ki, _compute)
+
+    @_when(ki == plan.n_k - 1)
     def _finish():
         dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
@@ -618,6 +744,25 @@ def _flash_vjp_bwd(causal, scale, blocks, res, g):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+def _how_traced():
+    """What the kernels' text depends on besides a call's arguments, read
+    while tracing: compiled or interpreted, and the products' dtype."""
+    return (_on_tpu(), _interpret(), _matmul_dtype(jnp.float32),
+            _matmul_dtype(jnp.bfloat16))
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _flash_traced_once(q, k, v, key_mask, causal, scale, blocks, how):
+    """``_flash`` behind a ``jax.jit`` of its own: the layers of a model
+    trace and lower each kernel once, not once a layer. A diagonal tile's
+    sub-blocks are copies of the body in the kernel's code, and traced a
+    layer at a time they cost ``gpt2_small``'s twelve layers 5 s of set-up
+    (PERF.md section 6, PR 32). ``how`` (:func:`_how_traced`) only keys the
+    cache of traces."""
+    del how
+    return _flash(q, k, v, key_mask, causal, scale, blocks)
+
+
 def _flash_on_mesh(mesh, q, k, v, key_mask, causal, scale, blocks):
     """The kernel under a multi-device mesh: inside ``shard_map``, batch
     split over the data-like axes and heads over the model axis (attention
@@ -638,8 +783,11 @@ def _flash_on_mesh(mesh, q, k, v, key_mask, causal, scale, blocks):
         args.append(key_mask)
         specs.append(P(batch_axes or None, None))
 
+    how = _how_traced()
+
     def local(q, k, v, *km):
-        return _flash(q, k, v, km[0] if km else None, causal, scale, blocks)
+        return _flash_traced_once(q, k, v, km[0] if km else None, causal,
+                                  scale, blocks, how)
 
     return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
                          out_specs=qkv_spec, check_vma=False)(*args)
@@ -690,14 +838,17 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None, bias=None,
     mesh = _active_kernel_mesh()
     if mesh is not None:
         return _flash_on_mesh(mesh, q, k, v, key_mask, causal, scale, blocks)
-    return _flash(q, k, v, key_mask, causal, scale, blocks)
+    return _flash_traced_once(q, k, v, key_mask, causal, scale, blocks,
+                              _how_traced())
 
 
 def _record_plan(seq_q, seq_k, head_dim, causal, has_mask, blocks):
     """One ``kernel.flash_plan`` flight event per call, at trace time (a
     jitted step traces its calls once, so this costs a run nothing): the
-    geometry of each kernel and how many of its tiles are dead (skipped,
-    nothing fetched) and live."""
+    geometry of each kernel, how many of its tiles are dead (skipped,
+    nothing fetched), live and, of the live, diagonal (computed in
+    ``sub_blocks`` row sub-blocks), and the pairs it computes over the
+    pairs the call's mask leaves (``TilePlan.counts``)."""
     from deeplearning4j_tpu.observability.flightrecorder import record_event
 
     record_event(

@@ -86,6 +86,28 @@ def test_forward_and_backward_compile_at_the_default_geometry(
     assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
+@pytest.mark.parametrize("block", [1024, 512, 256, 128])
+def test_the_gpt2_call_compiles_at_each_split_the_rule_can_return(
+        block, one_chip, as_on_tpu):
+    """``gpt2_small.train_s1024``'s call with all three kernels at one
+    block size: a diagonal tile is cut into ``block // _SUB_BLOCK`` row
+    sub-blocks, each a copy of the body in the kernel's code, down to one
+    (the tile whole)."""
+    plan = fa.TilePlan(1024, 1024, block, block, True)
+    assert plan.sub_blocks == max(1, block // fa._SUB_BLOCK)
+    q = jax.ShapeDtypeStruct((16, 12, 1024, 64), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa._flash(q, k, v, None, True, 0.125,
+                        FlashBlocks(*[(block, block)] * 3))
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
 def test_the_gpt2_head_writes_its_logits_and_no_other_array_of_their_size(
         one_chip, as_on_tpu):
     """A count of the compiled program, not a time: the one-layer

@@ -1,7 +1,8 @@
 """The flash kernels' tile plan (kernels/flash_attention.py::TilePlan): the
-one function that says which score tiles are dead and which are live,
-checked here against the dense mask it stands for; and the flight event
-that reports it at trace time."""
+one function that says which score tiles are dead, which are live, and
+which of the live ones the causal diagonal crosses corner to corner and so
+are computed in row sub-blocks; checked here against the dense mask it
+stands for; and the flight event that reports it at trace time."""
 
 import itertools
 
@@ -38,8 +39,9 @@ def test_the_plan_is_the_dense_mask(seq_q, seq_k, causal):
         dense = _dense_mask(plan)
         live = plan.live_tiles()
         assert live.shape == (plan.n_q, plan.n_k)
-        assert plan.counts() == {"dead": int((~live).sum()),
-                                 "live": int(live.sum())}
+        counts = plan.counts()
+        assert (counts["dead"], counts["live"]) == (int((~live).sum()),
+                                                    int(live.sum()))
         rebuilt = np.zeros_like(dense)
         for qi, ki in np.ndindex(*live.shape):
             rows = slice(qi * block_q, (qi + 1) * block_q)
@@ -79,14 +81,110 @@ def test_a_dead_step_fetches_nothing_new(seq_q, seq_k, causal):
                                       fetch_q[1:][dead_before])
 
 
+# name: (seq_q, seq_k, block_q, block_k, causal), then what the plan has to
+# say: sub_blocks, diagonal tiles, live tiles. The blocks are as
+# ``flash_attention`` hands them over, clamped to the sequence.
+_SUB = fa._SUB_BLOCK
+_SPLIT_CASES = {
+    "gpt2_small_one_tile_of_1024": ((1024, 1024, 1024, 1024, True),
+                                    1024 // _SUB, 1, 1),
+    "zaya_4096_at_1024_tiles": ((4096, 4096, 1024, 1024, True),
+                                1024 // _SUB, 4, 10),
+    "1024_at_512_tiles": ((1024, 1024, 512, 512, True), 512 // _SUB, 2, 3),
+    "a_block_of_one_sub_block": ((4096, 4096, _SUB, _SUB, True), 1, 0, 136),
+    "a_block_that_is_no_multiple_of_it": ((768, 768, 384, 384, True),
+                                          1, 0, 3),
+    "offset_of_one_tile": ((1024, 2048, 1024, 1024, True),
+                           1024 // _SUB, 1, 2),
+    "fewer_keys_than_queries_by_two_tiles": ((2048, 1024, 512, 512, True),
+                                             512 // _SUB, 2, 3),
+    "offset_that_no_tile_edge_meets": ((1024, 1536, 1024, 1024, True),
+                                       1, 0, 2),
+    "cross_512_by_2048_clamped": ((512, 2048, 512, 1024, True), 1, 0, 2),
+    "unequal_blocks": ((1024, 1024, 512, 1024, True), 1, 0, 2),
+    "not_causal": ((1024, 1024, 1024, 1024, False), 1, 0, 1),
+    "ragged_1000_padded_to_one_tile": ((1000, 1000, 1000, 1024, True),
+                                       1, 0, 1),
+    "ragged_1000_at_512_tiles": ((1000, 1000, 512, 512, True),
+                                 512 // _SUB, 2, 3),
+}
+
+
+def _touched(plan):
+    """``[n_q * block_q, n_k * block_k]`` of bool: the pairs a kernel
+    computes, tile by tile, from ``diagonal`` and ``parts`` alone."""
+    touched = np.zeros((plan.n_q * plan.block_q, plan.n_k * plan.block_k),
+                       bool)
+    for qi, ki in np.ndindex(plan.n_q, plan.n_k):
+        if not plan.live(qi, ki):
+            assert not plan.diagonal(qi, ki) and not plan.whole(qi, ki)
+            continue
+        diagonal = bool(plan.diagonal(qi, ki))
+        assert bool(plan.whole(qi, ki)) is not diagonal
+        for row, rows, keys, causal in plan.parts(diagonal):
+            r = qi * plan.block_q + row
+            c = ki * plan.block_k
+            assert not touched[r:r + rows, c:c + keys].any()  # once each
+            touched[r:r + rows, c:c + keys] = True
+            if not causal:  # then the causal term kills no pair of it
+                i = np.arange(r, r + rows)[:, None]
+                j = np.arange(c, c + keys)[None, :]
+                assert not plan.causal or (j <= i + plan.offset).all()
+    return touched
+
+
+@pytest.mark.parametrize("case", sorted(_SPLIT_CASES))
+def test_which_tiles_are_diagonal_and_how_they_are_split(case):
+    args, sub_blocks, diagonal, live = _SPLIT_CASES[case]
+    plan = fa.TilePlan(*args)
+    assert plan.sub_blocks == sub_blocks
+    assert int(plan.diagonal_tiles().sum()) == diagonal
+    counts = plan.counts()
+    assert (counts["sub_blocks"], counts["diagonal"], counts["live"]) == (
+        sub_blocks, diagonal, live)
+    if sub_blocks == 1:
+        assert plan.parts(False) == [(0, plan.block_q, plan.block_k,
+                                      plan.causal)]
+    else:
+        assert len(plan.parts(True)) == sub_blocks
+    # every pair the mask leaves is computed, and counted as the event says
+    dense = _dense_mask(plan)
+    touched = _touched(plan)
+    assert touched[:plan.seq_q, :plan.seq_k][dense].all()
+    assert counts["pairs_touched_over_required"] == pytest.approx(
+        touched.sum() / dense.sum(), abs=1e-4)
+
+
+def test_the_pairs_touched_at_the_benchmarks_shapes():
+    """The figures ISSUE 32 and PERF.md quote."""
+    def ratio(*args):
+        return fa.TilePlan(*args).counts()["pairs_touched_over_required"]
+
+    square = 1024 * 1024 / (1024 * 1025 / 2)  # 1.998: one tile, whole
+    n = 1024 // _SUB
+    assert ratio(1024, 1024, 1024, 1024, True) == pytest.approx(
+        square * (n + 1) / (2 * n), abs=1e-4)
+    assert ratio(1024, 1024, 1024, 1024, False) == 1.0
+    # 4 diagonal tiles of 10 live, each cut to (n + 1) / 2n of itself
+    assert ratio(4096, 4096, 1024, 1024, True) == pytest.approx(
+        (6 + 4 * (n + 1) / (2 * n)) * 1024 ** 2 / (4096 * 4097 / 2),
+        abs=1e-4)
+
+
 def test_the_plan_answers_traced_indices_like_numbers():
-    plan = fa.TilePlan(100, 128, 32, 32, True)
+    for plan in (fa.TilePlan(100, 128, 32, 32, True),
+                 fa.TilePlan(3 * _SUB, 4 * _SUB, 2 * _SUB, 2 * _SUB, True)):
+        _traced_like_numbers(plan)
+
+
+def _traced_like_numbers(plan):
     qi, ki = np.meshgrid(np.arange(plan.n_q), np.arange(plan.n_k),
                          indexing="ij")
 
     def answers(qi, ki):
         return (plan.live(qi, ki), plan.fetch_k(qi, ki),
-                plan.fetch_q(qi, ki))
+                plan.fetch_q(qi, ki), plan.diagonal(qi, ki),
+                plan.whole(qi, ki))
 
     for got, want in zip(jax.jit(answers)(jnp.asarray(qi), jnp.asarray(ki)),
                          answers(qi, ki)):
@@ -118,11 +216,16 @@ def test_the_plan_is_a_flight_event_of_every_traced_call(flight):
     data = first["data"]
     assert (data["seq_q"], data["seq_k"], data["head_dim"]) == (128, 128, 16)
     assert data["causal"] is True and data["key_mask"] is False
-    want = {"block_q": 32, "block_k": 64, "dead": 2, "live": 6}
+    want = {"block_q": 32, "block_k": 64, "dead": 2, "live": 6,
+            "diagonal": 0, "sub_blocks": 1,
+            "pairs_touched_over_required": round(
+                6 * 32 * 64 / (128 * 129 / 2), 4)}
     assert data["fwd"] == data["dkv"] == data["dq"] == want
     assert second["data"]["causal"] is False
     assert second["data"]["dq"] == {"block_q": 64, "block_k": 128,
-                                    "dead": 0, "live": 2}
+                                    "dead": 0, "live": 2, "diagonal": 0,
+                                    "sub_blocks": 1,
+                                    "pairs_touched_over_required": 1.0}
     # a recorder armed later sees the plans of the next trace as well
     later = set_flight_recorder(FlightRecorder())
     jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
@@ -144,8 +247,8 @@ def test_the_default_plan_at_the_benchmarks_shape(flight):
     assert _QUOTED == {name: data[name] for name in ("fwd", "dkv", "dq")}
 
 
-_QUOTED = {
-    "fwd": {"block_q": 1024, "block_k": 1024, "dead": 0, "live": 1},
-    "dkv": {"block_q": 512, "block_k": 512, "dead": 1, "live": 3},
-    "dq": {"block_q": 1024, "block_k": 1024, "dead": 0, "live": 1},
-}
+_ONE_TILE_IN_FOUR = {"block_q": 1024, "block_k": 1024, "dead": 0, "live": 1,
+                    "diagonal": 1, "sub_blocks": 4,
+                    "pairs_touched_over_required": 1.2488}
+_QUOTED = {"fwd": _ONE_TILE_IN_FOUR, "dkv": _ONE_TILE_IN_FOUR,
+           "dq": _ONE_TILE_IN_FOUR}

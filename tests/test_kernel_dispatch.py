@@ -106,6 +106,15 @@ class TestExplicitPallasIsAContract:
             rtol=2e-5, atol=2e-6)
 
 
+def _flash_sites(text):
+    """The calls of the functions that hold the flash kernels
+    (``flash_attention._flash_traced_once``: the forward's, and the
+    backward's under a numbered name)."""
+    import re
+
+    return re.findall(r"call @_flash_traced_once(?:_\d+)?\(", text)
+
+
 class TestTheStepLowersForTheChip:
     """The two benchmark steps at tiny depth and the cells' own sequence
     lengths and head width (64), lowered for the TPU platform."""
@@ -120,7 +129,9 @@ class TestTheStepLowersForTheChip:
             lowering_platforms=("tpu",)).as_text(debug_info=names)
 
     def test_gpt2_shaped_step_feeds_the_kernels_64_wide(self, as_on_tpu):
-        """3 ``tpu_custom_call``s a block, by name, q, k and v 64 wide."""
+        """The three kernels by name, q, k and v 64 wide, each lowered
+        once in a function that every block calls: the forward's and the
+        backward's."""
         import re
 
         from deeplearning4j_tpu.models.gpt import gpt_tiny
@@ -132,9 +143,8 @@ class TestTheStepLowersForTheChip:
                  if "@tpu_custom_call" in line]
         names = [re.search(r'kernel_name = "(\w+)"', c).group(1)
                  for c in calls]
-        assert sorted(names) == sorted(
-            ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
-            * model.config.num_layers)
+        assert sorted(names) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+        assert len(_flash_sites(text)) == 2 * model.config.num_layers
         for call in calls:  # q, k, v come first: [batch x heads, T, 64]
             operands = re.search(r" : \(([^)]*)\) -> ", call).group(1)
             qkv = re.findall(r"tensor<([0-9x]+)x\w+>", operands)[:3]
@@ -185,7 +195,8 @@ class TestTheStepLowersForTheChip:
         calls = [line for line in text.splitlines()
                  if "@tpu_custom_call" in line]
         flash = [c for c in calls if 'kernel_name = "flash_' in c]
-        assert len(flash) == 3 * model.config.num_layers
+        assert len(flash) == 3  # each once, in a function the layers call
+        assert len(_flash_sites(text)) == 2 * model.config.num_layers
         for call in flash:  # q, k, v: [batch x query heads, T, 128]
             operands = re.search(r" : \(([^)]*)\) -> ", call).group(1)
             qkv = re.findall(r"tensor<([0-9x]+)x\w+>", operands)[:3]
@@ -290,8 +301,8 @@ class TestFlashUnderAMesh:
         batch = {"features": {"token_ids": np.zeros((4, 64), np.int32)}}
         text = trainer.train_step.trace(template, batch).lower(
             lowering_platforms=("tpu",)).as_text()
-        assert text.count("tpu_custom_call") == \
-            3 * model.config.num_layers  # fwd + dkv + dq per layer
+        assert text.count("@tpu_custom_call") == 3  # fwd, dkv, dq: once
+        assert len(_flash_sites(text)) == 2 * model.config.num_layers
 
     def test_single_device_mesh_is_not_wrapped(self):
         one = build_mesh(MeshSpec(data=-1), devices_=jax.devices()[:1])

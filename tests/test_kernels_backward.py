@@ -113,33 +113,68 @@ def test_flash_single_kv_iteration_block(causal):
     _assert_grads_close(_grads(fn, q, k, v), _grads(ref, q, k, v))
 
 
-# name: (t, s, d, causal, block, (dead, live) tiles of the plan, first row
-# that sees a key). Blocks of 32 x 32 make a 4 x 4 grid of T = S = 128: 6
-# tiles above the diagonal, 4 on it, 6 under it.
+# name: (t, s, d, causal, block, (dead, live, split) tiles of the plan,
+# first row that sees a key, key mask or not). Blocks of 32 x 32 make a
+# 4 x 4 grid of T = S = 128: 6 tiles above the diagonal, 4 on it, 6 under
+# it; a block that small is computed whole. A block of two sub-blocks or
+# more (``_B``) is split where the diagonal runs corner to corner.
+_B = 2 * fa._SUB_BLOCK
 _TILE_CASES = {
-    "d64_dead_interior_diagonal": (128, 128, 64, True, 32, (6, 10), 0),
-    "d40_no_multiple_of_64": (128, 128, 40, True, 32, (6, 10), 0),
-    "ragged_queries_and_keys_causal": (100, 100, 16, True, 32, (6, 10), 0),
-    "ragged_not_causal": (100, 72, 16, False, 32, (0, 12), 0),
-    "offset_more_keys_than_queries": (64, 128, 16, True, 32, (1, 7), 0),
+    "d64_dead_interior_diagonal": (128, 128, 64, True, 32, (6, 10, 0), 0),
+    "d40_no_multiple_of_64": (128, 128, 40, True, 32, (6, 10, 0), 0),
+    "ragged_queries_and_keys_causal": (100, 100, 16, True, 32, (6, 10, 0),
+                                       0),
+    "ragged_not_causal": (100, 72, 16, False, 32, (0, 12, 0), 0),
+    "offset_more_keys_than_queries": (64, 128, 16, True, 32, (1, 7, 0), 0),
     "offset_fewer_keys_rows_without_a_key": (128, 64, 16, True, 32,
-                                             (5, 3), 64),
-    "whole_blocks_not_causal": (64, 96, 16, False, 32, (0, 6), 0),
+                                             (5, 3, 0), 64),
+    "whole_blocks_not_causal": (64, 96, 16, False, 32, (0, 6, 0), 0),
+    # the forward's lane-broadcast running state against more keys, and a
+    # wider head, than its 128 lanes
+    "d256_wider_than_the_state": (64, 64, 256, True, 32, (1, 3, 0), 0),
+    "keys_192_no_multiple_of_the_state": (192, 192, 16, True, 192,
+                                          (0, 1, 0), 0),
+    "split_one_tile": (_B, _B, 16, True, _B, (0, 1, 1), 0),
+    "split_one_tile_four_sub_blocks": (2 * _B, 2 * _B, 8, True, 2 * _B,
+                                       (0, 1, 1), 0),
+    "split_3_by_3_grid": (3 * _B, 3 * _B, 8, True, _B, (3, 6, 3), 0),
+    "split_3_by_3_grid_key_mask": (3 * _B, 3 * _B, 8, True, _B, (3, 6, 3),
+                                   0, True),
+    "split_one_tile_key_mask": (_B, _B, 16, True, _B, (0, 1, 1), 0, True),
+    "split_padded_queries_and_keys": (2 * _B - 24, 2 * _B - 24, 8, True, _B,
+                                      (1, 3, 2), 0),
+    "split_padded_key_mask": (2 * _B - 24, 2 * _B - 24, 8, True, _B,
+                              (1, 3, 2), 0, True),
+    "split_offset_of_one_tile": (_B, 2 * _B, 8, True, _B, (0, 2, 1), 0),
+    "split_fewer_keys_by_one_tile": (2 * _B, _B, 8, True, _B, (1, 1, 1),
+                                     _B),
+    "cross_shaped_offset_meets_no_edge": (_B, _B + _B // 2, 8, True, _B,
+                                          (0, 2, 0), 0),
+    "unequal_blocks_computed_whole": (_B, _B, 8, True, (_B // 2, _B),
+                                      (0, 2, 0), 0),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_TILE_CASES))
 def test_flash_every_tile_kind_matches_reference(case):
     """Forward and all three gradients where one call holds dead tiles,
-    tiles under the diagonal and tiles the diagonal crosses, at head widths
-    that are never padded."""
-    t, s, d, causal, block, (dead, live), first_row = _TILE_CASES[case]
-    plan = fa.TilePlan(t, s, block, block, causal)
-    assert plan.counts() == {"dead": dead, "live": live}
+    tiles under the diagonal and tiles the diagonal crosses (computed whole
+    or split into row sub-blocks), at head widths that are never padded."""
+    (t, s, d, causal, block, (dead, live, split), first_row,
+     *masked) = _TILE_CASES[case]
+    block_q, block_k = block if isinstance(block, tuple) else (block, block)
+    counts = fa.TilePlan(t, s, block_q, block_k, causal).counts()
+    assert (counts["dead"], counts["live"], counts["diagonal"]) == (
+        dead, live, split)
     q, k, v = _qkv(5, b=1, t=t, s=s, d=d)
-    fn = functools.partial(flash_attention, causal=causal,
-                           block_q=block, block_k=block)
-    ref = functools.partial(reference_attention, causal=causal)
+    key_mask = None
+    if masked:  # a padded tail, and a hole inside the first sub-block
+        # (keys 0-2 stay live, so every row still sees a key)
+        key_mask = jnp.ones((1, s)).at[:, s - 37:].set(0.0).at[:, 3:9].set(0.0)
+    fn = functools.partial(flash_attention, causal=causal, key_mask=key_mask,
+                           block_q=block_q, block_k=block_k)
+    ref = functools.partial(reference_attention, causal=causal,
+                            key_mask=key_mask)
     got = fn(q, k, v)
     np.testing.assert_allclose(np.asarray(got)[:, :, first_row:],
                                np.asarray(ref(q, k, v))[:, :, first_row:],
