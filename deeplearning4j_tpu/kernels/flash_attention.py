@@ -22,8 +22,10 @@ tile that its rows can see (slices known at trace time: straight-line code
 to Mosaic, no new grid step, copy or scratch). The pairs skipped are those
 the causal mask sets to -1e30, whose p and ds are exactly 0, so only the
 order of the additions changes. A per-example key padding mask ([B,S] 1/0
-— the BERT attention-mask case) runs inside the kernel; only arbitrary
-additive ``bias`` falls back to the XLA reference. The head dimension is never padded (a block as wide
+— the BERT attention-mask case) runs inside the kernel, and so does a
+per-pair mask ([B,T,S] int8, one for all heads of a sequence: the keys a
+learned indexer selected for each query), fetched tile by tile beside k and
+v; only arbitrary additive ``bias`` falls back to the XLA reference. The head dimension is never padded (a block as wide
 as the array is legal at any width; 8 to 256 were compiled for the v5e),
 and the softmax scale goes on the ``[block_q, D]`` operand, not on the
 scores.
@@ -124,22 +126,32 @@ def _matmul_dtype(dtype):
     return jnp.bfloat16 if dtype == jnp.float32 else dtype
 
 
-def _compiler_params(*semantics):
+# A call with a pair mask holds two [block_q, block_k] int8 tiles more (one in
+# flight), which at 1024 x 1024 and head width 128 puts ``flash_bwd_dkv`` 20 KB
+# over the compiler's default 16 MiB of scoped VMEM; the v5e has 128 MiB.
+_PAIR_MASK_VMEM_BYTES = 32 * 1024 * 1024
+
+
+def _compiler_params(*semantics, pair_mask=False):
     """Mosaic grid-dimension semantics (parallel dims enable multi-core
     partitioning on megacore chips and better pipelining); only meaningful
     when compiled for TPU — interpret mode ignores them."""
     if not _on_tpu():
         return None
+    if pair_mask:
+        return pltpu.CompilerParams(dimension_semantics=tuple(semantics),
+                                    vmem_limit_bytes=_PAIR_MASK_VMEM_BYTES)
     return pltpu.CompilerParams(dimension_semantics=tuple(semantics))
 
 
 def reference_attention(q, k, v, *, causal=False, bias=None, key_mask=None,
-                        scale=None):
+                        pair_mask=None, scale=None):
     """XLA O(T²) attention; q [B,H,T,D], k/v [B,H,S,D]. fp32 softmax.
 
-    ``key_mask`` [B,S] 1/0 is folded into an additive bias. Fully-masked
-    rows produce uniform attention (softmax of constant) — callers never
-    read those outputs.
+    ``key_mask`` [B,S] 1/0 is folded into an additive bias, ``pair_mask``
+    [B,T,S] (nonzero: attend) into the scores. Fully-masked rows produce
+    uniform attention (softmax of constant) — callers never read those
+    outputs.
     """
     d = q.shape[-1]
     scale = (d ** -0.5) if scale is None else scale
@@ -148,6 +160,8 @@ def reference_attention(q, k, v, *, causal=False, bias=None, key_mask=None,
         s = s + bias
     if key_mask is not None:
         s = s + jnp.where(key_mask[:, None, None, :] > 0, 0.0, _NEG_INF)
+    if pair_mask is not None:
+        s = jnp.where(pair_mask[:, None] != 0, s, _NEG_INF)
     if causal:
         t_len, s_len = s.shape[-2], s.shape[-1]
         idx_t = jnp.arange(t_len)[:, None]
@@ -189,6 +203,7 @@ class TilePlan:
     block_q: int
     block_k: int
     causal: bool
+    pair_mask: bool = False  # the call carries a [B, T, S] mask of pairs
 
     @property
     def n_q(self) -> int:
@@ -216,7 +231,9 @@ class TilePlan:
     def rows_can_be_empty(self) -> bool:
         """Whether a real query row can have no live key at all: under
         causal masking alone only when there are fewer keys than queries
-        (otherwise every row's first live block holds key 0)."""
+        (otherwise every row's first live block holds key 0). The running
+        state of a row that a key mask or a pair mask has masked whole so
+        far needs the same care, tile by tile (``_flash_kernel``)."""
         return self.causal and self.seq_k < self.seq_q
 
     @property
@@ -347,11 +364,11 @@ def _on_live_tile(plan, qi, ki, body):
                 body(*part)
 
 
-def _tile_mask(plan, qi, ki, km_ref, first_row, rows, keys, causal):
+def _tile_mask(plan, qi, ki, km_ref, pm_ref, first_row, rows, keys, causal):
     """The mask of a rectangle of a live tile: key padding, the per-example
-    key mask, query padding (the backward's padded rows carry no residuals)
-    and the causal triangle; each term only where the shapes can make it
-    false, and None where none can."""
+    key mask, the per-pair mask, query padding (the backward's padded rows
+    carry no residuals) and the causal triangle; each term only where the
+    shapes can make it false, and None where none can."""
     shape = (rows, keys)
     key_idx = ki * plan.block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     terms = []
@@ -359,6 +376,9 @@ def _tile_mask(plan, qi, ki, km_ref, first_row, rows, keys, causal):
         terms.append(key_idx < plan.seq_k)
     if km_ref is not None:  # [1, keys] broadcasts over rows
         terms.append(km_ref[0, :, :keys] > 0)
+    if pm_ref is not None:  # [block_q, block_k] of int8, the tile's own
+        terms.append(pm_ref[0, first_row:first_row + rows, :keys]
+                     .astype(jnp.int32) != 0)
     if causal or plan.pads_q:
         query_idx = (qi * plan.block_q + first_row
                      + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
@@ -385,8 +405,8 @@ def _lanes(x, width):
     return jnp.tile(x, (1, -(-width // x.shape[1])))[:, :width]
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, lse_ref, m_scr, l_scr,
-                  acc_scr, *, scale, plan):
+def _flash_kernel(q_ref, k_ref, v_ref, km_ref, pm_ref, o_ref, lse_ref, m_scr,
+                  l_scr, acc_scr, *, scale, plan):
     qi, ki = _grid_ids(plan, 1, 2)
 
     @_when(ki == 0)
@@ -403,8 +423,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, lse_ref, m_scr, l_scr,
             k_ref[0, :n_keys, :].astype(mm),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [rows, keys]
-        mask = _tile_mask(plan, qi, ki, km_ref, first_row, n_rows, n_keys,
-                          causal)
+        mask = _tile_mask(plan, qi, ki, km_ref, pm_ref, first_row, n_rows,
+                          n_keys, causal)
         if mask is not None:
             s = jnp.where(mask, s, _NEG_INF)
         # The running maximum and sum stay the lane-broadcast [rows, 128]
@@ -413,7 +433,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, lse_ref, m_scr, l_scr,
         m_prev = m_scr[rows, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - _lanes(m_new, n_keys))
-        if mask is not None and (km_ref is not None
+        if mask is not None and (km_ref is not None or pm_ref is not None
                                  or plan.rows_can_be_empty):
             # A row masked whole so far keeps m_new at _NEG_INF, and
             # exp(s - m_new) would be 1 there, not 0.
@@ -477,11 +497,18 @@ def _key_mask_rows(key_mask, heads, block_k):
     return jnp.repeat(km, heads, axis=0)[:, None, :]
 
 
-def _flash_fwd(q, k, v, key_mask, *, causal, scale, block_q, block_k,
-               save_lse=False):
+def _pair_mask_tiles(pair_mask, block_q, block_k):
+    """``[B, T, S]`` of int8 padded to whole tiles (the padding selects
+    nothing); the kernels' index maps drop the head: ``bh // heads``."""
+    return _pad_to(_pad_to(pair_mask.astype(jnp.int8), 1, block_q), 2,
+                   block_k)
+
+
+def _flash_fwd(q, k, v, key_mask, pair_mask, *, causal, scale, block_q,
+               block_k, save_lse=False):
     b, h, t, d = q.shape
     s_len = k.shape[2]
-    plan = TilePlan(t, s_len, block_q, block_k, causal)
+    plan = TilePlan(t, s_len, block_q, block_k, causal, pair_mask is not None)
     qp, kp, vp = _rows(q, block_q), _rows(k, block_k), _rows(v, block_k)
     tq = qp.shape[1]
 
@@ -499,6 +526,11 @@ def _flash_fwd(q, k, v, key_mask, *, causal, scale, block_q, block_k,
         operands.append(_key_mask_rows(key_mask, h, block_k))
         in_specs.append(pl.BlockSpec(
             (1, 1, block_k), lambda bh, qi, ki: (bh, 0, plan.fetch_k(qi, ki))))
+    if pair_mask is not None:
+        operands.append(_pair_mask_tiles(pair_mask, block_q, block_k))
+        in_specs.append(pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda bh, qi, ki: (bh // h, qi, plan.fetch_k(qi, ki))))
     out_specs = [pl.BlockSpec((1, block_q, d), q_index)]
     out_shape = [jax.ShapeDtypeStruct((b * h, tq, d), q.dtype)]
     if save_lse:
@@ -508,10 +540,11 @@ def _flash_fwd(q, k, v, key_mask, *, causal, scale, block_q, block_k,
     def kernel(*refs):
         q_ref, k_ref, v_ref, *rest = refs
         km_ref = rest.pop(0) if key_mask is not None else None
+        pm_ref = rest.pop(0) if pair_mask is not None else None
         o_ref = rest.pop(0)
         lse_ref = rest.pop(0) if save_lse else None
-        _flash_kernel(q_ref, k_ref, v_ref, km_ref, o_ref, lse_ref, *rest,
-                      scale=scale, plan=plan)
+        _flash_kernel(q_ref, k_ref, v_ref, km_ref, pm_ref, o_ref, lse_ref,
+                      *rest, scale=scale, plan=plan)
 
     res = pl.pallas_call(
         kernel,
@@ -524,7 +557,8 @@ def _flash_fwd(q, k, v, key_mask, *, causal, scale, block_q, block_k,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
+        compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
+                                         pair_mask=plan.pair_mask),
         interpret=_interpret(),
         name="flash_fwd",
     )(*operands)
@@ -532,8 +566,9 @@ def _flash_fwd(q, k, v, key_mask, *, causal, scale, block_q, block_k,
     return out, (res[1] if save_lse else None)
 
 
-def _bwd_recompute(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref, delta_ref,
-                   qi, ki, first_row, n_rows, n_keys, causal, *, scale, plan):
+def _bwd_recompute(q_ref, k_ref, v_ref, km_ref, pm_ref, g_ref, lse_ref,
+                   delta_ref, qi, ki, first_row, n_rows, n_keys, causal, *,
+                   scale, plan):
     """Recompute p and ds for one rectangle of a (q-block, kv-block) pair —
     the math both backward kernels share. Returns (q, k, g, p, ds) in the
     MXU compute dtype (see _matmul_dtype); ds lacks the softmax scale, which
@@ -552,7 +587,8 @@ def _bwd_recompute(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref, delta_ref,
     s = jax.lax.dot_general(
         _scaled(q, scale, mm), k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    mask = _tile_mask(plan, qi, ki, km_ref, first_row, n_rows, n_keys, causal)
+    mask = _tile_mask(plan, qi, ki, km_ref, pm_ref, first_row, n_rows, n_keys,
+                      causal)
     if mask is not None:
         s = jnp.where(mask, s, _NEG_INF)
     p = jnp.exp(s - lse)  # [rows, keys]; exactly 0 where masked
@@ -565,7 +601,7 @@ def _bwd_recompute(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref, delta_ref,
     return q.astype(mm), k, g, p.astype(mm), ds.astype(mm)
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref,
+def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, km_ref, pm_ref, g_ref, lse_ref,
                           delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                           scale, plan):
     qi, ki = _grid_ids(plan, 2, 1)
@@ -577,7 +613,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref,
 
     def _compute(first_row, n_rows, n_keys, causal):
         q, _, g, p, ds = _bwd_recompute(
-            q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref, delta_ref,
+            q_ref, k_ref, v_ref, km_ref, pm_ref, g_ref, lse_ref, delta_ref,
             qi, ki, first_row, n_rows, n_keys, causal, scale=scale, plan=plan)
         dv_scr[:n_keys, :] += jax.lax.dot_general(
             p, g, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -595,7 +631,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref,
+def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, km_ref, pm_ref, g_ref, lse_ref,
                          delta_ref, dq_ref, dq_scr, *, scale, plan):
     qi, ki = _grid_ids(plan, 1, 2)
 
@@ -605,7 +641,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref,
 
     def _compute(first_row, n_rows, n_keys, causal):
         _, k, _, _, ds = _bwd_recompute(
-            q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref, delta_ref,
+            q_ref, k_ref, v_ref, km_ref, pm_ref, g_ref, lse_ref, delta_ref,
             qi, ki, first_row, n_rows, n_keys, causal, scale=scale, plan=plan)
         rows = slice(first_row, first_row + n_rows)
         dq_scr[rows, :] += jax.lax.dot_general(
@@ -619,7 +655,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, km_ref, g_ref, lse_ref,
         dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
-def _flash_bwd_call(kernel_fn, q, k, v, key_mask, g, lse, delta, *,
+def _flash_bwd_call(kernel_fn, q, k, v, key_mask, pair_mask, g, lse, delta, *,
                     causal, scale, block_q, block_k, key_major):
     """One backward kernel at its own geometry, as (kernel, the keyword
     arguments of its ``pallas_call``, operands). ``key_major``: the grid is
@@ -627,7 +663,7 @@ def _flash_bwd_call(kernel_fn, q, k, v, key_mask, g, lse, delta, *,
     (bh, qi, ki) (``flash_bwd_dq``). The outputs come back padded."""
     b, h, t, d = q.shape
     s_len = k.shape[2]
-    plan = TilePlan(t, s_len, block_q, block_k, causal)
+    plan = TilePlan(t, s_len, block_q, block_k, causal, pair_mask is not None)
 
     def ids(bh, i, j):
         return (bh, j, i) if key_major else (bh, i, j)  # -> (bh, qi, ki)
@@ -644,6 +680,9 @@ def _flash_bwd_call(kernel_fn, q, k, v, key_mask, g, lse, delta, *,
         bh, block, _ = kv_index(*grid)
         return (bh, 0, block)
 
+    def pm_index(*grid):
+        return (grid[0] // h, q_index(*grid)[1], kv_index(*grid)[1])
+
     def rows_q(x):  # [BH, T(+), 128] residuals at this kernel's padding
         return _pad_to(x[:, :t], 1, block_q)
 
@@ -655,6 +694,9 @@ def _flash_bwd_call(kernel_fn, q, k, v, key_mask, g, lse, delta, *,
     if key_mask is not None:
         operands.append(_key_mask_rows(key_mask, h, block_k))
         in_specs.append(pl.BlockSpec((1, 1, block_k), km_index))
+    if pair_mask is not None:
+        operands.append(_pair_mask_tiles(pair_mask, block_q, block_k))
+        in_specs.append(pl.BlockSpec((1, block_q, block_k), pm_index))
     operands += [_rows(g, block_q), rows_q(lse), rows_q(delta)]
     in_specs += [q_spec, row_spec, row_spec]
     tq, tk = operands[0].shape[1], operands[1].shape[1]
@@ -674,7 +716,9 @@ def _flash_bwd_call(kernel_fn, q, k, v, key_mask, g, lse, delta, *,
     def kernel(*refs):
         q_ref, k_ref, v_ref, *rest = refs
         km_ref = rest.pop(0) if key_mask is not None else None
-        kernel_fn(q_ref, k_ref, v_ref, km_ref, *rest, scale=scale, plan=plan)
+        pm_ref = rest.pop(0) if pair_mask is not None else None
+        kernel_fn(q_ref, k_ref, v_ref, km_ref, pm_ref, *rest, scale=scale,
+                  plan=plan)
 
     return kernel, dict(
         grid=grid,
@@ -682,19 +726,21 @@ def _flash_bwd_call(kernel_fn, q, k, v, key_mask, g, lse, delta, *,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
+        compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
+                                         pair_mask=plan.pair_mask),
         interpret=_interpret(),
     ), operands
 
 
-def _flash_bwd_impl(q, k, v, key_mask, out, lse, g, *, causal, scale, blocks):
+def _flash_bwd_impl(q, k, v, key_mask, pair_mask, out, lse, g, *, causal,
+                    scale, blocks):
     """Blockwise backward: two kernels, each at its own geometry."""
     b, h, t, d = q.shape
     s_len = k.shape[2]
     delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta.reshape(b * h, t)[:, :, None],
                              (b * h, t, 128))
-    args = (q, k, v, key_mask, g, lse, delta)
+    args = (q, k, v, key_mask, pair_mask, g, lse, delta)
     kernel, call, operands = _flash_bwd_call(
         _flash_bwd_dkv_kernel, *args, causal=causal, scale=scale,
         block_q=blocks.dkv[0], block_k=blocks.dkv[1], key_major=True)
@@ -717,28 +763,31 @@ def _flash_bwd_impl(q, k, v, key_mask, out, lse, g, *, causal, scale, blocks):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q, k, v, key_mask, causal, scale, blocks):
+def _flash(q, k, v, key_mask, causal, scale, blocks, pair_mask=None):
     """``blocks``: a ``FlashBlocks`` already clamped to the shapes."""
-    out, _ = _flash_fwd(q, k, v, key_mask, causal=causal, scale=scale,
-                        block_q=blocks.fwd[0], block_k=blocks.fwd[1])
+    out, _ = _flash_fwd(q, k, v, key_mask, pair_mask, causal=causal,
+                        scale=scale, block_q=blocks.fwd[0],
+                        block_k=blocks.fwd[1])
     return out
 
 
-def _flash_vjp_fwd(q, k, v, key_mask, causal, scale, blocks):
-    out, lse = _flash_fwd(q, k, v, key_mask, causal=causal, scale=scale,
-                          block_q=blocks.fwd[0], block_k=blocks.fwd[1],
-                          save_lse=True)
-    return out, (q, k, v, key_mask, out, lse)
+def _flash_vjp_fwd(q, k, v, key_mask, causal, scale, blocks, pair_mask):
+    out, lse = _flash_fwd(q, k, v, key_mask, pair_mask, causal=causal,
+                          scale=scale, block_q=blocks.fwd[0],
+                          block_k=blocks.fwd[1], save_lse=True)
+    return out, (q, k, v, key_mask, pair_mask, out, lse)
 
 
 def _flash_vjp_bwd(causal, scale, blocks, res, g):
-    q, k, v, key_mask, out, lse = res
+    q, k, v, key_mask, pair_mask, out, lse = res
     dq, dk, dv = _flash_bwd_impl(
-        q, k, v, key_mask, out, lse, g,
+        q, k, v, key_mask, pair_mask, out, lse, g,
         causal=causal, scale=scale, blocks=blocks,
     )
     dkm = jnp.zeros_like(key_mask) if key_mask is not None else None
-    return dq, dk, dv, dkm
+    dpm = (np.zeros(pair_mask.shape, jax.dtypes.float0)  # integers: no
+           if pair_mask is not None else None)           # cotangent
+    return dq, dk, dv, dkm, dpm
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -752,7 +801,8 @@ def _how_traced():
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
-def _flash_traced_once(q, k, v, key_mask, causal, scale, blocks, how):
+def _flash_traced_once(q, k, v, key_mask, causal, scale, blocks, how,
+                       pair_mask=None):
     """``_flash`` behind a ``jax.jit`` of its own: the layers of a model
     trace and lower each kernel once, not once a layer. A diagonal tile's
     sub-blocks are copies of the body in the kernel's code, and traced a
@@ -760,10 +810,10 @@ def _flash_traced_once(q, k, v, key_mask, causal, scale, blocks, how):
     (PERF.md section 6, PR 32). ``how`` (:func:`_how_traced`) only keys the
     cache of traces."""
     del how
-    return _flash(q, k, v, key_mask, causal, scale, blocks)
+    return _flash(q, k, v, key_mask, causal, scale, blocks, pair_mask)
 
 
-def _flash_on_mesh(mesh, q, k, v, key_mask, causal, scale, blocks):
+def _flash_on_mesh(mesh, q, k, v, key_mask, pair_mask, causal, scale, blocks):
     """The kernel under a multi-device mesh: inside ``shard_map``, batch
     split over the data-like axes and heads over the model axis (attention
     is independent across both, so no collective is needed); a dimension
@@ -782,25 +832,32 @@ def _flash_on_mesh(mesh, q, k, v, key_mask, causal, scale, blocks):
     if key_mask is not None:
         args.append(key_mask)
         specs.append(P(batch_axes or None, None))
+    if pair_mask is not None:
+        args.append(pair_mask)
+        specs.append(P(batch_axes or None, None, None))
 
     how = _how_traced()
 
-    def local(q, k, v, *km):
-        return _flash_traced_once(q, k, v, km[0] if km else None, causal,
-                                  scale, blocks, how)
+    def local(q, k, v, *masks):
+        masks = list(masks)
+        km = masks.pop(0) if key_mask is not None else None
+        pm = masks.pop(0) if pair_mask is not None else None
+        return _flash_traced_once(q, k, v, km, causal, scale, blocks, how, pm)
 
     return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
                          out_specs=qkv_spec, check_vma=False)(*args)
 
 
 def flash_attention(q, k, v, *, causal: bool = False, scale=None, bias=None,
-                    key_mask=None, block_q: int = None, block_k: int = None,
-                    backend: str = None):
+                    key_mask=None, pair_mask=None, block_q: int = None,
+                    block_k: int = None, backend: str = None):
     """Blockwise attention; q [B,H,T,D], k/v [B,H,S,D] → [B,H,T,D].
 
     ``key_mask`` [B,S] 1/0 (padding mask) runs inside the kernel — the
-    BERT path keeps the flash fast path. Arbitrary additive ``bias``
-    forces the XLA fallback.
+    BERT path keeps the flash fast path. ``pair_mask`` [B,T,S] of int8
+    (nonzero: the query attends to the key; one mask for all heads of a
+    sequence) runs inside it too; no gradient reaches it. Arbitrary
+    additive ``bias`` forces the XLA fallback.
 
     ``backend``: None (auto), 'pallas', or 'xla'. Auto dispatch picks XLA's
     fused attention below ``_dispatch.flash_min_seq()`` keys, off-TPU, for
@@ -828,21 +885,36 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None, bias=None,
             _force_pallas() or k.shape[2] >= _flash_min_seq()) else "xla"
     if backend == "xla":
         return reference_attention(q, k, v, causal=causal, bias=bias,
-                                   key_mask=key_mask, scale=scale)
+                                   key_mask=key_mask, pair_mask=pair_mask,
+                                   scale=scale)
     t, s_len = q.shape[2], k.shape[2]
-    # an explicit block_q / block_k gives all three kernels that geometry
-    blocks = FlashBlocks(*[
-        _clamp_blocks(block_q or bq, block_k or bk, t, s_len)
-        for bq, bk in _flash_block_sizes(t, s_len, d, causal)])
-    _record_plan(t, s_len, d, causal, key_mask is not None, blocks)
+    blocks = _call_blocks(t, s_len, d, causal, block_q, block_k)
+    _record_plan(t, s_len, d, causal, key_mask is not None,
+                 pair_mask is not None, blocks)
     mesh = _active_kernel_mesh()
     if mesh is not None:
-        return _flash_on_mesh(mesh, q, k, v, key_mask, causal, scale, blocks)
+        return _flash_on_mesh(mesh, q, k, v, key_mask, pair_mask, causal,
+                              scale, blocks)
     return _flash_traced_once(q, k, v, key_mask, causal, scale, blocks,
-                              _how_traced())
+                              _how_traced(), pair_mask)
 
 
-def _record_plan(seq_q, seq_k, head_dim, causal, has_mask, blocks):
+def _call_blocks(seq_q, seq_k, head_dim, causal, block_q=None, block_k=None):
+    """The geometry of a call's three kernels, clamped to its shapes; an
+    explicit ``block_q`` / ``block_k`` gives all three that geometry."""
+    return FlashBlocks(*[
+        _clamp_blocks(block_q or bq, block_k or bk, seq_q, seq_k)
+        for bq, bk in _flash_block_sizes(seq_q, seq_k, head_dim, causal)])
+
+
+def forward_plan(seq_q, seq_k, head_dim, *, causal, pair_mask=False):
+    """The :class:`TilePlan` of ``flash_fwd`` for a call of these shapes
+    at the default geometry: what a caller that counts tiles asks."""
+    blocks = _call_blocks(seq_q, seq_k, head_dim, causal)
+    return TilePlan(seq_q, seq_k, *blocks.fwd, causal, pair_mask)
+
+
+def _record_plan(seq_q, seq_k, head_dim, causal, has_mask, has_pairs, blocks):
     """One ``kernel.flash_plan`` flight event per call, at trace time (a
     jitted step traces its calls once, so this costs a run nothing): the
     geometry of each kernel, how many of its tiles are dead (skipped,
@@ -853,7 +925,7 @@ def _record_plan(seq_q, seq_k, head_dim, causal, has_mask, blocks):
 
     record_event(
         "kernel.flash_plan", seq_q=seq_q, seq_k=seq_k, head_dim=head_dim,
-        causal=causal, key_mask=has_mask,
+        causal=causal, key_mask=has_mask, pair_mask=has_pairs,
         **{name: {"block_q": bq, "block_k": bk,
                   **TilePlan(seq_q, seq_k, bq, bk, causal).counts()}
            for name, (bq, bk) in blocks._asdict().items()})

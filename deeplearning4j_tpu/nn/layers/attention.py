@@ -16,19 +16,25 @@ the transpose.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.kernels.flash_attention import flash_attention
+from deeplearning4j_tpu.kernels.flash_attention import (
+    flash_attention,
+    forward_plan,
+)
 from deeplearning4j_tpu.nn.activations import get_activation
 from deeplearning4j_tpu.nn.config import LayerConfig, register_config
 from deeplearning4j_tpu.nn.initializers import get_initializer
 from deeplearning4j_tpu.observability.vocab import (
     SCOPE_ATTN,
     SCOPE_CCA_MIX,
+    SCOPE_DSA_INDEX,
+    SCOPE_DSA_SELECT,
     SCOPE_MLP,
 )
 from deeplearning4j_tpu.ops import nn as opsnn
@@ -155,6 +161,162 @@ def cca_attention(params, h, *, num_heads: int, num_kv_heads: int,
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     y = flash_attention(q, k, v, causal=True)
     return opsnn.linear(_merge_heads(y), params["Wo"])
+
+
+# Query rows of one piece of the indexer's score: a piece's per-head scores
+# [N, rows, heads, keys] in float32 are the largest array the layer makes
+# (537 MB at 2 x 512 x 16 x 8192), and pieces run one after another.
+_INDEX_ROWS = 512
+
+
+def _kth_largest(scores, k: int):
+    """The ``k``-th largest of each row of ``scores`` [..., S] (float32, no
+    NaN, at least ``k`` entries a row that are not ``-inf``), as the
+    order-preserving unsigned key of its bit pattern, beside every entry's
+    key: ``keys >= kth`` are the row's ``k`` largest and whatever ties the
+    last of them. A search for the threshold by counting, a bit a pass
+    from the top: no index is needed, so nothing is sorted."""
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores), jnp.uint32)  # -0.0 as +0.0
+    top = jnp.uint32(1 << 31)
+    keys = jnp.where(bits >= top, ~bits, bits | top)
+
+    def add_bit(i, kth):
+        trial = kth | (top >> i.astype(jnp.uint32))
+        enough = jnp.sum(keys >= trial, axis=-1, keepdims=True,
+                         dtype=jnp.int32) >= k
+        return jnp.where(enough, trial, kth)
+
+    kth = jax.lax.fori_loop(
+        0, 32, add_bit, jnp.zeros(keys.shape[:-1] + (1,), jnp.uint32))
+    return keys, kth
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def _selected_pairs(q_index, k_index, w_index, top_k: int):
+    """The pair mask [N, T, T] of int8: for query t the keys s <= t whose
+    index score I[t, s] = sum_j w[t, j] relu(q_index[t, j] . k_index[s]) is
+    among the ``top_k`` largest of its past (all of its past where that
+    holds no more than ``top_k`` keys; ties with the last one kept).
+    q_index [N,T,J,D], k_index [N,T,D], w_index [N,T,J], float32, every
+    product at ``highest``. Rows are scored in pieces of ``_INDEX_ROWS``,
+    each against the keys up to its last row only; the first ``top_k``
+    rows select their whole past, so nothing is scored for them. Jitted on
+    its own: the layers of a model trace it once."""
+    n, t = k_index.shape[:2]
+    first = min(top_k, t)
+    pieces = [jnp.broadcast_to(
+        jnp.tril(jnp.ones((first, t), jnp.int8))[None], (n, first, t))]
+    for a in range(first, t, _INDEX_ROWS):
+        b = min(a + _INDEX_ROWS, t)
+        with jax.named_scope(SCOPE_DSA_INDEX):
+            per_head = jnp.einsum(
+                "ntjd,nsd->ntjs", q_index[:, a:b], k_index[:, :b],
+                precision=jax.lax.Precision.HIGHEST)
+            score = jnp.sum(jax.nn.relu(per_head) * w_index[:, a:b, :, None],
+                            axis=2)
+        with jax.named_scope(SCOPE_DSA_SELECT):
+            past = (jnp.arange(b)[None, :] <= jnp.arange(a, b)[:, None])[None]
+            keys, kth = _kth_largest(jnp.where(past, score, -jnp.inf), top_k)
+            chosen = (past & (keys >= kth)).astype(jnp.int8)
+            pieces.append(jnp.pad(chosen, ((0, 0), (0, 0), (0, t - b))))
+    with jax.named_scope(SCOPE_DSA_SELECT):
+        return jnp.concatenate(pieces, axis=1)
+
+
+def _empty_tile_share(pair_mask, head_dim: int):
+    """The share of ``flash_fwd``'s live tiles, at the geometry it would
+    take for this call, in which ``pair_mask`` [N,T,T] selects nothing."""
+    n, t, _ = pair_mask.shape
+    plan = forward_plan(t, t, head_dim, causal=True, pair_mask=True)
+    padded = jnp.pad(pair_mask, ((0, 0), (0, plan.n_q * plan.block_q - t),
+                                 (0, plan.n_k * plan.block_k - t)))
+    some = jnp.any(padded.reshape(n, plan.n_q, plan.block_q, plan.n_k,
+                                  plan.block_k) != 0, axis=(2, 4))
+    live = plan.live_tiles()
+    empty = jnp.sum(~some & jnp.asarray(live)[None], dtype=jnp.int32)
+    return empty / jnp.float32(n * int(live.sum()))
+
+
+def indexed_attention(params, h, *, num_heads: int, num_kv_heads: int,
+                      index_heads: int, top_k: int, rope_theta: float,
+                      eps: float):
+    """Causal grouped-query attention over the keys a learned indexer
+    selects for each query (DeepSeek-V3.2-Exp's sparse attention, as
+    Keye-VL-2.0's ``sa_config`` sizes it) over ``h`` [N,T,E], already
+    normed. Returns the sub-layer's output and what it counted:
+    ``pairs_selected`` (over the batch) and ``tiles_empty_share``.
+
+    Main path: q, k, v by ``Wq``, ``Wk``, ``Wv`` (no bias), RMSNorm of
+    each head of q and of k with a learned gain (``q_norm``, ``k_norm``),
+    rotary positions on the whole head (dimension i paired with i + half),
+    k and v repeated to the query heads, ``flash_attention`` with the pair
+    mask, ``Wo``.
+
+    The indexer (leaves under ``params["index"]``) reads ``h`` with its
+    gradient stopped, in float32 with every product at ``highest``:
+    ``index_heads`` query heads (``Wq``) against one key head (``Wk``,
+    LayerNorm with ``k_gamma``, ``k_beta``), rotary on both, and a weight a
+    head a query (``Ww``, times heads^-1/2 * width^-1/2); the score of a
+    pair is sum_j w[t, j] relu(q[t, j] . k[s]). Each query keeps the
+    ``top_k`` largest of its past (``_selected_pairs``: exact, ties
+    kept); the set is shared by all heads and is a constant of the
+    backward pass, so the indexer's leaves get a zero gradient. The
+    indexer's work is the ``dsa_index`` sub-scope, the threshold and the
+    mask ``dsa_select``.
+    """
+    n, t, _ = h.shape
+    group = num_heads // num_kv_heads
+    f32, exact = jnp.float32, jax.lax.Precision.HIGHEST
+    index = params["index"]
+    with jax.named_scope(SCOPE_DSA_INDEX):
+        g = jax.lax.stop_gradient(h).astype(f32)
+
+        def product(name):
+            return jnp.matmul(g, index[name].astype(f32), precision=exact)
+
+        q_index = product("Wq").reshape(n, t, index_heads, -1)
+        width = q_index.shape[-1]
+        k_index = opsnn.layer_norm(
+            product("Wk"), index["k_gamma"].astype(f32),
+            index["k_beta"].astype(f32), eps=eps)
+        q_index = _rotary(q_index, rope_theta, 1.0)
+        k_index = _rotary(k_index[:, :, None], rope_theta, 1.0)[:, :, 0]
+        w_index = product("Ww") * (index_heads ** -0.5 * width ** -0.5)
+    _record_selection(t, top_k, index_heads, width)
+    # the set is a constant of the backward pass: no tangent is traced
+    pair_mask = _selected_pairs(*jax.lax.stop_gradient(
+        (q_index, k_index, w_index)), top_k)
+
+    q = opsnn.linear(h, params["Wq"]).reshape(n, t, num_heads, -1)
+    k = opsnn.linear(h, params["Wk"]).reshape(n, t, num_kv_heads, -1)
+    v = opsnn.linear(h, params["Wv"]).reshape(n, t, num_kv_heads, -1)
+    q = opsnn.rms_norm(q, params["q_norm"], eps)
+    k = opsnn.rms_norm(k, params["k_norm"], eps)
+    q = _rotary(q.astype(f32), rope_theta, 1.0).astype(h.dtype)
+    k = _rotary(k.astype(f32), rope_theta, 1.0).astype(h.dtype)
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    y = flash_attention(q, k, v, causal=True, pair_mask=pair_mask)
+    y = opsnn.linear(_merge_heads(y), params["Wo"])
+    with jax.named_scope(SCOPE_DSA_SELECT):
+        counted = {
+            "pairs_selected": jnp.sum(pair_mask, dtype=jnp.int32),
+            "tiles_empty_share": _empty_tile_share(pair_mask, q.shape[-1]),
+        }
+    return y, counted
+
+
+def _record_selection(seq_len, top_k, index_heads, width):
+    """One ``attention.dsa_select`` flight event a layer, at trace time:
+    the selection's method and how its work is cut."""
+    from deeplearning4j_tpu.observability.flightrecorder import record_event
+
+    record_event(
+        "attention.dsa_select", method="threshold_by_bit_search_xla",
+        passes=32, seq_len=seq_len, top_k=top_k, index_heads=index_heads,
+        index_width=width, rows_a_piece=_INDEX_ROWS,
+        pieces=-(-max(seq_len - top_k, 0) // _INDEX_ROWS))
 
 
 @register_config

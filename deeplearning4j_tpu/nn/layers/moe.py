@@ -16,14 +16,16 @@ semantics — the residual path carries them); ``load_balance_loss`` exposes
 the GShard auxiliary loss for callers that want to regularize routing.
 
 ``RoutedExperts``, beside it on the same ``[E, H, I]`` stacks, is what a
-language model of today runs: drop-free top-1 routing with no capacity
-and no dispatch tensor (the tokens are sorted by expert and the experts'
-products run grouped over the sorted rows), told which of the experts it
-holds, so that it is one chip's share of an expert-parallel layer.
+language model of today runs: drop-free top-k routing with no capacity
+and no dispatch tensor (the token-expert pairs are sorted by expert and
+the experts' products run grouped over the sorted rows), told which of the
+experts it holds, so that it is one chip's share of an expert-parallel
+layer.
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -159,16 +161,26 @@ class MoEBlock(LayerConfig):
         return y.reshape(shape), new_state
 
 
-@jax.custom_vjp
-def _take_rows(x, index, inverse):
-    """``x[index]`` for ``index`` a permutation of the rows and ``inverse``
-    its inverse, so that the gradient is a gather too and not a scatter."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x, index, inverse, fan=1):
+    """``x[index]`` with a gradient that is a gather too and not a
+    scatter. ``index`` takes every row of ``x`` ``fan`` times, in an order
+    that is a permutation of the copies (copy ``c`` of row ``r`` counted
+    as ``r * fan + c``) whose inverse is ``inverse``: the copies'
+    gradients, gathered back side by side, are summed."""
     return x[index]
 
 
+def _take_rows_bwd(fan, inverse, g):
+    back = g[inverse]
+    if fan > 1:
+        back = jnp.sum(back.reshape(-1, fan, g.shape[-1]).astype(jnp.float32),
+                       axis=1).astype(g.dtype)
+    return back, None, None
+
+
 _take_rows.defvjp(
-    lambda x, index, inverse: (x[index], inverse),
-    lambda inverse, g: (g[inverse], None, None))
+    lambda x, index, inverse, fan: (x[index], inverse), _take_rows_bwd)
 
 # The grouped product on the chip: megablox ``gmm`` (Pallas; JAX ships it),
 # chosen over ``jax.lax.ragged_dot`` by traces on a v5e (PERF.md section 6,
@@ -223,25 +235,32 @@ def _record_grouped_product(m, k, n, groups):
 @register_config
 @dataclass
 class RoutedExperts(LayerConfig):
-    """Drop-free top-1 routing over ``experts_total`` SiLU-gated experts,
-    of which this layer holds ``experts_held`` (expert parallelism: the
-    other chips hold the rest).
+    """Drop-free top-``top_k`` routing over ``experts_total`` SiLU-gated
+    experts, of which this layer holds ``experts_held`` (expert
+    parallelism: the other chips hold the rest).
 
-    The router is a small MLP over a ``router_hidden``-wide state that
+    The router is, by ``router``, one of two forms, both over ALL the
+    experts whatever is held and both in float32 from their first matrix
+    on. ``"mlp"``: a small MLP over a ``router_hidden``-wide state that
     crosses layers (ZAYA1, arXiv:2511.17127): r = x Wr + gamma r_before,
-    z = Wc gelu(Wb gelu(Wa r)), p = softmax(z) over ALL the experts
-    whatever is held, e* = argmax(p + bias); the bias balances load and no
-    gradient reaches it. It runs in float32 from ``Wr`` on. The tokens are
-    sorted by expert, the experts' three products run grouped over the
-    sorted rows (``[E, H, I]`` stacks, as ``MoEBlock``'s), and the results
-    go back weighted by p[e*]. A token whose expert is held elsewhere gets
-    zeros here; no token is dropped for want of room, because there is no
-    capacity: the sorted rows are all the tokens.
+    z = Wc gelu(Wb gelu(Wa r)), p = softmax(z), the experts chosen by
+    p + bias; the bias balances load and no gradient reaches it.
+    ``"linear"``: z = x Wg, p = softmax(z), no bias and no state. A token's
+    ``top_k`` largest are its experts, each weighted by its p over the sum
+    of the chosen p's, wherever those experts are held (one expert a token
+    keeps its own p).
 
-    ``apply`` takes the router's state of the layer before under
+    The token-expert pairs are sorted by the expert's place among those
+    held, the experts' three products run grouped over the sorted rows
+    (``[E, H, I]`` stacks, as ``MoEBlock``'s), and the weighted results of
+    one token are gathered back and summed. A pair whose expert is held
+    elsewhere adds zeros here; no pair is dropped for want of room,
+    because there is no capacity: the sorted rows are all the pairs.
+
+    ``apply`` takes the MLP router's state of the layer before under
     ``state["router"]`` (absent in the first layer, which has no
     ``gamma``) and returns its own there, beside ``tokens_here``: how many
-    tokens landed on each expert held.
+    pairs landed on each expert held.
     """
 
     experts_total: int = 16
@@ -249,6 +268,8 @@ class RoutedExperts(LayerConfig):
     units: int = 0            # the experts' inner width; 0 -> the input's
     router_hidden: int = 256
     carries_router: bool = True   # False in the first layer: no gamma
+    top_k: int = 1
+    router: str = "mlp"           # or "linear": one matrix, no state
 
     def output_shape(self, input_shape):
         return tuple(input_shape)
@@ -258,66 +279,89 @@ class RoutedExperts(LayerConfig):
         i, r, held = self.units or h, self.router_hidden, len(self.experts_held)
         w_init = get_initializer("xavier")
         ks = jax.random.split(rng, 7)
-        params = {
-            "Wr": w_init(ks[0], (h, r), dtype),
-            "Wa": w_init(ks[1], (r, r), dtype),
-            "Wb": w_init(ks[2], (r, r), dtype),
-            "Wc": w_init(ks[3], (r, self.experts_total), dtype),
-            "bias": jnp.zeros((self.experts_total,), dtype),
-            "gate": w_init(ks[4], (held, h, i), dtype),
-            "up": w_init(ks[5], (held, h, i), dtype),
-            "down": w_init(ks[6], (held, i, h), dtype),
-        }
-        if self.carries_router:
-            params["gamma"] = jnp.zeros((), dtype)
+        if self.router == "linear":
+            params = {"Wg": w_init(ks[0], (h, self.experts_total), dtype)}
+        else:
+            params = {
+                "Wr": w_init(ks[0], (h, r), dtype),
+                "Wa": w_init(ks[1], (r, r), dtype),
+                "Wb": w_init(ks[2], (r, r), dtype),
+                "Wc": w_init(ks[3], (r, self.experts_total), dtype),
+                "bias": jnp.zeros((self.experts_total,), dtype),
+            }
+            if self.carries_router:
+                params["gamma"] = jnp.zeros((), dtype)
+        params.update(
+            gate=w_init(ks[4], (held, h, i), dtype),
+            up=w_init(ks[5], (held, h, i), dtype),
+            down=w_init(ks[6], (held, i, h), dtype))
         return params, {}
 
     def route(self, params, tokens, carried):
-        """The router's state [M,R], each token's expert and its share
-        p[e*], in float32."""
+        """The MLP router's state [M,R] (None for the linear router), each
+        token's experts and their shares of its output, in float32: [M]
+        where ``top_k`` is 1, else [M, top_k]."""
         f32, exact = jnp.float32, jax.lax.Precision.HIGHEST
 
         def product(x, name):
             return jnp.matmul(x, params[name].astype(f32), precision=exact)
 
-        r = product(tokens.astype(f32), "Wr")
-        if "gamma" in params:
-            r = r + params["gamma"].astype(f32) * carried
-        z = product(jax.nn.gelu(product(jax.nn.gelu(product(r, "Wa")), "Wb")),
-                    "Wc")
-        prob = jax.nn.softmax(z, axis=-1)
-        chosen = jnp.argmax(
-            prob + jax.lax.stop_gradient(params["bias"].astype(f32)), axis=-1)
-        share = jnp.take_along_axis(prob, chosen[:, None], axis=-1)[:, 0]
+        if self.router == "linear":
+            r, z = None, product(tokens.astype(f32), "Wg")
+        else:
+            r = product(tokens.astype(f32), "Wr")
+            if "gamma" in params:
+                r = r + params["gamma"].astype(f32) * carried
+            z = product(
+                jax.nn.gelu(product(jax.nn.gelu(product(r, "Wa")), "Wb")),
+                "Wc")
+        prob = tilted = jax.nn.softmax(z, axis=-1)
+        if "bias" in params:
+            tilted = prob + jax.lax.stop_gradient(params["bias"].astype(f32))
+        if self.top_k == 1:
+            chosen = jnp.argmax(tilted, axis=-1)
+            share = jnp.take_along_axis(prob, chosen[:, None], axis=-1)[:, 0]
+        else:
+            _, chosen = jax.lax.top_k(tilted, self.top_k)
+            share = jnp.take_along_axis(prob, chosen, axis=-1)
+            share = share / jnp.sum(share, axis=-1, keepdims=True)
         return r, chosen, share
 
     def apply(self, params, state, x, *, train=False, rng=None):
         shape = x.shape
         tokens = x.reshape(-1, shape[-1])
-        held = len(self.experts_held)
+        held, fan = len(self.experts_held), self.top_k
         # an expert's place among those held; ``held`` for one held elsewhere
         place = np.full((self.experts_total,), held, np.int32)
         place[list(self.experts_held)] = np.arange(held)
         with jax.named_scope(SCOPE_MOE_ROUTE):
             r, chosen, share = self.route(
                 params, tokens, state.get("router"))
-            local = jnp.asarray(place)[chosen]
+            # a token's pairs lie side by side: pair p is of token p // fan
+            local = jnp.asarray(place)[chosen.reshape(-1)]
             order = jnp.argsort(local)
             inverse = jnp.argsort(order)
             sizes = jnp.sum(local[:, None] == jnp.arange(held + 1)[None, :],
                             axis=0, dtype=jnp.int32)
-            rows = _take_rows(tokens, order, inverse)
-        _record_grouped_product(tokens.shape[0], shape[-1],
+            rows = _take_rows(tokens, order if fan == 1 else order // fan,
+                              inverse, fan)
+        _record_grouped_product(rows.shape[0], shape[-1],
                                 params["gate"].shape[-1], held)
         with jax.named_scope(SCOPE_MOE_EXPERTS):
             inner = (jax.nn.silu(_grouped(rows, params["gate"], sizes))
                      * _grouped(rows, params["up"], sizes))
             out = _grouped(inner, params["down"], sizes)
         with jax.named_scope(SCOPE_MOE_ROUTE):
-            weight = jnp.where(local < held, share, 0.0)[order]
+            weight = jnp.where(local < held, share.reshape(-1), 0.0)[order]
             out = (out * weight[:, None]).astype(x.dtype)
             y = _take_rows(out, inverse, order)
-        return y.reshape(shape), {"router": r, "tokens_here": sizes[:held]}
+            if fan > 1:  # the weighted results of one token add up
+                y = jnp.sum(y.reshape(-1, fan, shape[-1]).astype(jnp.float32),
+                            axis=1).astype(x.dtype)
+        y, routed = y.reshape(shape), {"tokens_here": sizes[:held]}
+        if r is not None:
+            routed["router"] = r
+        return y, routed
 
 
 def load_balance_loss(probs, dispatch) -> jnp.ndarray:

@@ -126,8 +126,10 @@ COMPILE_KINDS = frozenset({
 })
 
 # kernels and operations with a backward rule of their own, at trace time
-# (kernels/flash_attention.py, nn/layers/moe.py, ops/loss.py)
+# (kernels/flash_attention.py, nn/layers/attention.py, nn/layers/moe.py,
+# ops/loss.py)
 KERNEL_KINDS = frozenset({
+    "attention.dsa_select",
     "head.linear_cross_entropy",
     "kernel.flash_plan",
     "kernel.grouped_product",
@@ -221,13 +223,25 @@ SCOPE_CCA_MIX = "cca_mix"          # in attn: CCA's projections, value shift,
 SCOPE_MOE_ROUTE = "moe_route"      # in mlp: router, argmax, sort, gather,
                                    # scatter and weighting
 SCOPE_MOE_EXPERTS = "moe_experts"  # in mlp: the experts' grouped products
-SUB_SCOPES = (SCOPE_CCA_MIX, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS)
+SCOPE_DSA_INDEX = "dsa_index"      # in attn: the indexer's projections, norm,
+                                   # rotary, score product, relu, weighting
+SCOPE_DSA_SELECT = "dsa_select"    # in attn: the threshold and the pair mask
+SUB_SCOPES = (SCOPE_CCA_MIX, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
+              SCOPE_DSA_INDEX, SCOPE_DSA_SELECT)
 
 # Metrics of the step that ``Trainer.fit`` fetches once as it returns (the
 # last step's) and publishes in ``observability/runtime.step_counters``.
+# ``moe.tokens_here`` counts token-expert pairs where a token has several
+# experts.
 COUNTER_MOE_TOKENS_HERE = "moe.tokens_here"        # [layers, experts held]
 COUNTER_MOE_LOAD = "moe.load_max_over_mean"        # mean over the layers
-STEP_COUNTERS = (COUNTER_MOE_TOKENS_HERE, COUNTER_MOE_LOAD)
+MOE_COUNTERS = (COUNTER_MOE_TOKENS_HERE, COUNTER_MOE_LOAD)
+COUNTER_DSA_PAIRS = "dsa.pairs_selected"           # [layers], over the batch
+COUNTER_DSA_KEYS_MEAN = "dsa.keys_selected_mean"   # a query, over the layers
+COUNTER_DSA_TILES_EMPTY = "dsa.tiles_empty_share"  # of flash_fwd's live tiles
+DSA_COUNTERS = (COUNTER_DSA_PAIRS, COUNTER_DSA_KEYS_MEAN,
+                COUNTER_DSA_TILES_EMPTY)
+STEP_COUNTERS = MOE_COUNTERS + DSA_COUNTERS
 
 # ``name=`` of each Pallas kernel: the custom call reads ``jvp(flash_fwd)``
 # where an unnamed one reads ``jvp()``.

@@ -30,7 +30,7 @@ def one_chip():
 
 
 # (batch, heads, queries, keys, head width), dtype, causal, key mask, and
-# optionally the caller's own blocks and environment
+# optionally the caller's own blocks, environment, and a mask of pairs
 _CALLS = {
     "gpt2_small.train_s1024": ((16, 12, 1024, 1024, 64), "bfloat16", True,
                                False),
@@ -38,6 +38,12 @@ _CALLS = {
     # CCA's call: 8 query heads of 128, k and v repeated to them
     "zaya1_8b.train_s4096": ((2, 8, 4096, 4096, 128), "bfloat16", True,
                              False),
+    # indexed attention's call: 32 query heads of 128, k and v repeated to
+    # them, one [batch, T, S] int8 mask of the selected pairs for all heads
+    "keye_vl2_30b_a3b.train_s8192": ((2, 32, 8192, 8192, 128), "bfloat16",
+                                     True, False, None, None, True),
+    "pair_masked_ragged_float32": ((2, 4, 1000, 1000, 64), "float32", True,
+                                   True, None, None, True),
     "bert_like_masked_float32": ((2, 8, 2048, 2048, 128), "float32", False,
                                  True),
     "causal_masked_float32_128_wide": ((2, 8, 2048, 2048, 128), "float32",
@@ -61,7 +67,7 @@ _CALLS = {
 def test_forward_and_backward_compile_at_the_default_geometry(
         call, one_chip, as_on_tpu, monkeypatch):
     (b, h, t, s, d), dtype, causal, masked, *rest = _CALLS[call]
-    own_blocks, env = (rest + [None, None])[:2]
+    own_blocks, env, pairs = (rest + [None, None, False])[:3]
     for name, value in (env or {}).items():
         monkeypatch.setenv(name, value)
     blocks = flash_block_sizes(t, s, d, causal)
@@ -75,10 +81,16 @@ def test_forward_and_backward_compile_at_the_default_geometry(
     if masked:
         args += (jax.ShapeDtypeStruct((b, s), jnp.float32,
                                       sharding=one_chip),)
+    if pairs:
+        args += (jax.ShapeDtypeStruct((b, t, s), jnp.int8,
+                                      sharding=one_chip),)
 
-    def loss(q, k, v, *key_mask):
-        out = fa._flash(q, k, v, key_mask[0] if key_mask else None, causal,
-                        d ** -0.5, blocks)
+    def loss(q, k, v, *masks):
+        masks = list(masks)
+        key_mask = masks.pop(0) if masked else None
+        pair_mask = masks.pop(0) if pairs else None
+        out = fa._flash(q, k, v, key_mask, causal, d ** -0.5, blocks,
+                        pair_mask)
         return jnp.sum(out.astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(

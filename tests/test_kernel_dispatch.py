@@ -128,6 +128,39 @@ class TestTheStepLowersForTheChip:
         return trainer.train_step.trace(trainer.init_state(), batch).lower(
             lowering_platforms=("tpu",)).as_text(debug_info=names)
 
+    @pytest.mark.parametrize("masked", [False, True])
+    def test_a_call_holds_the_pair_mask_only_where_it_was_given(
+            self, as_on_tpu, masked):
+        """A bare causal call, lowered for the chip: without a pair mask
+        its three kernels take what they took before it existed (q, k, v;
+        and do, lse, delta in the backward) and no int8 operand; with one,
+        each takes it once, after q, k, v."""
+        import re
+
+        q = jnp.ones((2, 4, 1024, 64), jnp.bfloat16)
+        mask = jnp.ones((2, 1024, 1024), jnp.int8) if masked else None
+
+        def loss(q, k, v):
+            return jnp.sum(fa.flash_attention(
+                q, k, v, causal=True, pair_mask=mask).astype(jnp.float32))
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+            q, q, q).lower(lowering_platforms=("tpu",)).as_text()
+        calls = [line for line in text.splitlines()
+                 if "@tpu_custom_call" in line]
+        assert len(calls) == 3
+        for call in calls:
+            operands = re.search(r" : \(([^)]*)\) -> ", call).group(1)
+            shapes = re.findall(r"tensor<([0-9x]+x\w+)>", operands)
+            forward = 'kernel_name = "flash_fwd"' in call
+            want = ["8x1024x64xbf16"] * 3
+            if masked:
+                want.append("2x1024x1024xi8")
+            if not forward:  # do, lse, delta
+                want += ["8x1024x64xbf16", "8x1024x128xf32",
+                         "8x1024x128xf32"]
+            assert shapes == want, (shapes, want)
+
     def test_gpt2_shaped_step_feeds_the_kernels_64_wide(self, as_on_tpu):
         """The three kernels by name, q, k and v 64 wide, each lowered
         once in a function that every block calls: the forward's and the
@@ -208,8 +241,50 @@ class TestTheStepLowersForTheChip:
         assert len(sites) == 9 * model.config.num_layers
         assert sites.count("tgmm") == 3 * model.config.num_layers
         names = set(re.findall(r'loc\("([^"]+)"', text))
-        for sub in vocab.SUB_SCOPES:
+        for sub in (vocab.SCOPE_CCA_MIX, vocab.SCOPE_MOE_ROUTE,
+                    vocab.SCOPE_MOE_EXPERTS):
             assert [n for n in names if sub in n], sub
+        # and nothing of the indexed attention that came after it
+        for sub in (vocab.SCOPE_DSA_INDEX, vocab.SCOPE_DSA_SELECT):
+            assert not [n for n in names if sub in n], sub
+        for call in flash:  # q, k, v, do, lse, delta: no mask of pairs
+            assert "xi8>" not in call
+
+    def test_keye_shaped_step_hands_the_kernels_one_mask_for_all_heads(
+            self, as_on_tpu, monkeypatch):
+        """Heads of 128 at T = 1024, the indexer keeping 256 keys: the
+        three flash kernels each take a [batch, T, T] int8 mask beside
+        q, k, v at [batch x query heads, T, 128] and ask for more scoped
+        VMEM than a call without one; the pairs (2 a token) go through
+        megablox; the indexer's sub-scopes are on the step."""
+        import re
+
+        from deeplearning4j_tpu.models.keye import keye_tiny
+        from deeplearning4j_tpu.nn.layers import moe
+        from deeplearning4j_tpu.observability import vocab
+
+        monkeypatch.setattr(moe, "use_pallas", lambda: True)
+        monkeypatch.setattr(moe, "interpret", lambda: False)
+        model = keye_tiny(hidden=256, head_dim=128, expert_units=256,
+                          index_top_k=256, experts_held=(0, 2, 5))
+        text = self._lowered(model, {"features": {
+            "token_ids": np.zeros((2, 1024), np.int32)}}, names=True)
+        calls = [line for line in text.splitlines()
+                 if "@tpu_custom_call" in line]
+        flash = [c for c in calls if 'kernel_name = "flash_' in c]
+        assert len(flash) == 3  # each once, in a function the layers call
+        for call in flash:
+            operands = re.search(r" : \(([^)]*)\) -> ", call).group(1)
+            shapes = re.findall(r"tensor<([0-9x]+x\w+)>", operands)
+            assert shapes[:4] == ["8x1024x128xf32"] * 3 + [
+                "2x1024x1024xi8"], call[-400:]
+        sites = re.findall(r"call @(t?gmm)(?:_\d+)?\(", text)
+        assert sites.count("tgmm") == 3 * model.config.num_layers
+        names = set(re.findall(r'loc\("([^"]+)"', text))
+        for sub in (vocab.SCOPE_DSA_INDEX, vocab.SCOPE_DSA_SELECT,
+                    vocab.SCOPE_MOE_ROUTE, vocab.SCOPE_MOE_EXPERTS):
+            assert [n for n in names if sub in n], sub
+        assert not [n for n in names if vocab.SCOPE_CCA_MIX in n]
 
     def test_bert_shaped_step_at_128_holds_no_kernel(self, as_on_tpu):
         from deeplearning4j_tpu.models.bert import bert_tiny, make_mlm_batch
