@@ -50,6 +50,7 @@ from deeplearning4j_tpu.observability.vocab import (
     COUNTER_DSA_PAIRS,
     COUNTER_DSA_TILES_EMPTY,
     COUNTER_MOE_LOAD,
+    COUNTER_MOE_PIECES_RUN,
     COUNTER_MOE_TOKENS_HERE,
     SCOPE_ATTN,
     SCOPE_EMBED,
@@ -158,9 +159,10 @@ class Keye:
     def encode(self, params, ids):
         """[N,T] int32 -> (hidden [N,T,H] with the final norm applied, what
         the layers counted: the token-expert pairs that landed on each
-        expert held [layers, held], the pairs the indexer selected
-        [layers] and the share of flash_fwd's live tiles in which it
-        selected nothing [layers])."""
+        expert held [layers, held], the pieces of the sorted pairs that
+        ran [layers], the pairs the indexer selected [layers] and the
+        share of flash_fwd's live tiles in which it selected nothing
+        [layers])."""
         c = self.config
         with jax.named_scope(SCOPE_EMBED):
             x = opsnn.embedding_lookup(params["embeddings"]["word"], ids)
@@ -172,7 +174,8 @@ class Keye:
         def experts(p, x):
             y, routed = self._experts().apply(
                 p, {}, opsnn.rms_norm(x, p["norm"], c.eps))
-            return x + c.residual_init_scale * y, routed["tokens_here"]
+            return x + c.residual_init_scale * y, {
+                k: routed[k] for k in ("tokens_here", "pieces_run")}
 
         counted = []
         for i in range(c.num_layers):
@@ -186,8 +189,8 @@ class Keye:
                     rope_theta=c.rope_theta, eps=c.eps)
                 x = x + c.residual_init_scale * a
             with jax.named_scope(SCOPE_MLP):
-                x, tokens_here = experts(layer["moe"], x)
-            counted.append(dict(selected, tokens_here=tokens_here))
+                x, routed = experts(layer["moe"], x)
+            counted.append(dict(selected, **routed))
         with jax.named_scope(SCOPE_HEAD):
             x = opsnn.rms_norm(x, params["final"]["norm"], c.eps)
         return x, {k: jnp.stack([layer[k] for layer in counted])
@@ -218,6 +221,7 @@ class Keye:
             COUNTER_MOE_LOAD: jnp.mean(
                 jnp.max(load, axis=1)
                 / jnp.maximum(jnp.mean(load, axis=1), 1.0)),
+            COUNTER_MOE_PIECES_RUN: counted["pieces_run"],
             COUNTER_DSA_PAIRS: counted["pairs_selected"],
             COUNTER_DSA_KEYS_MEAN: jnp.mean(
                 counted["pairs_selected"].astype(jnp.float32)) / ids.size,

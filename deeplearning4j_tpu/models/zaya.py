@@ -30,6 +30,7 @@ from deeplearning4j_tpu.nn.layers.attention import cca_attention
 from deeplearning4j_tpu.nn.layers.moe import RoutedExperts
 from deeplearning4j_tpu.observability.vocab import (
     COUNTER_MOE_LOAD,
+    COUNTER_MOE_PIECES_RUN,
     COUNTER_MOE_TOKENS_HERE,
     SCOPE_ATTN,
     SCOPE_EMBED,
@@ -129,12 +130,14 @@ class Zaya:
 
     def encode(self, params, ids):
         """[N,T] int32 -> (hidden [N,T,H] with the final norm applied,
-        tokens that landed on each expert held [layers, held])."""
+        what the layers counted: the tokens that landed on each expert
+        held [layers, held], the pieces of the sorted tokens that ran
+        [layers])."""
         c = self.config
         with jax.named_scope(SCOPE_EMBED):
             x = opsnn.embedding_lookup(params["embeddings"]["word"], ids)
         routed: Dict[str, Any] = {}
-        tokens_here = []
+        counted = []
         for i in range(c.num_layers):
             p = params[f"layer_{i}"]["attn"]
             with jax.named_scope(SCOPE_ATTN):
@@ -148,10 +151,11 @@ class Zaya:
                 y, routed = self._experts(i).apply(
                     p, routed, opsnn.rms_norm(x, p["norm"], c.eps))
                 x = p["res_a"] * x + p["res_c"] * y
-            tokens_here.append(routed["tokens_here"])
+            counted.append(routed)
         with jax.named_scope(SCOPE_HEAD):
             x = opsnn.rms_norm(x, params["final"]["norm"], c.eps)
-        return x, jnp.stack(tokens_here)
+        return x, {k: jnp.stack([layer[k] for layer in counted])
+                   for k in ("tokens_here", "pieces_run")}
 
     def logits(self, params, hidden):
         with jax.named_scope(SCOPE_HEAD):
@@ -168,17 +172,18 @@ class Zaya:
         have a next token. The step's metrics carry the experts' load
         (``observability.vocab.STEP_COUNTERS``)."""
         ids = _token_ids(batch["features"])
-        h, tokens_here = self.encode(params, ids)
+        h, counted = self.encode(params, ids)
         with jax.named_scope(SCOPE_HEAD):
             loss = jnp.mean(losses.linear_softmax_cross_entropy(
                 h[:, :-1], params["embeddings"]["word"], ids[:, 1:]))
-        load = tokens_here.astype(jnp.float32)
+        load = counted["tokens_here"].astype(jnp.float32)
         metrics = {
             "loss": loss,
-            COUNTER_MOE_TOKENS_HERE: tokens_here,
+            COUNTER_MOE_TOKENS_HERE: counted["tokens_here"],
             COUNTER_MOE_LOAD: jnp.mean(
                 jnp.max(load, axis=1)
                 / jnp.maximum(jnp.mean(load, axis=1), 1.0)),
+            COUNTER_MOE_PIECES_RUN: counted["pieces_run"],
         }
         return loss, (state, metrics)
 

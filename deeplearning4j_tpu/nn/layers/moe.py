@@ -18,9 +18,9 @@ the GShard auxiliary loss for callers that want to regularize routing.
 ``RoutedExperts``, beside it on the same ``[E, H, I]`` stacks, is what a
 language model of today runs: drop-free top-k routing with no capacity
 and no dispatch tensor (the token-expert pairs are sorted by expert and
-the experts' products run grouped over the sorted rows), told which of the
-experts it holds, so that it is one chip's share of an expert-parallel
-layer.
+the experts' products run grouped over the sorted rows, a piece at a time
+and only the pieces in which a pair landed), told which of the experts it
+holds, so that it is one chip's share of an expert-parallel layer.
 """
 
 from __future__ import annotations
@@ -162,25 +162,49 @@ class MoEBlock(LayerConfig):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_rows(x, index, inverse, fan=1):
-    """``x[index]`` with a gradient that is a gather too and not a
-    scatter. ``index`` takes every row of ``x`` ``fan`` times, in an order
-    that is a permutation of the copies (copy ``c`` of row ``r`` counted
-    as ``r * fan + c``) whose inverse is ``inverse``: the copies'
-    gradients, gathered back side by side, are summed."""
-    return x[index]
+def _dispatch(tokens, source, inverse, count):
+    """The rows of the sorted pairs, or of a piece of them: ``tokens[source]``
+    (``count`` tokens), with the gradient ``_combine`` of the rows'
+    gradients, its transpose, and not a scatter in ``tokens``' own
+    rounding."""
+    return tokens[source]
 
 
-def _take_rows_bwd(fan, inverse, g):
-    back = g[inverse]
-    if fan > 1:
-        back = jnp.sum(back.reshape(-1, fan, g.shape[-1]).astype(jnp.float32),
-                       axis=1).astype(g.dtype)
-    return back, None, None
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine(rows, source, inverse, count):
+    """For each of ``count`` tokens the sum of its pairs' ``rows``; its
+    gradient is ``_dispatch`` of the tokens' gradients."""
+    return _sum_by_token(rows, source, inverse, count)
 
 
-_take_rows.defvjp(
-    lambda x, index, inverse, fan: (x[index], inverse), _take_rows_bwd)
+def _sum_by_token(rows, source, inverse, count):
+    """Each token's rows summed in float32. Where the rows are all the
+    sorted pairs, ``inverse`` [pairs] is each pair's row, a token's pairs
+    side by side: a gather back and the sum. Where they are a piece of
+    them it is None, a token's other pairs may lie in another piece, and
+    each row is added to the token that ``source`` names: on the chip
+    that scatter of a piece's rows costs less than two thirds of the
+    gather of all the pairs' positions out of the piece (PERF.md section
+    5, 2b)."""
+    f32 = jnp.float32
+    if inverse is None:
+        total = jnp.zeros((count, rows.shape[-1]), f32)
+        return total.at[source].add(rows.astype(f32)).astype(rows.dtype)
+    back = rows[inverse]
+    if back.shape[0] > count:
+        back = jnp.sum(back.reshape(count, -1, rows.shape[-1]).astype(f32),
+                       axis=1).astype(rows.dtype)
+    return back
+
+
+_dispatch.defvjp(
+    lambda tokens, source, inverse, count: (tokens[source],
+                                            (source, inverse)),
+    lambda count, kept, g: (_sum_by_token(g, *kept, count), None, None))
+_combine.defvjp(
+    lambda rows, source, inverse, count: (
+        _sum_by_token(rows, source, inverse, count), source),
+    lambda count, source, g: (g[source], None, None))
 
 # The grouped product on the chip: megablox ``gmm`` (Pallas; JAX ships it),
 # chosen over ``jax.lax.ragged_dot`` by traces on a v5e (PERF.md section 6,
@@ -219,9 +243,41 @@ def _tiles(m, k, n):
         GROUPED_TILES, (-(-m // 8) * 8, k, n)))
 
 
-def _record_grouped_product(m, k, n, groups):
+# The sorted pair rows are walked a piece at a time, and a piece in which no
+# pair landed is not run. A piece holds this many times the pairs that a
+# balanced router lands on the experts held: every load a balanced cell has
+# shown fits the first (PERF.md section 5, 2b, has the chip's sweep).
+PIECE_OVER_BALANCED = 2
+
+
+def _piece_rows(pairs, held, total):
+    """Rows of a piece of ``pairs`` sorted rows where ``held`` of ``total``
+    experts are held: whole row tiles, and all the rows where half of the
+    experts or more are held."""
+    rows = -(-PIECE_OVER_BALANCED * pairs * held // total)
+    tile = _tiles(rows, 0, 0)[0]  # of rows: 8 at least, as ``_grouped`` pads
+    return min(pairs, -(-rows // tile) * tile)
+
+
+def _piece_of(order, sizes, first, rows):
+    """Of the sorted rows ``[first, first + rows)``: the pair in each
+    (``order`` there; past the last pair a piece is padded with rows of no
+    group) and the rows of each group, last those of no group."""
+    pairs = order.shape[0]
+    if rows == pairs:
+        return order, sizes
+    span = jax.lax.dynamic_slice_in_dim(
+        jnp.pad(order, (0, -pairs % rows)), first, rows)
+    ends = jnp.cumsum(sizes[:-1])
+    here = (jnp.clip(ends, first, first + rows)
+            - jnp.clip(ends - sizes[:-1], first, first + rows))
+    return span, jnp.append(here, rows - jnp.sum(here))
+
+
+def _record_grouped_product(pairs, m, k, n, groups):
     """One ``kernel.grouped_product`` flight event per layer, at trace
-    time: which product the experts run through, and its tiles."""
+    time: which product the experts run through, its tiles, and the
+    pieces of ``m`` rows that the ``pairs`` sorted rows are walked in."""
     from deeplearning4j_tpu.observability.flightrecorder import record_event
 
     on_chip = use_pallas()
@@ -229,7 +285,8 @@ def _record_grouped_product(m, k, n, groups):
         "kernel.grouped_product",
         product="megablox.gmm" if on_chip else "jax.lax.ragged_dot",
         tiles=list(_tiles(m, k, n)) if on_chip else None,
-        rows=m, inner=k, columns=n, groups=groups)
+        rows=pairs, rows_a_piece=m, pieces=-(-pairs // m),
+        inner=k, columns=n, groups=groups)
 
 
 @register_config
@@ -251,16 +308,21 @@ class RoutedExperts(LayerConfig):
     keeps its own p).
 
     The token-expert pairs are sorted by the expert's place among those
-    held, the experts' three products run grouped over the sorted rows
-    (``[E, H, I]`` stacks, as ``MoEBlock``'s), and the weighted results of
-    one token are gathered back and summed. A pair whose expert is held
-    elsewhere adds zeros here; no pair is dropped for want of room,
-    because there is no capacity: the sorted rows are all the pairs.
+    held (a pair whose expert is held elsewhere sorts last and adds
+    nothing here), and the sorted rows are walked in pieces of
+    ``_piece_rows``: twice what a balanced router lands here, so one
+    piece of all the pairs where half of the experts or more are held.
+    A piece gathers its tokens' rows, runs the experts' three products
+    grouped over them (``[E, H, I]`` stacks, as ``MoEBlock``'s) and adds
+    each token's weighted results to the output; a piece in which no pair
+    landed is not run. No pair is dropped for want of room, because there
+    is no capacity: a load that fills every piece runs every piece.
 
     ``apply`` takes the MLP router's state of the layer before under
     ``state["router"]`` (absent in the first layer, which has no
     ``gamma``) and returns its own there, beside ``tokens_here``: how many
-    pairs landed on each expert held.
+    pairs landed on each expert held, and ``pieces_run``: how many pieces
+    of the sorted rows ran.
     """
 
     experts_total: int = 16
@@ -334,31 +396,61 @@ class RoutedExperts(LayerConfig):
         # an expert's place among those held; ``held`` for one held elsewhere
         place = np.full((self.experts_total,), held, np.int32)
         place[list(self.experts_held)] = np.arange(held)
+        pairs = tokens.shape[0] * fan
+        rows_a_piece = _piece_rows(pairs, held, self.experts_total)
         with jax.named_scope(SCOPE_MOE_ROUTE):
             r, chosen, share = self.route(
                 params, tokens, state.get("router"))
             # a token's pairs lie side by side: pair p is of token p // fan
             local = jnp.asarray(place)[chosen.reshape(-1)]
             order = jnp.argsort(local)
-            inverse = jnp.argsort(order)
+            # each pair's row, where one piece holds them all
+            inverse = (jnp.argsort(order) if rows_a_piece == pairs
+                       else None)
             sizes = jnp.sum(local[:, None] == jnp.arange(held + 1)[None, :],
                             axis=0, dtype=jnp.int32)
-            rows = _take_rows(tokens, order if fan == 1 else order // fan,
-                              inverse, fan)
-        _record_grouped_product(rows.shape[0], shape[-1],
+        _record_grouped_product(pairs, rows_a_piece, shape[-1],
                                 params["gate"].shape[-1], held)
-        with jax.named_scope(SCOPE_MOE_EXPERTS):
-            inner = (jax.nn.silu(_grouped(rows, params["gate"], sizes))
-                     * _grouped(rows, params["up"], sizes))
-            out = _grouped(inner, params["down"], sizes)
-        with jax.named_scope(SCOPE_MOE_ROUTE):
-            weight = jnp.where(local < held, share.reshape(-1), 0.0)[order]
-            out = (out * weight[:, None]).astype(x.dtype)
-            y = _take_rows(out, inverse, order)
-            if fan > 1:  # the weighted results of one token add up
-                y = jnp.sum(y.reshape(-1, fan, shape[-1]).astype(jnp.float32),
-                            axis=1).astype(x.dtype)
-        y, routed = y.reshape(shape), {"tokens_here": sizes[:held]}
+
+        def piece(first):
+            """What the sorted rows from ``first`` on, a piece of them,
+            add to every token's output."""
+            with jax.named_scope(SCOPE_MOE_ROUTE):
+                span, sizes_here = _piece_of(order, sizes, first,
+                                             rows_a_piece)
+                source = span if fan == 1 else span // fan
+                rows = _dispatch(tokens, source, inverse, tokens.shape[0])
+            with jax.named_scope(SCOPE_MOE_EXPERTS):
+                inner = (
+                    jax.nn.silu(_grouped(rows, params["gate"], sizes_here))
+                    * _grouped(rows, params["up"], sizes_here))
+                out = _grouped(inner, params["down"], sizes_here)
+            with jax.named_scope(SCOPE_MOE_ROUTE):
+                weight = jnp.where(local < held, share.reshape(-1), 0.0)[span]
+                out = (out * weight[:, None]).astype(x.dtype)
+                # the weighted results of one token add up
+                return _combine(out, source, inverse, tokens.shape[0])
+
+        y, pieces_run = piece(0), jnp.int32(1)
+        if rows_a_piece < pairs:
+            landed = pairs - sizes[-1]
+            # recomputed in the backward pass, so that the scan keeps no
+            # [rows of a piece, .] array for a piece that may not run
+            later = jax.checkpoint(piece)
+
+            def walk(y, first):
+                return jax.lax.cond(first < landed, lambda: y + later(first),
+                                    lambda: y), None
+
+            def spilled(y):
+                return jax.lax.scan(walk, y, jnp.arange(
+                    rows_a_piece, pairs, rows_a_piece, dtype=jnp.int32))[0]
+
+            # one branch not taken, where all that landed fits the first
+            y = jax.lax.cond(rows_a_piece < landed, spilled, lambda y: y, y)
+            pieces_run = jnp.maximum(1, -(-landed // rows_a_piece))
+        y = y.reshape(shape)
+        routed = {"tokens_here": sizes[:held], "pieces_run": pieces_run}
         if r is not None:
             routed["router"] = r
         return y, routed

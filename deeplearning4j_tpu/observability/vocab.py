@@ -235,7 +235,9 @@ SUB_SCOPES = (SCOPE_CCA_MIX, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
 # experts.
 COUNTER_MOE_TOKENS_HERE = "moe.tokens_here"        # [layers, experts held]
 COUNTER_MOE_LOAD = "moe.load_max_over_mean"        # mean over the layers
-MOE_COUNTERS = (COUNTER_MOE_TOKENS_HERE, COUNTER_MOE_LOAD)
+COUNTER_MOE_PIECES_RUN = "moe.pieces_run"          # [layers], of the sorted rows
+MOE_COUNTERS = (COUNTER_MOE_TOKENS_HERE, COUNTER_MOE_LOAD,
+                COUNTER_MOE_PIECES_RUN)
 COUNTER_DSA_PAIRS = "dsa.pairs_selected"           # [layers], over the batch
 COUNTER_DSA_KEYS_MEAN = "dsa.keys_selected_mean"   # a query, over the layers
 COUNTER_DSA_TILES_EMPTY = "dsa.tiles_empty_share"  # of flash_fwd's live tiles

@@ -167,3 +167,55 @@ def test_the_gpt2_head_writes_its_logits_and_no_other_array_of_their_size(
     # the bias's gradient: no top-level reduction over the rows to [50257]
     assert not re.search(
         r'= \w+\[50257\]\S* fusion\(.*op_name="[^"]*head[^"]*/reduce_sum"', entry)
+
+
+def test_the_keye_cells_expert_layer_compiles_in_pieces(one_chip,
+                                                        monkeypatch):
+    """A count of the compiled program, not a time: one ``RoutedExperts``
+    layer at ``keye_vl2_30b_a3b.train_s8192``'s shape (2 x 8,192 tokens of
+    2,048, top-8 of 128 experts, 16 held, 768 wide), forward and gradient
+    under ``jax.checkpoint`` as the model holds it. The 131,072 sorted
+    pairs are four pieces of 32,768 rows: megablox's kernels take a
+    piece's rows, and no array of the experts' inner width or of the
+    model's has a row for each of the pairs."""
+    from deeplearning4j_tpu.nn.layers import moe
+
+    monkeypatch.setattr(moe, "use_pallas", lambda: True)
+    monkeypatch.setattr(moe, "interpret", lambda: False)
+    tokens, hidden, units, fan = 2 * 8192, 2048, 768, 8
+    layer = moe.RoutedExperts(experts_total=128,
+                              experts_held=tuple(range(16)), units=units,
+                              top_k=fan, router="linear")
+    pairs = tokens * fan
+    rows = moe._piece_rows(pairs, 16, 128)
+    assert (rows, -(-pairs // rows)) == (32768, 4)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    @jax.checkpoint
+    def sublayer(p, x):
+        y, routed = layer.apply(p, {}, x)
+        return x + y, routed["pieces_run"]
+
+    def loss(p, x):
+        y, ran = sublayer(p, x)
+        return jnp.sum(jnp.square(y.astype(jnp.float32))), ran
+
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.key(0), (hidden,), jnp.bfloat16)[0])
+    x = jax.ShapeDtypeStruct((2, 8192, hidden), jnp.bfloat16)
+    compiled = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)).lower(
+        described(params), described(x)).compile()
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels and all(f"[{rows}," in line for line in kernels)
+    assert not [line for line in kernels if f"[{pairs}," in line]
+    assert f"[{pairs},{units}]" not in text
+    assert f"[{pairs},{hidden}]" not in text
+    # the parent's layer planned 2.66 GB here (PERF.md section 6, PR 34)
+    assert compiled.memory_analysis().peak_memory_in_bytes < 2.5e9

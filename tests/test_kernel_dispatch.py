@@ -278,8 +278,11 @@ class TestTheStepLowersForTheChip:
             shapes = re.findall(r"tensor<([0-9x]+x\w+)>", operands)
             assert shapes[:4] == ["8x1024x128xf32"] * 3 + [
                 "2x1024x1024xi8"], call[-400:]
+        # 3 of 8 experts held: the sorted pairs are two pieces, the first
+        # in line in each layer and the other in the body of a scan, a
+        # function that is lowered once and called by the layers' scans
         sites = re.findall(r"call @(t?gmm)(?:_\d+)?\(", text)
-        assert sites.count("tgmm") == 3 * model.config.num_layers
+        assert sites.count("tgmm") == 3 * model.config.num_layers + 3
         names = set(re.findall(r'loc\("([^"]+)"', text))
         for sub in (vocab.SCOPE_DSA_INDEX, vocab.SCOPE_DSA_SELECT,
                     vocab.SCOPE_MOE_ROUTE, vocab.SCOPE_MOE_EXPERTS):
@@ -294,6 +297,88 @@ class TestTheStepLowersForTheChip:
                                vocab_size=model.config.vocab_size,
                                max_predictions=8, pad_frac=0.2)
         assert "tpu_custom_call" not in self._lowered(model, batch)
+
+
+# experts in all, held, a token's experts, the router
+_EXPERT_LAYERS = {
+    "zaya1_8b_shaped_half_held": (16, tuple(range(8)), 1, "mlp"),
+    "keye_all_held": (8, tuple(range(8)), 2, "linear"),
+    "keye_three_of_eight_held": (8, (0, 2, 5), 2, "linear"),
+    "keye_shaped_an_eighth_held": (16, (3, 9), 2, "linear"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_EXPERT_LAYERS))
+def test_routed_experts_walk_pieces_only_where_a_part_is_held(case,
+                                                              monkeypatch):
+    """One ``RoutedExperts`` layer over 2 x 1024 tokens, forward and
+    gradient under ``jax.checkpoint`` as a model's step holds it, lowered
+    for the TPU platform. Where half of the experts or more are held the
+    sorted rows are one piece: no loop, no branch, no scatter, and
+    megablox's calls as before there were pieces. Where fewer are held,
+    every ``gmm`` / ``tgmm`` call takes a piece's rows, the rows gathered
+    from the tokens are a piece's, and no operation is over all the
+    pairs' rows: a piece's results are added to their tokens."""
+    import re
+
+    from deeplearning4j_tpu.nn.layers import moe
+
+    monkeypatch.setattr(moe, "use_pallas", lambda: True)
+    monkeypatch.setattr(moe, "interpret", lambda: False)
+    total, held, fan, router = _EXPERT_LAYERS[case]
+    tokens, hidden = 2 * 1024, 256
+    layer = moe.RoutedExperts(experts_total=total, experts_held=held,
+                              units=256, router_hidden=16, top_k=fan,
+                              router=router)
+    params, _ = layer.init(jax.random.key(0), (hidden,), jnp.float32)
+    state = {"router": jnp.zeros((tokens, 16))} if router == "mlp" else {}
+
+    def loss(p, x):
+        y, routed = jax.checkpoint(layer.apply)(p, state, x)
+        return jnp.sum(jnp.square(y)), routed["pieces_run"]
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+                   ).trace(params, jnp.zeros((2, 1024, hidden))).lower(
+        lowering_platforms=("tpu",)).as_text()
+    pairs = tokens * fan
+    rows = moe._piece_rows(pairs, len(held), total)
+    calls = re.findall(
+        r"call @(t?gmm)(?:_\d+)?\([^)]*\) : \(([^)]*)\) -> ", text)
+    # a loop that carries rows of the layer's width (megablox's own, over
+    # the groups' edges, carry a few numbers) and a branch inside it
+    walked = any(f"x{hidden}xf32" in line for line in text.splitlines()
+                 if "stablehlo.while" in line)
+    assert walked == ("stablehlo.case" in text) == (rows < pairs)
+    if not walked:
+        assert rows == pairs
+        # forward, forward again for the backward pass, and that pass
+        assert sorted(name for name, _ in calls) == ["gmm"] * 9 + ["tgmm"] * 3
+    else:
+        assert rows == {"keye_three_of_eight_held": 3072,
+                        "keye_shaped_an_eighth_held": 1024}[case]
+    for name, operands in calls:  # lhs [m, k] (tgmm: [k, m]), rhs, sizes
+        shapes = [tuple(int(d) for d in dims.split("x"))
+                  for dims in re.findall(r"tensor<([0-9x]+)x\w+>", operands)]
+        assert rows in shapes[0], (name, shapes)
+        assert all(pairs not in shape or rows == pairs
+                   for shape in shapes), (name, shapes)
+    gathers = re.findall(
+        r'"stablehlo\.gather"\([^)]*\).*? : \(tensor<([0-9x]+)x\w+>, '
+        r"[^)]*\) -> tensor<([0-9x]+)x\w+>", text)
+    wide = {(source, result) for source, result in gathers
+            if result.endswith(f"x{hidden}")}
+    assert (f"{tokens}x{hidden}", f"{rows}x{hidden}") in wide  # dispatch
+    scattered = set(re.findall(  # rows of the layer's width: into, added
+        r": \(tensor<(\d+x%d)xf32>, tensor<\d+x1xi32>, "
+        r"tensor<(\d+x%d)xf32>\) -> tensor<" % (hidden, hidden), text))
+    if walked:  # no array has a row for each of the pairs: a piece's rows
+        # are gathered, and added to their tokens in float32
+        assert not [shape for pair in wide for shape in pair
+                    if shape.startswith(f"{pairs}x")], wide
+        assert scattered == {(f"{tokens}x{hidden}", f"{rows}x{hidden}")}
+    else:  # all the pairs' rows, gathered there and back: no scatter
+        assert (f"{pairs}x{hidden}", f"{pairs}x{hidden}") in wide
+        assert not scattered
 
 
 class TestFlashUnderAMesh:

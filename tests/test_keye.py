@@ -14,6 +14,7 @@ from benchmark.configs import reference_common as rc
 from deeplearning4j_tpu.kernels.flash_attention import reference_attention
 from deeplearning4j_tpu.models.keye import keye_tiny
 from deeplearning4j_tpu.nn.layers import attention as attn
+from deeplearning4j_tpu.nn.layers import moe
 from deeplearning4j_tpu.nn.layers.moe import RoutedExperts
 
 ROWS, SEQ, TOP_K = 2, 32, 8
@@ -217,8 +218,8 @@ def test_at_a_sequence_no_longer_than_top_k_the_layer_is_causal_gqa():
 
 # -- the experts ----------------------------------------------------------------
 
-def sublayer_inputs(seed=11):
-    cfg = tiny_cfg()
+def sublayer_inputs(seed=11, total=8):
+    cfg = dict(tiny_cfg(tuple(range(total))), num_experts_total=total)
     p = layer_of(seeded(cfg, seed), 1)["moe"]
     h = jax.random.normal(jax.random.key(seed), (ROWS, SEQ, 64))
     return cfg, p, h
@@ -229,9 +230,9 @@ def share_of(p, held):
                       for k in ("gate", "up", "down")})
 
 
-def layer(held):
-    return RoutedExperts(experts_total=8, experts_held=tuple(held), units=32,
-                         top_k=2, router="linear")
+def layer(held, total=8):
+    return RoutedExperts(experts_total=total, experts_held=tuple(held),
+                         units=32, top_k=2, router="linear")
 
 
 def program_sublayer(p, h, held):
@@ -269,9 +270,10 @@ def test_no_pair_is_dropped_when_all_tokens_choose_the_same_experts():
     cfg, p, h = sublayer_inputs()
     h = jnp.abs(h)  # so that a positive column of Wg is a large logit:
     # every token's two largest are experts 2 and 5
-    p = dict(p, Wg=(0.01 * p["Wg"]).at[:, 2].set(1.0).at[:, 5].set(0.9))
+    p = crowded(p, 2, 5)
     y, state = program_sublayer(p, h, (2, 5))
     assert np.asarray(state["tokens_here"]).tolist() == [ROWS * SEQ] * 2
+    assert int(state["pieces_run"]) == 2  # of 2: every piece is full
     want = ref.expert_sublayer(dict(cfg, experts_held=[2, 5]),
                                rc.Matmul("float32"), h, share_of(p, (2, 5)))
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-4,
@@ -280,10 +282,77 @@ def test_no_pair_is_dropped_when_all_tokens_choose_the_same_experts():
     # half of the pairs on a chip that holds one of the two
     y, state = program_sublayer(p, h, (5, 7))
     assert np.asarray(state["tokens_here"]).tolist() == [ROWS * SEQ, 0]
+    assert int(state["pieces_run"]) == 1  # half of the pairs: one piece
     # and none where neither is held: all zeros
     y, state = program_sublayer(p, h, (0, 1))
     assert not np.any(np.asarray(y))
     assert np.asarray(state["tokens_here"]).tolist() == [0, 0]
+    assert int(state["pieces_run"]) == 1  # the first runs whatever lands
+
+
+def crowded(p, first, second=None):
+    """The router's matrix with every token's largest logit on expert
+    ``first`` and, if given, its next on ``second``; the input is made
+    positive, so that a positive column is a large logit."""
+    wg = (0.01 * p["Wg"]).at[:, first].set(1.0)
+    return dict(p, Wg=wg if second is None else wg.at[:, second].set(0.9))
+
+
+# experts in all, held, the experts all tokens choose, rows of a piece,
+# pieces, pieces run
+_WALKS = {
+    "fills_both_of_2": (8, (2, 5), (2, 5), 64, 2, 2),
+    "fits_the_first_of_2": (8, (5, 7), (2, 5), 64, 2, 1),
+    # every token's first choice and some second choices
+    "spills_into_the_second_of_2": (8, (5, 7), (5,), 64, 2, 2),
+    "fills_2_of_4": (8, (5,), (2, 5), 32, 4, 2),
+    "fills_all_4": (16, (2, 5), (2, 5), 32, 4, 4),
+    "a_draw_fits_the_first_of_4": (8, (3,), None, 32, 4, 1),
+    "the_last_piece_is_padded": (8, (0, 2, 5), (2, 5), 96, 2, 2),
+    "nothing_lands": (8, (0, 1), (2, 5), 64, 2, 1),
+}
+
+
+@pytest.mark.parametrize("walk", sorted(_WALKS))
+def test_the_walk_in_pieces_gives_the_references_sublayer(walk):
+    """The sorted pairs walked in 2 and in 4 pieces, at loads that fit the
+    first piece, spill into the next and fill all of them, and where the
+    pairs are no whole number of pieces: output, ``tokens_here`` and every
+    gradient are the float32 reference's, and ``pieces_run`` counts the
+    pieces a landed pair lies in (the first runs whatever lands)."""
+    total, held, chosen, rows_a_piece, pieces, ran = _WALKS[walk]
+    cfg, p, h = sublayer_inputs(total=total)
+    if chosen is not None:
+        h, p = jnp.abs(h), crowded(p, *chosen)
+    p = {k: v for k, v in share_of(p, held).items() if k != "norm"}
+    pairs = 2 * ROWS * SEQ
+    assert moe._piece_rows(pairs, len(held), total) == rows_a_piece
+    assert -(-pairs // rows_a_piece) == pieces
+    cut = dict(cfg, experts_held=list(held))
+    mm = rc.Matmul("float32")
+    weigh = jax.random.normal(jax.random.key(9), h.shape)
+
+    def want(p, h):
+        return jnp.sum(weigh * ref.expert_sublayer(cut, mm, h, p))
+
+    def got(p, h):
+        y, state = jax.checkpoint(layer(held, total).apply)(p, {}, h)
+        return jnp.sum(weigh * y), state
+
+    want_loss, want_grads = jax.value_and_grad(want, argnums=(0, 1))(p, h)
+    (got_loss, state), got_grads = jax.value_and_grad(
+        got, argnums=(0, 1), has_aux=True)(p, h)
+    landed = int(np.asarray(state["tokens_here"]).sum())
+    assert int(state["pieces_run"]) == ran
+    assert ran == max(1, -(-landed // rows_a_piece))
+    assert float(got_loss) == pytest.approx(float(want_loss), rel=1e-5,
+                                            abs=1e-6)
+    flat_want = jax.tree_util.tree_flatten_with_path(want_grads)[0]
+    scale = max(float(jnp.max(jnp.abs(g))) for _, g in flat_want)
+    for (path, w), g in zip(flat_want, jax.tree_util.tree_leaves(got_grads)):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-6 * scale,
+            err_msg=jax.tree_util.keystr(path))
 
 
 def test_the_router_keeps_its_width_when_a_part_is_held():
@@ -303,23 +372,29 @@ def test_the_router_keeps_its_width_when_a_part_is_held():
     assert np.all(np.asarray(chosen[:, 0] != chosen[:, 1]))
 
 
-@pytest.mark.parametrize("rows", [ROWS, 3])
-def test_the_chips_grouped_product_gives_the_same_sublayer(rows, monkeypatch):
+@pytest.mark.parametrize("rows,pieces_run", [(ROWS, 1), (3, 1), (ROWS, 2)])
+def test_the_chips_grouped_product_gives_the_same_sublayer(rows, pieces_run,
+                                                           monkeypatch):
     """The kernel the chip runs (megablox ``gmm``, here interpreted) over
-    the sorted pairs, those of no group here last: the same output and
-    gradients as XLA's product."""
+    the sorted pairs, those of no group here last, in two pieces of which
+    a router's draw fills the first and a crowd on two of the experts
+    held both: the same output and gradients as XLA's product."""
     _, p, _ = sublayer_inputs()
     h = jax.random.normal(jax.random.key(5), (rows, SEQ, 64))
+    if pieces_run == 2:
+        h, p = jnp.abs(h), crowded(p, 3, 6)
 
     def run(p, h):
         y, state = program_sublayer(p, h, (1, 3, 6))
-        return jnp.sum(jnp.square(y)), state["tokens_here"]
+        return jnp.sum(jnp.square(y)), (state["tokens_here"],
+                                        state["pieces_run"])
 
     step = jax.value_and_grad(run, argnums=(0, 1), has_aux=True)
-    (want, landed), want_grads = step(p, h)
+    (want, (landed, ran)), want_grads = step(p, h)
     monkeypatch.setenv("DL4J_TPU_FORCE_PALLAS", "1")
-    (got, landed_kernel), got_grads = step(p, h)
-    assert 0 < int(landed.sum()) < 2 * rows * SEQ
+    (got, (landed_kernel, ran_kernel)), got_grads = step(p, h)
+    assert int(ran) == int(ran_kernel) == pieces_run
+    assert 0 < int(landed.sum()) <= 2 * rows * SEQ
     assert np.array_equal(np.asarray(landed), np.asarray(landed_kernel))
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for w, g in zip(jax.tree_util.tree_leaves(want_grads),
@@ -377,6 +452,7 @@ def test_fit_publishes_what_the_last_step_selected_and_routed(monkeypatch):
     here = np.asarray(last[vocab.COUNTER_MOE_TOKENS_HERE])
     assert here.shape == (2, 4) and here.dtype == np.int32
     assert counters[vocab.COUNTER_MOE_TOKENS_HERE] == here.tolist()
+    assert counters[vocab.COUNTER_MOE_PIECES_RUN] == [1, 1]  # a layer
     pairs = np.asarray(last[vocab.COUNTER_DSA_PAIRS])
     assert pairs.shape == (2,) and counters[vocab.COUNTER_DSA_PAIRS] == \
         pairs.tolist()
@@ -393,4 +469,7 @@ def test_fit_publishes_what_the_last_step_selected_and_routed(monkeypatch):
     assert selection[0]["data"]["method"] == "threshold_by_bit_search_xla"
     products = flight.events(kinds=["kernel.grouped_product"])
     assert products and products[0]["data"]["rows"] == 2 * ROWS * SEQ
+    # half of the experts held: the sorted pairs are one piece
+    assert products[0]["data"]["rows_a_piece"] == 2 * ROWS * SEQ
+    assert products[0]["data"]["pieces"] == 1
     assert "attention.dsa_select" in vocab.known_event_kinds()

@@ -168,6 +168,55 @@ def test_no_token_is_dropped_when_all_choose_one_expert():
     assert np.asarray(state["tokens_here"]).tolist() == [0, 0]
 
 
+@pytest.mark.parametrize("held,crowd,ran", [
+    ((2,), 2, 2), ((3,), 2, 1), ((1,), None, None), ((0, 1), 1, 1)])
+def test_one_expert_a_token_walked_in_pieces_gives_the_reference(held, crowd,
+                                                                 ran):
+    """A quarter of the experts held: the 64 sorted tokens are two pieces
+    of 32. All tokens on the expert held (both pieces run), all on one held
+    elsewhere, a router's draw, and half held (one piece, as before):
+    output and every gradient are the float32 reference's."""
+    from deeplearning4j_tpu.nn.layers import moe
+
+    cfg, p, h, carried = sublayer_inputs()
+    if crowd is not None:
+        p = dict(p, bias=jnp.zeros((4,)).at[crowd].set(50.0))
+    p = share_of(p, held)
+    rows_a_piece = moe._piece_rows(ROWS * SEQ, len(held), 4)
+    assert rows_a_piece == (32 if len(held) == 1 else ROWS * SEQ)
+    cut, mm = dict(cfg, experts_held=list(held)), rc.Matmul("float32")
+    weigh = jax.random.normal(jax.random.key(9), h.shape)
+    layer = RoutedExperts(experts_total=4, experts_held=held, units=64,
+                          router_hidden=16)
+
+    def want(p, h):
+        return jnp.sum(weigh * ref.expert_sublayer(cut, mm, h, p, carried)[0])
+
+    def got(p, h):
+        y, state = jax.checkpoint(layer.apply)(
+            p, {"router": carried.reshape(-1, 16)}, h)
+        return jnp.sum(weigh * y), state
+
+    want_loss, want_grads = jax.value_and_grad(want, argnums=(0, 1))(p, h)
+    (got_loss, state), got_grads = jax.value_and_grad(
+        got, argnums=(0, 1), has_aux=True)(p, h)
+    landed = int(np.asarray(state["tokens_here"]).sum())
+    assert int(state["pieces_run"]) == max(1, -(-landed // rows_a_piece))
+    if ran is not None:
+        assert int(state["pieces_run"]) == ran
+    assert float(got_loss) == pytest.approx(float(want_loss), rel=1e-5,
+                                            abs=1e-6)
+    for name in want_grads[0]:
+        if name in ("bias", "norm"):  # no gradient reaches the bias, and
+            continue  # the norm is the model's, outside the layer
+        np.testing.assert_allclose(
+            np.asarray(got_grads[0][name]), np.asarray(want_grads[0][name]),
+            rtol=2e-4, atol=2e-6, err_msg=name)
+    np.testing.assert_allclose(np.asarray(got_grads[1]),
+                               np.asarray(want_grads[1]), rtol=2e-4,
+                               atol=2e-6)
+
+
 def test_the_router_keeps_its_width_when_half_the_experts_are_held():
     layer = RoutedExperts(experts_total=4, experts_held=(1, 3), units=64,
                           router_hidden=16)
@@ -285,6 +334,10 @@ def test_fit_publishes_the_last_steps_expert_load(monkeypatch):
     events = flight.events(kinds=["kernel.grouped_product"])
     assert events and events[0]["data"]["product"] == "jax.lax.ragged_dot"
     assert events[0]["data"]["groups"] == 2
+    # half of the experts held: the sorted tokens are one piece, which ran
+    assert counters[vocab.COUNTER_MOE_PIECES_RUN] == [1, 1]  # a layer
+    assert events[0]["data"]["rows_a_piece"] == ROWS * SEQ
+    assert events[0]["data"]["pieces"] == 1
     assert "kernel.grouped_product" in vocab.known_event_kinds()
 
 
