@@ -25,7 +25,13 @@ order of the additions changes. A per-example key padding mask ([B,S] 1/0
 — the BERT attention-mask case) runs inside the kernel, and so does a
 per-pair mask ([B,T,S] int8, one for all heads of a sequence: the keys a
 learned indexer selected for each query), fetched tile by tile beside k and
-v; only arbitrary additive ``bias`` falls back to the XLA reference. The head dimension is never padded (a block as wide
+v; only arbitrary additive ``bias`` falls back to the XLA reference. A
+``window`` (the last so many keys of a query's past, itself counted) is in
+the plan too: the tiles wholly behind it are dead, the sweep over a query
+block's keys (a key block's queries) starts at its first live block and
+is only as long as the widest run of live ones, and the tile the window's
+far edge crosses corner to corner is swept in sub-blocks as the diagonal's
+mirror image. The head dimension is never padded (a block as wide
 as the array is legal at any width; 8 to 256 were compiled for the v5e),
 and the softmax scale goes on the ``[block_q, D]`` operand, not on the
 scores.
@@ -63,7 +69,13 @@ suffix (0.885 against 0.821 ms); sweeping a grid step's keys tile by tile
 inside the kernel (``fori_loop``, bounds from the plan: 6% off
 ``flash_bwd_dq``, the other two slower); and building ``flash_bwd_dkv``'s
 score tile key-major so that its two transposed products become plain ones
-(1.096 against 1.106 ms: nothing).
+(1.096 against 1.106 ms: nothing). With a window of 4,096 keys at ``[1,
+28, 16384, 128]`` (PR 35; 70 live tiles of 256, 16 diagonal and 12 on the
+edge) the three read 8.10 / 12.55 / 9.64 ms with every key block a grid
+step and 6.83 / 10.85 / 7.88 with the sweeps cut to the window's 5 blocks
+(the 176 dead steps a head cost 0.3 us each); with the edge tile whole
+and not in sub-blocks 8.66 / 11.59 / 8.41; the same call without a window
+14.32 / 22.81 / 17.69.
 
 The same kernels run everywhere: compiled on TPU, interpret-mode in CPU
 tests (via DL4J_TPU_FORCE_PALLAS=1; plain CPU callers never reach them
@@ -77,6 +89,7 @@ import dataclasses
 import functools
 import math
 import operator
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -145,13 +158,14 @@ def _compiler_params(*semantics, pair_mask=False):
 
 
 def reference_attention(q, k, v, *, causal=False, bias=None, key_mask=None,
-                        pair_mask=None, scale=None):
+                        pair_mask=None, scale=None, window=None):
     """XLA O(T²) attention; q [B,H,T,D], k/v [B,H,S,D]. fp32 softmax.
 
     ``key_mask`` [B,S] 1/0 is folded into an additive bias, ``pair_mask``
-    [B,T,S] (nonzero: attend) into the scores. Fully-masked rows produce
-    uniform attention (softmax of constant) — callers never read those
-    outputs.
+    [B,T,S] (nonzero: attend) into the scores. ``window`` (with ``causal``)
+    keeps of each query's past its own position and the ``window - 1``
+    before it. Fully-masked rows produce uniform attention (softmax of
+    constant) — callers never read those outputs.
     """
     d = q.shape[-1]
     scale = (d ** -0.5) if scale is None else scale
@@ -166,7 +180,10 @@ def reference_attention(q, k, v, *, causal=False, bias=None, key_mask=None,
         t_len, s_len = s.shape[-2], s.shape[-1]
         idx_t = jnp.arange(t_len)[:, None]
         idx_s = jnp.arange(s_len)[None, :]
-        s = jnp.where(idx_t + (s_len - t_len) >= idx_s, s, _NEG_INF)
+        seen = idx_t + (s_len - t_len) >= idx_s
+        if window is not None:
+            seen = seen & (idx_t + (s_len - t_len) - idx_s < window)
+        s = jnp.where(seen, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return jnp.einsum("bhts,bhsd->bhtd", p, v)
 
@@ -175,6 +192,19 @@ def reference_attention(q, k, v, *, causal=False, bias=None, key_mask=None,
 # 128, the fastest in all three kernels at head widths 64 and 128 (PERF.md
 # section 5); every sub-block is a copy of the body in the kernel's code.
 _SUB_BLOCK = 256
+
+
+class Part(NamedTuple):
+    """One rectangle of a live tile that a kernel computes: rows ``row`` to
+    ``row + rows`` of the tile against its keys ``key`` to ``key + keys``,
+    and which of the two position terms can be false in it."""
+
+    row: int
+    rows: int
+    key: int
+    keys: int
+    causal: bool
+    window: bool
 
 
 @dataclasses.dataclass(frozen=True)
@@ -187,15 +217,22 @@ class TilePlan:
     dead step should point, the flight event and the tests ask it with
     numbers. Every method takes ints, numpy arrays or traced values.
 
-    A tile is dead when every pair of it lies above the causal diagonal: it
-    is skipped and nothing is fetched for it. Every other tile is live. A
-    live tile is diagonal when the causal diagonal runs from its first
-    corner to its last (square blocks, and its first query row's last
-    visible key is its first key): nearly half of its pairs are dead, so
-    it is computed in ``sub_blocks`` row sub-blocks, each against only the
-    key prefix its rows can see (:meth:`parts`). Where some tile is
-    diagonal, every other live tile lies under the diagonal whole and has
-    no causal term to build.
+    A pair's distance is how far the key lies behind the query, ``i +
+    offset - j``: the causal mask keeps distances from 0 up, a ``window``
+    those under it (the query's own position counts, so ``window`` keys at
+    most). A tile is dead when every pair of it lies above the causal
+    diagonal or at the window's length or further behind: it is skipped
+    and nothing is fetched for it. Every other tile is live. A live tile
+    is diagonal when the causal diagonal runs from its first corner to its
+    last (square blocks, and its first query row's last visible key is its
+    first key): nearly half of its pairs are dead, so it is computed in
+    ``sub_blocks`` row sub-blocks, each against only the keys of the tile
+    its rows can see (:meth:`parts`). A live tile is an edge tile when the
+    window's far edge runs through it corner to corner (a window of whole
+    blocks): the mirror image, its rows see the keys *after* their own
+    place in the tile, and it is swept the same way. Where tiles are split
+    so, every other live tile lies whole between the two edges and has
+    neither term to build.
     """
 
     seq_q: int
@@ -204,6 +241,14 @@ class TilePlan:
     block_k: int
     causal: bool
     pair_mask: bool = False  # the call carries a [B, T, S] mask of pairs
+    window: Optional[int] = None  # keys a query reaches back over, itself
+                                  # counted; only with ``causal``
+
+    def __post_init__(self):
+        if self.window is not None and not (self.causal and self.window > 0):
+            raise ValueError("a window is a positive count of keys under a "
+                             f"causal mask, got window={self.window!r} "
+                             f"causal={self.causal!r}")
 
     @property
     def n_q(self) -> int:
@@ -232,8 +277,9 @@ class TilePlan:
         """Whether a real query row can have no live key at all: under
         causal masking alone only when there are fewer keys than queries
         (otherwise every row's first live block holds key 0). The running
-        state of a row that a key mask or a pair mask has masked whole so
-        far needs the same care, tile by tile (``_flash_kernel``)."""
+        state of a row that a key mask, a pair mask or a window has masked
+        whole so far needs the same care, tile by tile
+        (``_flash_kernel``)."""
         return self.causal and self.seq_k < self.seq_q
 
     @property
@@ -247,33 +293,96 @@ class TilePlan:
             return 1
         return self.block_q // _SUB_BLOCK
 
+    @property
+    def splits_edge(self) -> bool:
+        """Whether the window's far edge crosses tiles corner to corner and
+        those tiles are swept in sub-blocks (10.8% faster than whole under
+        the mask at the cell's shape: PERF.md section 5)."""
+        return (self.window is not None and self.sub_blocks > 1
+                and self.window % self.block_q == 0)
+
+    def _nearest(self, qi, ki):
+        """The least distance of a pair of the (qi, ki) tile."""
+        return qi * self.block_q + self.offset - (ki + 1) * self.block_k + 1
+
+    def _corner(self, qi, ki):
+        """The distance of the (qi, ki) tile's first pair."""
+        return qi * self.block_q + self.offset - ki * self.block_k
+
+    def _behind(self, qi, ki, distance):
+        """Whether ``distance`` is inside the window and the (qi, ki) tile
+        inside the arrays (a windowed sweep's last steps can lie past
+        them)."""
+        return (distance < self.window) & (qi < self.n_q) & (ki < self.n_k)
+
     def live(self, qi, ki):
         if not self.causal:
             return True
-        return (qi + 1) * self.block_q - 1 + self.offset >= ki * self.block_k
+        live = (qi + 1) * self.block_q - 1 + self.offset >= ki * self.block_k
+        if self.window is not None:
+            live = live & self._behind(qi, ki, self._nearest(qi, ki))
+        return live
 
     def diagonal(self, qi, ki):
         """Whether the (qi, ki) tile is run by the split body."""
         if self.sub_blocks == 1:
             return False
-        return qi * self.block_q + self.offset == ki * self.block_k
+        diagonal = qi * self.block_q + self.offset == ki * self.block_k
+        if self.window is not None:
+            diagonal = diagonal & self._behind(qi, ki, 0)
+        return diagonal
+
+    def edge(self, qi, ki):
+        """Whether the (qi, ki) tile is run by the split body's mirror."""
+        if not self.splits_edge:
+            return False
+        return ((self._corner(qi, ki) == self.window)
+                & self._behind(qi, ki, 0))
 
     def whole(self, qi, ki):
         """Whether the (qi, ki) tile is live and computed whole."""
         if self.sub_blocks == 1:
             return self.live(qi, ki)
-        return qi * self.block_q + self.offset > ki * self.block_k
+        under = qi * self.block_q + self.offset > ki * self.block_k
+        if self.window is None:
+            return under
+        return under & self._behind(qi, ki, (
+            self._corner if self.splits_edge else self._nearest)(qi, ki))
 
-    def parts(self, diagonal: bool) -> list:
-        """The rectangles of a live tile that a kernel computes, each as
-        (first row, rows, keys from the tile's first, whether the causal
-        term can be false in it)."""
-        if not diagonal:
-            return [(0, self.block_q, self.block_k,
-                     self.causal and self.sub_blocks == 1)]
+    def parts(self, kind: str = "whole") -> list:
+        """The rectangles (:class:`Part`) of a live tile of ``kind``
+        (``"whole"``, ``"diagonal"`` or ``"edge"``) that a kernel
+        computes. A split tile's corner distance is known while tracing
+        (0 on the diagonal, the window on its edge), and so are the keys
+        of the tile that each row sub-block can see: whole sub-block
+        columns from the first to the last of them."""
+        if kind == "whole":
+            return [Part(0, self.block_q, 0, self.block_k,
+                         self.causal and self.sub_blocks == 1,
+                         self.window is not None and not self.splits_edge)]
+        corner = 0 if kind == "diagonal" else self.window
         size = self.block_q // self.sub_blocks
-        return [(r * size, size, (r + 1) * size, True)
-                for r in range(self.sub_blocks)]
+        reach = math.inf if self.window is None else self.window
+        parts = []
+        for row in range(0, self.block_q, size):
+            first = max(0, corner + row - reach + 1)
+            last = min(self.block_k - 1, corner + row + size - 1)
+            if first > last:
+                continue
+            key = first // size * size
+            end = -(-(last + 1) // size) * size
+            parts.append(Part(row, size, key, end - key,
+                              causal=corner + row - (end - 1) < 0,
+                              window=corner + row + size - 1 - key >= reach))
+        return parts
+
+    def first_live_k(self, qi):
+        """The first live key block of query block ``qi``, inside the
+        array."""
+        if self.window is None:
+            return 0
+        return _xp(qi).clip((qi * self.block_q + self.offset + 1
+                             - self.window) // self.block_k, 0, self.n_k - 1)
 
     def last_live_k(self, qi):
         """The last live key block of query block ``qi``, inside the array."""
@@ -289,19 +398,64 @@ class TilePlan:
         return _xp(ki).clip((ki * self.block_k - self.offset) // self.block_q,
                             0, self.n_q - 1)
 
+    def last_live_q(self, ki):
+        """The last live query block of key block ``ki``, inside the
+        array."""
+        if self.window is None:
+            return self.n_q - 1
+        return _xp(ki).clip(((ki + 1) * self.block_k - 2 + self.window
+                             - self.offset) // self.block_q, 0, self.n_q - 1)
+
+    @property
+    def steps_k(self) -> int:
+        """Grid steps of a query block's sweep over the keys (``flash_fwd``,
+        ``flash_bwd_dq``): every key block, or under a window only as many
+        as the widest run of live blocks of any query block, from its
+        first live one (:meth:`key_of_step`)."""
+        if self.window is None:
+            return self.n_k
+        qi = np.arange(self.n_q)
+        return int((self.last_live_k(qi) - self.first_live_k(qi)).max()) + 1
+
+    @property
+    def steps_q(self) -> int:
+        """The same for a key block's sweep over the queries
+        (``flash_bwd_dkv``)."""
+        if self.window is None:
+            return self.n_q
+        ki = np.arange(self.n_k)
+        return int((self.last_live_q(ki) - self.first_live_q(ki)).max()) + 1
+
+    def key_of_step(self, qi, step):
+        """The key block of step ``step`` of query block ``qi``'s sweep;
+        past the last key block where the sweep has run out of them."""
+        return step if self.window is None else self.first_live_k(qi) + step
+
+    def query_of_step(self, ki, step):
+        """The query block of step ``step`` of key block ``ki``'s sweep."""
+        return step if self.window is None else self.first_live_q(ki) + step
+
     def fetch_k(self, qi, ki):
         """The key block a (qi, ki) grid step fetches: its own while live,
-        the row's last live one after it, so a dead step copies nothing."""
+        the row's first live one before that and its last live one after,
+        so a dead step copies nothing."""
         if not self.causal:
             return ki
-        return _xp(qi, ki).minimum(ki, self.last_live_k(qi))
+        fetched = _xp(qi, ki).minimum(ki, self.last_live_k(qi))
+        if self.window is not None:
+            fetched = _xp(qi, ki).maximum(fetched, self.first_live_k(qi))
+        return fetched
 
     def fetch_q(self, qi, ki):
         """The same for the key-major sweep of ``flash_bwd_dkv``: dead steps
-        come first there and point at the column's first live block."""
+        before a column's live ones point at its first live block, those
+        after them at its last."""
         if not self.causal:
             return qi
-        return _xp(qi, ki).maximum(qi, self.first_live_q(ki))
+        fetched = _xp(qi, ki).maximum(qi, self.first_live_q(ki))
+        if self.window is not None:
+            fetched = _xp(qi, ki).minimum(fetched, self.last_live_q(ki))
+        return fetched
 
     def _of_every_tile(self, answer) -> np.ndarray:
         qi = np.arange(self.n_q)[:, None]
@@ -316,21 +470,44 @@ class TilePlan:
         """``[n_q, n_k]`` of bool."""
         return self._of_every_tile(self.diagonal)
 
+    def edge_tiles(self) -> np.ndarray:
+        """``[n_q, n_k]`` of bool."""
+        return self._of_every_tile(self.edge)
+
+    def pairs_required(self) -> int:
+        """The pairs the mask of the shapes leaves."""
+        if not self.causal:
+            return self.seq_q * self.seq_k
+        last = np.arange(self.seq_q) + self.offset  # each row's last key
+        first = (np.zeros_like(last) if self.window is None
+                 else last - self.window + 1)
+        return int(np.clip(np.minimum(last, self.seq_k - 1)
+                           - np.maximum(first, 0) + 1, 0, None).sum())
+
+    def pairs_touched(self) -> int:
+        """The pairs a kernel computes, a split tile's sub-blocks counted
+        as they run (padding counts as computed)."""
+        split = {"diagonal": int(self.diagonal_tiles().sum()),
+                 "edge": int(self.edge_tiles().sum())}
+        tiles = dict(split, whole=int(self.live_tiles().sum())
+                     - sum(split.values()))
+        return sum(n * p.rows * p.keys for kind, n in tiles.items() if n
+                   for p in self.parts(kind))
+
     def counts(self) -> dict:
         """What the flight event says of a kernel: its tiles by kind, and
         the pairs it computes over the pairs the mask of the shapes leaves
         (padding counts as computed, not as required)."""
         live = int(self.live_tiles().sum())
-        diagonal = int(self.diagonal_tiles().sum())
-        touched = ((live - diagonal) * self.block_q * self.block_k
-                   + diagonal * sum(rows * keys for _, rows, keys, _
-                                    in self.parts(True)))
-        required = int(np.clip(np.arange(self.seq_q) + self.offset + 1, 0,
-                               self.seq_k).sum()
-                       ) if self.causal else self.seq_q * self.seq_k
-        return {"dead": self.n_q * self.n_k - live, "live": live,
-                "diagonal": diagonal, "sub_blocks": self.sub_blocks,
-                "pairs_touched_over_required": round(touched / required, 4)}
+        return {"dead": self.n_q * self.n_k - live,
+                # of the dead tiles, those a query-major sweep still steps
+                # over (under a window it starts at its first live block)
+                "dead_steps": self.n_q * self.steps_k - live, "live": live,
+                "diagonal": int(self.diagonal_tiles().sum()),
+                "edge": int(self.edge_tiles().sum()),
+                "sub_blocks": self.sub_blocks,
+                "pairs_touched_over_required": round(
+                    self.pairs_touched() / self.pairs_required(), 4)}
 
 
 def _xp(*xs):
@@ -346,46 +523,62 @@ def _when(condition):
     return (lambda body: body()) if condition else (lambda body: None)
 
 
-def _grid_ids(plan, q_axis, k_axis):
-    """(qi, ki) of a grid step; 0 and not traced along an axis of one tile."""
-    return (pl.program_id(q_axis) if plan.n_q > 1 else 0,
-            pl.program_id(k_axis) if plan.n_k > 1 else 0)
+def _grid_ids(plan, q_axis, k_axis, key_major=False):
+    """(qi, ki, the step of the inner sweep) of a grid step; a number and
+    not traced along an axis of one step. The inner sweep is over the keys
+    of a query block, or (``key_major``) over the queries of a key block,
+    and under a window starts at the block's first live one."""
+    if key_major:
+        ki = pl.program_id(k_axis) if plan.n_k > 1 else 0
+        step = pl.program_id(q_axis) if plan.steps_q > 1 else 0
+        return plan.query_of_step(ki, step), ki, step
+    qi = pl.program_id(q_axis) if plan.n_q > 1 else 0
+    step = pl.program_id(k_axis) if plan.steps_k > 1 else 0
+    return qi, plan.key_of_step(qi, step), step
 
 
 def _on_live_tile(plan, qi, ki, body):
-    """Run ``body(first_row, rows, keys, causal)`` on each rectangle that
-    the (qi, ki) step has to compute: a diagonal tile's sub-blocks, any
-    other live tile whole, a dead tile not at all."""
-    for diagonal, here in ((True, plan.diagonal(qi, ki)),
-                           (False, plan.whole(qi, ki))):
+    """Run ``body(part)`` on each rectangle (:class:`Part`) that the
+    (qi, ki) step has to compute: a diagonal or an edge tile's sub-blocks,
+    any other live tile whole, a dead tile not at all."""
+    for kind, here in (("diagonal", plan.diagonal(qi, ki)),
+                       ("whole", plan.whole(qi, ki)),
+                       ("edge", plan.edge(qi, ki))):
         @_when(here)
         def _parts():
-            for part in plan.parts(diagonal):
-                body(*part)
+            for part in plan.parts(kind):
+                body(part)
 
 
-def _tile_mask(plan, qi, ki, km_ref, pm_ref, first_row, rows, keys, causal):
+def _tile_mask(plan, qi, ki, km_ref, pm_ref, part):
     """The mask of a rectangle of a live tile: key padding, the per-example
     key mask, the per-pair mask, query padding (the backward's padded rows
-    carry no residuals) and the causal triangle; each term only where the
-    shapes can make it false, and None where none can."""
-    shape = (rows, keys)
-    key_idx = ki * plan.block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    carry no residuals), the causal triangle and the window's far edge;
+    each term only where the shapes can make it false, and None where none
+    can."""
+    shape = (part.rows, part.keys)
+    rows = slice(part.row, part.row + part.rows)
+    keys = slice(part.key, part.key + part.keys)
+    first_key = ki * plan.block_k
+    if part.key:
+        first_key = first_key + part.key
+    key_idx = first_key + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     terms = []
     if plan.pads_k:
         terms.append(key_idx < plan.seq_k)
     if km_ref is not None:  # [1, keys] broadcasts over rows
-        terms.append(km_ref[0, :, :keys] > 0)
+        terms.append(km_ref[0, :, keys] > 0)
     if pm_ref is not None:  # [block_q, block_k] of int8, the tile's own
-        terms.append(pm_ref[0, first_row:first_row + rows, :keys]
-                     .astype(jnp.int32) != 0)
-    if causal or plan.pads_q:
-        query_idx = (qi * plan.block_q + first_row
+        terms.append(pm_ref[0, rows, keys].astype(jnp.int32) != 0)
+    if part.causal or part.window or plan.pads_q:
+        query_idx = (qi * plan.block_q + part.row
                      + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
         if plan.pads_q:
             terms.append(query_idx < plan.seq_q)
-        if causal:
+        if part.causal:
             terms.append(query_idx + plan.offset >= key_idx)
+        if part.window:
+            terms.append(query_idx + plan.offset - key_idx < plan.window)
     if not terms:
         return None
     return jnp.broadcast_to(functools.reduce(operator.and_, terms), shape)
@@ -407,24 +600,24 @@ def _lanes(x, width):
 
 def _flash_kernel(q_ref, k_ref, v_ref, km_ref, pm_ref, o_ref, lse_ref, m_scr,
                   l_scr, acc_scr, *, scale, plan):
-    qi, ki = _grid_ids(plan, 1, 2)
+    qi, ki, step = _grid_ids(plan, 1, 2)
 
-    @_when(ki == 0)
+    @_when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _compute(first_row, n_rows, n_keys, causal):
-        rows = slice(first_row, first_row + n_rows)
+    def _compute(part):
+        rows = slice(part.row, part.row + part.rows)
+        keys = slice(part.key, part.key + part.keys)
         mm = _matmul_dtype(q_ref.dtype)
         s = jax.lax.dot_general(
             _scaled(q_ref[0, rows, :], scale, mm),
-            k_ref[0, :n_keys, :].astype(mm),
+            k_ref[0, keys, :].astype(mm),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [rows, keys]
-        mask = _tile_mask(plan, qi, ki, km_ref, pm_ref, first_row, n_rows,
-                          n_keys, causal)
+        mask = _tile_mask(plan, qi, ki, km_ref, pm_ref, part)
         if mask is not None:
             s = jnp.where(mask, s, _NEG_INF)
         # The running maximum and sum stay the lane-broadcast [rows, 128]
@@ -432,11 +625,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, km_ref, pm_ref, o_ref, lse_ref, m_scr,
         # broadcast each, which a diagonal tile's sub-blocks do not repay.
         m_prev = m_scr[rows, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - _lanes(m_new, n_keys))
+        p = jnp.exp(s - _lanes(m_new, part.keys))
         if mask is not None and (km_ref is not None or pm_ref is not None
-                                 or plan.rows_can_be_empty):
+                                 or plan.rows_can_be_empty or part.window):
             # A row masked whole so far keeps m_new at _NEG_INF, and
-            # exp(s - m_new) would be 1 there, not 0.
+            # exp(s - m_new) would be 1 there, not 0. (Under a window a
+            # row's first live tile is the edge's, of which its last rows
+            # see nothing.)
             p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[rows, :] = (l_scr[rows, :] * alpha
@@ -444,13 +639,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, km_ref, pm_ref, o_ref, lse_ref, m_scr,
         acc_scr[rows, :] = (
             acc_scr[rows, :] * _lanes(alpha, acc_scr.shape[1])
             + jax.lax.dot_general(
-                p.astype(mm), v_ref[0, :n_keys, :].astype(mm),
+                p.astype(mm), v_ref[0, keys, :].astype(mm),
                 (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32))
         m_scr[rows, :] = m_new
 
     _on_live_tile(plan, qi, ki, _compute)
 
-    @_when(ki == plan.n_k - 1)
+    @_when(step == plan.steps_k - 1)
     def _finish():
         # Fully-masked rows: l == 0 → output 0 (callers never read them).
         l = jnp.maximum(l_scr[:], 1e-30)
@@ -505,18 +700,22 @@ def _pair_mask_tiles(pair_mask, block_q, block_k):
 
 
 def _flash_fwd(q, k, v, key_mask, pair_mask, *, causal, scale, block_q,
-               block_k, save_lse=False):
+               block_k, window=None, save_lse=False):
     b, h, t, d = q.shape
     s_len = k.shape[2]
-    plan = TilePlan(t, s_len, block_q, block_k, causal, pair_mask is not None)
+    plan = TilePlan(t, s_len, block_q, block_k, causal, pair_mask is not None,
+                    window)
     qp, kp, vp = _rows(q, block_q), _rows(k, block_k), _rows(v, block_k)
     tq = qp.shape[1]
 
-    def q_index(bh, qi, ki):
+    def q_index(bh, qi, step):
         return (bh, qi, 0)
 
-    def kv_index(bh, qi, ki):
-        return (bh, plan.fetch_k(qi, ki), 0)
+    def fetched_k(qi, step):
+        return plan.fetch_k(qi, plan.key_of_step(qi, step))
+
+    def kv_index(bh, qi, step):
+        return (bh, fetched_k(qi, step), 0)
 
     operands = [qp, kp, vp]
     in_specs = [pl.BlockSpec((1, block_q, d), q_index),
@@ -525,12 +724,12 @@ def _flash_fwd(q, k, v, key_mask, pair_mask, *, causal, scale, block_q,
     if key_mask is not None:
         operands.append(_key_mask_rows(key_mask, h, block_k))
         in_specs.append(pl.BlockSpec(
-            (1, 1, block_k), lambda bh, qi, ki: (bh, 0, plan.fetch_k(qi, ki))))
+            (1, 1, block_k), lambda bh, qi, step: (bh, 0, fetched_k(qi, step))))
     if pair_mask is not None:
         operands.append(_pair_mask_tiles(pair_mask, block_q, block_k))
         in_specs.append(pl.BlockSpec(
             (1, block_q, block_k),
-            lambda bh, qi, ki: (bh // h, qi, plan.fetch_k(qi, ki))))
+            lambda bh, qi, step: (bh // h, qi, fetched_k(qi, step))))
     out_specs = [pl.BlockSpec((1, block_q, d), q_index)]
     out_shape = [jax.ShapeDtypeStruct((b * h, tq, d), q.dtype)]
     if save_lse:
@@ -548,7 +747,7 @@ def _flash_fwd(q, k, v, key_mask, pair_mask, *, causal, scale, block_q,
 
     res = pl.pallas_call(
         kernel,
-        grid=(b * h, plan.n_q, plan.n_k),
+        grid=(b * h, plan.n_q, plan.steps_k),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -567,16 +766,16 @@ def _flash_fwd(q, k, v, key_mask, pair_mask, *, causal, scale, block_q,
 
 
 def _bwd_recompute(q_ref, k_ref, v_ref, km_ref, pm_ref, g_ref, lse_ref,
-                   delta_ref, qi, ki, first_row, n_rows, n_keys, causal, *,
-                   scale, plan):
+                   delta_ref, qi, ki, part, *, scale, plan):
     """Recompute p and ds for one rectangle of a (q-block, kv-block) pair —
     the math both backward kernels share. Returns (q, k, g, p, ds) in the
     MXU compute dtype (see _matmul_dtype); ds lacks the softmax scale, which
     each kernel puts on its ``[*, D]`` accumulator at the end."""
-    rows = slice(first_row, first_row + n_rows)
+    rows = slice(part.row, part.row + part.rows)
+    keys = slice(part.key, part.key + part.keys)
     mm = _matmul_dtype(q_ref.dtype)
     q = q_ref[0, rows, :]
-    k = k_ref[0, :n_keys, :].astype(mm)
+    k = k_ref[0, keys, :].astype(mm)
     g = g_ref[0, rows, :].astype(mm)
     # Clamp: fully-masked rows carry lse ≈ -1e30; their scores are -1e30
     # too, so the clamped difference underflows exp to exactly 0 (no
@@ -587,13 +786,12 @@ def _bwd_recompute(q_ref, k_ref, v_ref, km_ref, pm_ref, g_ref, lse_ref,
     s = jax.lax.dot_general(
         _scaled(q, scale, mm), k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    mask = _tile_mask(plan, qi, ki, km_ref, pm_ref, first_row, n_rows, n_keys,
-                      causal)
+    mask = _tile_mask(plan, qi, ki, km_ref, pm_ref, part)
     if mask is not None:
         s = jnp.where(mask, s, _NEG_INF)
     p = jnp.exp(s - lse)  # [rows, keys]; exactly 0 where masked
     dp = jax.lax.dot_general(
-        g, v_ref[0, :n_keys, :].astype(mm), (((1,), (1,)), ((), ())),
+        g, v_ref[0, keys, :].astype(mm), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     ds = p * (dp - delta)
     # p/ds feed straight into MXU matmuls at the call sites — hand them
@@ -604,28 +802,29 @@ def _bwd_recompute(q_ref, k_ref, v_ref, km_ref, pm_ref, g_ref, lse_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, km_ref, pm_ref, g_ref, lse_ref,
                           delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                           scale, plan):
-    qi, ki = _grid_ids(plan, 2, 1)
+    qi, ki, step = _grid_ids(plan, 2, 1, key_major=True)
 
-    @_when(qi == 0)
+    @_when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _compute(first_row, n_rows, n_keys, causal):
+    def _compute(part):
         q, _, g, p, ds = _bwd_recompute(
             q_ref, k_ref, v_ref, km_ref, pm_ref, g_ref, lse_ref, delta_ref,
-            qi, ki, first_row, n_rows, n_keys, causal, scale=scale, plan=plan)
-        dv_scr[:n_keys, :] += jax.lax.dot_general(
+            qi, ki, part, scale=scale, plan=plan)
+        keys = slice(part.key, part.key + part.keys)
+        dv_scr[keys, :] += jax.lax.dot_general(
             p, g, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        dk_scr[:n_keys, :] += jax.lax.dot_general(
+        dk_scr[keys, :] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32
         )
 
     _on_live_tile(plan, qi, ki, _compute)
 
-    @_when(qi == plan.n_q - 1)
+    @_when(step == plan.steps_q - 1)
     def _finish():
         dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -633,40 +832,43 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, km_ref, pm_ref, g_ref, lse_ref,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, km_ref, pm_ref, g_ref, lse_ref,
                          delta_ref, dq_ref, dq_scr, *, scale, plan):
-    qi, ki = _grid_ids(plan, 1, 2)
+    qi, ki, step = _grid_ids(plan, 1, 2)
 
-    @_when(ki == 0)
+    @_when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def _compute(first_row, n_rows, n_keys, causal):
+    def _compute(part):
         _, k, _, _, ds = _bwd_recompute(
             q_ref, k_ref, v_ref, km_ref, pm_ref, g_ref, lse_ref, delta_ref,
-            qi, ki, first_row, n_rows, n_keys, causal, scale=scale, plan=plan)
-        rows = slice(first_row, first_row + n_rows)
+            qi, ki, part, scale=scale, plan=plan)
+        rows = slice(part.row, part.row + part.rows)
         dq_scr[rows, :] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
     _on_live_tile(plan, qi, ki, _compute)
 
-    @_when(ki == plan.n_k - 1)
+    @_when(step == plan.steps_k - 1)
     def _finish():
         dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_call(kernel_fn, q, k, v, key_mask, pair_mask, g, lse, delta, *,
-                    causal, scale, block_q, block_k, key_major):
+                    causal, scale, window, block_q, block_k, key_major):
     """One backward kernel at its own geometry, as (kernel, the keyword
     arguments of its ``pallas_call``, operands). ``key_major``: the grid is
     (bh, ki, qi), the query sweep innermost (``flash_bwd_dkv``); else
     (bh, qi, ki) (``flash_bwd_dq``). The outputs come back padded."""
     b, h, t, d = q.shape
     s_len = k.shape[2]
-    plan = TilePlan(t, s_len, block_q, block_k, causal, pair_mask is not None)
+    plan = TilePlan(t, s_len, block_q, block_k, causal, pair_mask is not None,
+                    window)
 
-    def ids(bh, i, j):
-        return (bh, j, i) if key_major else (bh, i, j)  # -> (bh, qi, ki)
+    def ids(bh, outer, step):  # -> (bh, qi, ki)
+        if key_major:
+            return bh, plan.query_of_step(outer, step), outer
+        return bh, outer, plan.key_of_step(outer, step)
 
     def q_index(*grid):
         bh, qi, ki = ids(*grid)
@@ -702,13 +904,13 @@ def _flash_bwd_call(kernel_fn, q, k, v, key_mask, pair_mask, g, lse, delta, *,
     tq, tk = operands[0].shape[1], operands[1].shape[1]
 
     if key_major:
-        grid = (b * h, plan.n_k, plan.n_q)
+        grid = (b * h, plan.n_k, plan.steps_q)
         out_specs = [kv_spec, kv_spec]
         out_shape = [jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
                      jax.ShapeDtypeStruct((b * h, tk, d), v.dtype)]
         scratch = [pltpu.VMEM((block_k, d), jnp.float32)] * 2
     else:
-        grid = (b * h, plan.n_q, plan.n_k)
+        grid = (b * h, plan.n_q, plan.steps_k)
         out_specs = [q_spec]
         out_shape = [jax.ShapeDtypeStruct((b * h, tq, d), q.dtype)]
         scratch = [pltpu.VMEM((block_q, d), jnp.float32)]
@@ -733,7 +935,7 @@ def _flash_bwd_call(kernel_fn, q, k, v, key_mask, pair_mask, g, lse, delta, *,
 
 
 def _flash_bwd_impl(q, k, v, key_mask, pair_mask, out, lse, g, *, causal,
-                    scale, blocks):
+                    scale, blocks, window):
     """Blockwise backward: two kernels, each at its own geometry."""
     b, h, t, d = q.shape
     s_len = k.shape[2]
@@ -743,7 +945,7 @@ def _flash_bwd_impl(q, k, v, key_mask, pair_mask, out, lse, g, *, causal,
     args = (q, k, v, key_mask, pair_mask, g, lse, delta)
     kernel, call, operands = _flash_bwd_call(
         _flash_bwd_dkv_kernel, *args, causal=causal, scale=scale,
-        block_q=blocks.dkv[0], block_k=blocks.dkv[1], key_major=True)
+        window=window, block_q=blocks.dkv[0], block_k=blocks.dkv[1], key_major=True)
     dk, dv = pl.pallas_call(
         kernel,
         name="flash_bwd_dkv",
@@ -751,7 +953,7 @@ def _flash_bwd_impl(q, k, v, key_mask, pair_mask, out, lse, g, *, causal,
     )(*operands)
     kernel, call, operands = _flash_bwd_call(
         _flash_bwd_dq_kernel, *args, causal=causal, scale=scale,
-        block_q=blocks.dq[0], block_k=blocks.dq[1], key_major=False)
+        window=window, block_q=blocks.dq[0], block_k=blocks.dq[1], key_major=False)
     dq, = pl.pallas_call(
         kernel,
         name="flash_bwd_dq",
@@ -762,27 +964,28 @@ def _flash_bwd_impl(q, k, v, key_mask, pair_mask, out, lse, g, *, causal,
             dv[:, :s_len].reshape(b, h, s_len, d))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q, k, v, key_mask, causal, scale, blocks, pair_mask=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash(q, k, v, key_mask, causal, scale, blocks, window, pair_mask=None):
     """``blocks``: a ``FlashBlocks`` already clamped to the shapes."""
     out, _ = _flash_fwd(q, k, v, key_mask, pair_mask, causal=causal,
-                        scale=scale, block_q=blocks.fwd[0],
+                        scale=scale, window=window, block_q=blocks.fwd[0],
                         block_k=blocks.fwd[1])
     return out
 
 
-def _flash_vjp_fwd(q, k, v, key_mask, causal, scale, blocks, pair_mask):
+def _flash_vjp_fwd(q, k, v, key_mask, causal, scale, blocks, window,
+                   pair_mask):
     out, lse = _flash_fwd(q, k, v, key_mask, pair_mask, causal=causal,
-                          scale=scale, block_q=blocks.fwd[0],
+                          scale=scale, window=window, block_q=blocks.fwd[0],
                           block_k=blocks.fwd[1], save_lse=True)
     return out, (q, k, v, key_mask, pair_mask, out, lse)
 
 
-def _flash_vjp_bwd(causal, scale, blocks, res, g):
+def _flash_vjp_bwd(causal, scale, blocks, window, res, g):
     q, k, v, key_mask, pair_mask, out, lse = res
     dq, dk, dv = _flash_bwd_impl(
         q, k, v, key_mask, pair_mask, out, lse, g,
-        causal=causal, scale=scale, blocks=blocks,
+        causal=causal, scale=scale, blocks=blocks, window=window,
     )
     dkm = jnp.zeros_like(key_mask) if key_mask is not None else None
     dpm = (np.zeros(pair_mask.shape, jax.dtypes.float0)  # integers: no
@@ -800,8 +1003,8 @@ def _how_traced():
             _matmul_dtype(jnp.bfloat16))
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
-def _flash_traced_once(q, k, v, key_mask, causal, scale, blocks, how,
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def _flash_traced_once(q, k, v, key_mask, causal, scale, blocks, how, window,
                        pair_mask=None):
     """``_flash`` behind a ``jax.jit`` of its own: the layers of a model
     trace and lower each kernel once, not once a layer. A diagonal tile's
@@ -810,10 +1013,11 @@ def _flash_traced_once(q, k, v, key_mask, causal, scale, blocks, how,
     (PERF.md section 6, PR 32). ``how`` (:func:`_how_traced`) only keys the
     cache of traces."""
     del how
-    return _flash(q, k, v, key_mask, causal, scale, blocks, pair_mask)
+    return _flash(q, k, v, key_mask, causal, scale, blocks, window, pair_mask)
 
 
-def _flash_on_mesh(mesh, q, k, v, key_mask, pair_mask, causal, scale, blocks):
+def _flash_on_mesh(mesh, q, k, v, key_mask, pair_mask, causal, scale, blocks,
+                   window):
     """The kernel under a multi-device mesh: inside ``shard_map``, batch
     split over the data-like axes and heads over the model axis (attention
     is independent across both, so no collective is needed); a dimension
@@ -842,22 +1046,28 @@ def _flash_on_mesh(mesh, q, k, v, key_mask, pair_mask, causal, scale, blocks):
         masks = list(masks)
         km = masks.pop(0) if key_mask is not None else None
         pm = masks.pop(0) if pair_mask is not None else None
-        return _flash_traced_once(q, k, v, km, causal, scale, blocks, how, pm)
+        return _flash_traced_once(q, k, v, km, causal, scale, blocks, how,
+                                  window, pm)
 
     return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
                          out_specs=qkv_spec, check_vma=False)(*args)
 
 
 def flash_attention(q, k, v, *, causal: bool = False, scale=None, bias=None,
-                    key_mask=None, pair_mask=None, block_q: int = None,
-                    block_k: int = None, backend: str = None):
+                    key_mask=None, pair_mask=None, window: int = None,
+                    block_q: int = None, block_k: int = None,
+                    backend: str = None):
     """Blockwise attention; q [B,H,T,D], k/v [B,H,S,D] → [B,H,T,D].
 
     ``key_mask`` [B,S] 1/0 (padding mask) runs inside the kernel — the
     BERT path keeps the flash fast path. ``pair_mask`` [B,T,S] of int8
     (nonzero: the query attends to the key; one mask for all heads of a
-    sequence) runs inside it too; no gradient reaches it. Arbitrary
-    additive ``bias`` forces the XLA fallback.
+    sequence) runs inside it too; no gradient reaches it. ``window``
+    (with ``causal``): a query attends to its own position and the
+    ``window - 1`` before it and nothing older; the kernels skip the tiles
+    that lie wholly behind it as they skip those above the diagonal
+    (:class:`TilePlan`), and a window no shorter than the keys is no
+    window. Arbitrary additive ``bias`` forces the XLA fallback.
 
     ``backend``: None (auto), 'pallas', or 'xla'. Auto dispatch picks XLA's
     fused attention below ``_dispatch.flash_min_seq()`` keys, off-TPU, for
@@ -868,35 +1078,56 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None, bias=None,
     """
     d = q.shape[-1]
     scale = (d ** -0.5) if scale is None else scale
+    window = _window_of_call(window, causal, k.shape[2])
+    backend = _backend_of(q.shape[2], k.shape[2], bias is not None, backend)
+    if backend == "xla":
+        return reference_attention(q, k, v, causal=causal, bias=bias,
+                                   key_mask=key_mask, pair_mask=pair_mask,
+                                   scale=scale, window=window)
+    t, s_len = q.shape[2], k.shape[2]
+    blocks = _call_blocks(t, s_len, d, causal, block_q, block_k)
+    _record_plan(t, s_len, d, causal, key_mask is not None,
+                 pair_mask is not None, window, blocks)
+    mesh = _active_kernel_mesh()
+    if mesh is not None:
+        return _flash_on_mesh(mesh, q, k, v, key_mask, pair_mask, causal,
+                              scale, blocks, window)
+    return _flash_traced_once(q, k, v, key_mask, causal, scale, blocks,
+                              _how_traced(), window, pair_mask)
+
+
+def _window_of_call(window, causal, seq_k):
+    """A call's window as the plans take it: None where it is no shorter
+    than the keys (every key of a query's past is then inside it)."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError("window counts the keys a query reaches back over "
+                         "under a causal mask, itself included; got "
+                         f"window={window!r} with causal={causal!r}")
+    return None if window >= seq_k else window
+
+
+def _backend_of(seq_q, seq_k, biased, backend=None):
+    """``"pallas"`` or ``"xla"`` for a call of these shapes: the caller's
+    choice where it made one and it can be honoured, else the dispatch
+    rule of :func:`flash_attention`."""
     if backend not in (None, "pallas", "xla"):
         raise ValueError(f"backend must be None|'pallas'|'xla', got {backend!r}")
-    can_pallas = bias is None and q.shape[2] >= 8 and _use_pallas()
+    can_pallas = not biased and seq_q >= 8 and _use_pallas()
     if backend == "pallas" and not can_pallas:
         # an explicit request is a contract: a caller that asked for the
         # kernel must never be handed XLA without a word
         raise ValueError(
             "backend='pallas' cannot be honoured: "
             + ("additive bias is not supported by the kernel"
-               if bias is not None else
-               f"query length {q.shape[2]} < 8" if q.shape[2] < 8 else
+               if biased else
+               f"query length {seq_q} < 8" if seq_q < 8 else
                "not on TPU and DL4J_TPU_FORCE_PALLAS is not set"))
     if backend is None:
         backend = "pallas" if can_pallas and (
-            _force_pallas() or k.shape[2] >= _flash_min_seq()) else "xla"
-    if backend == "xla":
-        return reference_attention(q, k, v, causal=causal, bias=bias,
-                                   key_mask=key_mask, pair_mask=pair_mask,
-                                   scale=scale)
-    t, s_len = q.shape[2], k.shape[2]
-    blocks = _call_blocks(t, s_len, d, causal, block_q, block_k)
-    _record_plan(t, s_len, d, causal, key_mask is not None,
-                 pair_mask is not None, blocks)
-    mesh = _active_kernel_mesh()
-    if mesh is not None:
-        return _flash_on_mesh(mesh, q, k, v, key_mask, pair_mask, causal,
-                              scale, blocks)
-    return _flash_traced_once(q, k, v, key_mask, causal, scale, blocks,
-                              _how_traced(), pair_mask)
+            _force_pallas() or seq_k >= _flash_min_seq()) else "xla"
+    return backend
 
 
 def _call_blocks(seq_q, seq_k, head_dim, causal, block_q=None, block_k=None):
@@ -914,18 +1145,36 @@ def forward_plan(seq_q, seq_k, head_dim, *, causal, pair_mask=False):
     return TilePlan(seq_q, seq_k, *blocks.fwd, causal, pair_mask)
 
 
-def _record_plan(seq_q, seq_k, head_dim, causal, has_mask, has_pairs, blocks):
+def pairs_of_call(seq_q, seq_k, head_dim, *, causal, window=None):
+    """Of one head of a call of these shapes left to the dispatch rule and
+    the default geometry: the query-key pairs its mask and window require,
+    and the pairs that what runs computes: a flash kernel's tile plan with
+    a split tile's sub-blocks counted as they run (the three kernels'
+    mean), every pair where XLA's attention runs."""
+    window = _window_of_call(window, causal, seq_k)
+    plans = [TilePlan(seq_q, seq_k, bq, bk, causal, window=window)
+             for bq, bk in _call_blocks(seq_q, seq_k, head_dim, causal)]
+    required = plans[0].pairs_required()
+    if _backend_of(seq_q, seq_k, False) == "xla":
+        return required, seq_q * seq_k
+    return required, sum(p.pairs_touched() for p in plans) // len(plans)
+
+
+def _record_plan(seq_q, seq_k, head_dim, causal, has_mask, has_pairs, window,
+                 blocks):
     """One ``kernel.flash_plan`` flight event per call, at trace time (a
     jitted step traces its calls once, so this costs a run nothing): the
     geometry of each kernel, how many of its tiles are dead (skipped,
-    nothing fetched), live and, of the live, diagonal (computed in
-    ``sub_blocks`` row sub-blocks), and the pairs it computes over the
-    pairs the call's mask leaves (``TilePlan.counts``)."""
+    nothing fetched), live and, of the live, diagonal or on the window's
+    edge (computed in ``sub_blocks`` row sub-blocks), and the pairs it
+    computes over the pairs the call's mask and window leave
+    (``TilePlan.counts``)."""
     from deeplearning4j_tpu.observability.flightrecorder import record_event
 
     record_event(
         "kernel.flash_plan", seq_q=seq_q, seq_k=seq_k, head_dim=head_dim,
-        causal=causal, key_mask=has_mask, pair_mask=has_pairs,
+        causal=causal, key_mask=has_mask, pair_mask=has_pairs, window=window,
         **{name: {"block_q": bq, "block_k": bk,
-                  **TilePlan(seq_q, seq_k, bq, bk, causal).counts()}
+                  **TilePlan(seq_q, seq_k, bq, bk, causal,
+                             window=window).counts()}
            for name, (bq, bk) in blocks._asdict().items()})

@@ -1,36 +1,35 @@
-"""Decoder-only language model of Keye-VL-2.0-30B-A3B (Kwai-Keye), for
-training.
+"""Decoder-only language model of SmallThinker-21BA3B-Instruct
+(PowerInfer), for training.
 
-Every layer is two pre-norm sub-layers, each ``x <- x + f(RMSNorm(x))``:
-grouped-query attention over the keys a learned indexer selects for each
-query (``nn.layers.attention.indexed_attention``: per-head q-k RMSNorm,
-full rotary positions, the ``index_top_k`` best-scored keys of each
-query's past, one set for all heads) and then ``experts_per_token`` of
-``experts_total`` SiLU-gated experts behind a linear router with the chosen
-weights renormalised (``nn.layers.moe.RoutedExperts``, told which of the
-experts it holds). No biases; the head is a matrix of its own, not the
-embedding. The vocabulary given is the slice held here: ids, logits and
-loss are over it. A tree of leaves a layer (``params["layer_<i>"]``), as
-``Zaya`` keeps them.
+Every layer is a router and two pre-norm sub-layers. The router comes
+first and reads the layer's input as it arrives, before any norm: it
+chooses ``experts_per_token`` of ``experts_total`` experts for each token
+and weighs them by the softmax over the chosen logits. Then
+``x <- x + attention(RMSNorm(x))``: grouped-query attention
+(``nn.layers.attention.grouped_query_attention``) whose kind the layer's
+place decides, by two lists of the config: where ``rope_layout[l]`` is 1
+the queries and keys carry rotary positions, where 0 the layer sees no
+positions at all; where ``sliding_window_layout[l]`` is 1 a query reaches
+back over its own position and the ``sliding_window - 1`` before it, where
+0 over everything before it. Then ``x <- x + experts(RMSNorm(x))``: the
+ReLU-gated experts the router chose (``nn.layers.moe.RoutedExperts``, told
+which of the experts it holds, handed the router's rows apart from its
+own). No biases, no q-k norm, no shared expert; the head is a matrix of
+its own, not the embedding. The vocabulary given is the slice held here:
+ids, logits and loss are over it. A tree of leaves a layer
+(``params["layer_<i>"]``), as ``Keye`` keeps them.
 
 ``residual_init_scale`` states how the two projections that write into the
-residual stream (``Wo`` and the experts' ``down``) start, for a caller
-that draws every matrix at one spread: each is held as that constant
-times its stored leaf, so a leaf drawn at the initialiser's spread starts
-the projection at ``residual_init_scale`` times it (a scaled
-initialisation of the residual projections, as GPT-2's 1 / sqrt(2 x
-layers)). At 1, the default, the stored leaf is the projection.
+residual stream (``Wo`` and the experts' ``down``) start, as in ``Keye``:
+each is held as that constant times its stored leaf.
 
-The loss is the language model's alone. The indexer reads the hidden state
-with its gradient stopped and the selected set is a constant of the
-backward pass, so the indexer's leaves get a zero gradient: the term that
-trains it (a KL divergence to the main attention's head-summed
-distribution, DeepSeek-V3.2-Exp's recipe) is left out (ROADMAP Queue B).
-The vision tower is left out: the model is the text decoder, and with text
-alone M-RoPE's three position streams are equal, which is plain rotary.
+The loss is the language model's alone: no balancing bias and no auxiliary
+loss on the router (the config has no key for either). The expert
+sub-layer is recomputed in the backward pass (``jax.checkpoint``), as in
+``Keye``: without that the cell's step does not fit the chip.
 
-Training only (``Trainer.fit``). Serving it wants a cache that the
-indexer's keys share with the model's and the selection at decode.
+Training only (``Trainer.fit``). Serving it wants a cache that gives a
+windowed layer its window and a global layer everything (ROADMAP Queue B).
 """
 
 from __future__ import annotations
@@ -43,18 +42,17 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.config import NeuralNetConfiguration, register_config
-from deeplearning4j_tpu.nn.layers.attention import indexed_attention
+from deeplearning4j_tpu.nn.layers.attention import grouped_query_attention
 from deeplearning4j_tpu.nn.layers.moe import (
     RoutedExperts,
     load_max_over_mean,
 )
 from deeplearning4j_tpu.observability.vocab import (
-    COUNTER_DSA_KEYS_MEAN,
-    COUNTER_DSA_PAIRS,
-    COUNTER_DSA_TILES_EMPTY,
     COUNTER_MOE_LOAD,
     COUNTER_MOE_PIECES_RUN,
     COUNTER_MOE_TOKENS_HERE,
+    COUNTER_SWA_PAIRS_REQUIRED,
+    COUNTER_SWA_PAIRS_TOUCHED,
     SCOPE_ATTN,
     SCOPE_EMBED,
     SCOPE_HEAD,
@@ -64,27 +62,30 @@ from deeplearning4j_tpu.ops import loss as losses
 from deeplearning4j_tpu.ops import nn as opsnn
 from deeplearning4j_tpu.train.updaters import Adam
 
+_PERIOD = (0, 1, 1, 1)  # one global layer without positions, three windowed
+
 
 @register_config
 @dataclass
-class KeyeConfig:
-    """Architecture config; the defaults are the language model of
-    Keye-VL-2.0-30B-A3B's ``config.json``."""
+class SmallThinkerConfig:
+    """Architecture config; the defaults are SmallThinker-21BA3B-Instruct's
+    ``config.json``. ``rope_layout`` and ``sliding_window_layout`` have one
+    entry a layer, or are None for the published period repeated."""
 
     vocab_size: int = 151936
-    hidden: int = 2048
-    num_layers: int = 48
-    num_heads: int = 32
+    hidden: int = 2560
+    num_layers: int = 52
+    num_heads: int = 28
     num_kv_heads: int = 4
     head_dim: int = 128
-    experts_total: int = 128
-    experts_held: Tuple[int, ...] = tuple(range(128))
-    experts_per_token: int = 8
+    experts_total: int = 64
+    experts_held: Tuple[int, ...] = tuple(range(64))
+    experts_per_token: int = 6
     expert_units: int = 768
-    index_heads: int = 16
-    index_dim: int = 64
-    index_top_k: int = 2048
-    rope_theta: float = 1e7
+    rope_layout: Optional[Tuple[int, ...]] = None
+    sliding_window_layout: Optional[Tuple[int, ...]] = None
+    sliding_window: int = 4096
+    rope_theta: float = 1.5e6
     eps: float = 1e-6
     initializer_range: float = 0.02
     residual_init_scale: float = 1.0
@@ -97,11 +98,20 @@ def _token_ids(features):
     return features["token_ids"] if isinstance(features, dict) else features
 
 
-class Keye:
-    """Trainer-compatible (init/apply/loss_fn) Keye-VL-2.0 text decoder."""
+class SmallThinker:
+    """Trainer-compatible (init/apply/loss_fn) SmallThinker decoder."""
 
-    def __init__(self, config: KeyeConfig):
+    def __init__(self, config: SmallThinkerConfig):
         config.experts_held = tuple(config.experts_held)
+        for name in ("rope_layout", "sliding_window_layout"):
+            layout = getattr(config, name)
+            layout = tuple(_PERIOD[i % len(_PERIOD)]
+                           for i in range(config.num_layers)
+                           ) if layout is None else tuple(layout)
+            if len(layout) != config.num_layers:
+                raise ValueError(f"{name} has {len(layout)} entries for "
+                                 f"{config.num_layers} layers")
+            setattr(config, name, layout)
         self.config = config
         self.net = config.net
 
@@ -110,7 +120,7 @@ class Keye:
         return RoutedExperts(
             experts_total=c.experts_total, experts_held=c.experts_held,
             units=c.expert_units, top_k=c.experts_per_token,
-            router="linear")
+            router="linear", gate_activation="relu")
 
     # -- construction ------------------------------------------------------
 
@@ -138,21 +148,10 @@ class Keye:
         moe = jax.eval_shape(
             lambda: self._experts().init(root, (e,), dtype)[0])
         for i in range(c.num_layers):
-            attn = {
-                "norm": ones(e), "Wq": normal((e, wq)),
-                "Wk": normal((e, wk)), "Wv": normal((e, wk)),
-                "Wo": normal((wq, e)),
-                "q_norm": ones(d), "k_norm": ones(d),
-                "index": {
-                    "Wq": normal((e, c.index_heads * c.index_dim)),
-                    "Wk": normal((e, c.index_dim)),
-                    "k_gamma": ones(c.index_dim),
-                    "k_beta": jnp.zeros((c.index_dim,), dtype),
-                    "Ww": normal((e, c.index_heads)),
-                },
-            }
             params[f"layer_{i}"] = {
-                "attn": attn,
+                "attn": {"norm": ones(e), "Wq": normal((e, wq)),
+                         "Wk": normal((e, wk)), "Wv": normal((e, wk)),
+                         "Wo": normal((wq, e))},
                 "moe": dict({k: normal(v.shape) for k, v in moe.items()},
                             norm=ones(e))}
         return {"params": params, "state": {}}
@@ -163,37 +162,41 @@ class Keye:
         """[N,T] int32 -> (hidden [N,T,H] with the final norm applied, what
         the layers counted: the token-expert pairs that landed on each
         expert held [layers, held], the pieces of the sorted pairs that
-        ran [layers], the pairs the indexer selected [layers] and the
-        share of flash_fwd's live tiles in which it selected nothing
-        [layers])."""
+        ran [layers], and the query-key pairs of a head, over the batch,
+        that each layer's kind requires and that what runs computes
+        [layers] each)."""
         c = self.config
         with jax.named_scope(SCOPE_EMBED):
             x = opsnn.embedding_lookup(params["embeddings"]["word"], ids)
 
         # the expert sub-layer's activations are rows of token-expert pairs,
-        # experts_per_token times the tokens: recomputed in the backward
-        # pass, not kept (PERF.md section 4: what that buys at the cell's size)
+        # experts_per_token times the tokens, beside the router's float32
+        # copies: recomputed in the backward pass, not kept (PERF.md section
+        # 4: the compiler's plan for the cell's step with and without)
         @jax.checkpoint
-        def experts(p, x):
+        def experts(p, x, arrived):
             y, routed = self._experts().apply(
-                p, {}, opsnn.rms_norm(x, p["norm"], c.eps))
+                p, {}, opsnn.rms_norm(x, p["norm"], c.eps),
+                router_input=arrived)
             return x + c.residual_init_scale * y, {
                 k: routed[k] for k in ("tokens_here", "pieces_run")}
 
         counted = []
         for i in range(c.num_layers):
             layer = params[f"layer_{i}"]
+            arrived = x  # what the router reads: no norm, no attention yet
             p = layer["attn"]
             with jax.named_scope(SCOPE_ATTN):
-                a, selected = indexed_attention(
+                a, pairs = grouped_query_attention(
                     p, opsnn.rms_norm(x, p["norm"], c.eps),
                     num_heads=c.num_heads, num_kv_heads=c.num_kv_heads,
-                    index_heads=c.index_heads, top_k=c.index_top_k,
-                    rope_theta=c.rope_theta, eps=c.eps)
+                    rope_theta=c.rope_theta if c.rope_layout[i] else None,
+                    window=(c.sliding_window if c.sliding_window_layout[i]
+                            else None))
                 x = x + c.residual_init_scale * a
             with jax.named_scope(SCOPE_MLP):
-                x, routed = experts(layer["moe"], x)
-            counted.append(dict(selected, **routed))
+                x, routed = experts(layer["moe"], x, arrived)
+            counted.append(dict(pairs, **routed))
         with jax.named_scope(SCOPE_HEAD):
             x = opsnn.rms_norm(x, params["final"]["norm"], c.eps)
         return x, {k: jnp.stack([layer[k] for layer in counted])
@@ -211,7 +214,8 @@ class Keye:
     def loss_fn(self, params, state, batch, rng=None):
         """Mean next-token cross entropy over the T - 1 positions that
         have a next token. The step's metrics carry the experts' load and
-        what the indexer selected (``observability.vocab.STEP_COUNTERS``)."""
+        the pairs of each layer's attention
+        (``observability.vocab.STEP_COUNTERS``)."""
         ids = _token_ids(batch["features"])
         h, counted = self.encode(params, ids)
         with jax.named_scope(SCOPE_HEAD):
@@ -222,10 +226,8 @@ class Keye:
             COUNTER_MOE_TOKENS_HERE: counted["tokens_here"],
             COUNTER_MOE_LOAD: load_max_over_mean(counted["tokens_here"]),
             COUNTER_MOE_PIECES_RUN: counted["pieces_run"],
-            COUNTER_DSA_PAIRS: counted["pairs_selected"],
-            COUNTER_DSA_KEYS_MEAN: jnp.mean(
-                counted["pairs_selected"].astype(jnp.float32)) / ids.size,
-            COUNTER_DSA_TILES_EMPTY: jnp.mean(counted["tiles_empty_share"]),
+            COUNTER_SWA_PAIRS_REQUIRED: counted["pairs_required"],
+            COUNTER_SWA_PAIRS_TOUCHED: counted["pairs_touched"],
         }
         return loss, (state, metrics)
 
@@ -240,20 +242,21 @@ class Keye:
                    jax.tree_util.tree_leaves(variables["params"]))
 
 
-def keye_vl2_30b_a3b(**kw) -> Keye:
-    """Keye-VL-2.0-30B-A3B's published widths; ``num_layers``,
+def smallthinker_21b_a3b(**kw) -> SmallThinker:
+    """SmallThinker-21BA3B-Instruct's published widths; ``num_layers``,
     ``experts_held`` and ``vocab_size`` say which share of it is held
     here."""
-    return Keye(KeyeConfig(**kw))
+    return SmallThinker(SmallThinkerConfig(**kw))
 
 
-def keye_tiny(**kw) -> Keye:
-    """2 layers, hidden 64, 4 and 2 heads of 16, an indexer of 2 heads of 8
-    that keeps 8 keys, top-2 of 8 experts of 32: tests and CPU runs."""
+def smallthinker_tiny(**kw) -> SmallThinker:
+    """4 layers (one period), hidden 64, 6 and 2 heads of 16 (3 a group),
+    a window of 8 keys, top-3 of 8 ReLU-gated experts of 32: tests and CPU
+    runs."""
     for key, value in dict(
-            hidden=64, num_layers=2, num_heads=4, num_kv_heads=2,
+            hidden=64, num_layers=4, num_heads=6, num_kv_heads=2,
             head_dim=16, experts_total=8, experts_held=tuple(range(8)),
-            experts_per_token=2, expert_units=32, index_heads=2,
-            index_dim=8, index_top_k=8, vocab_size=96).items():
+            experts_per_token=3, expert_units=32, sliding_window=8,
+            vocab_size=96).items():
         kw.setdefault(key, value)
-    return Keye(KeyeConfig(**kw))
+    return SmallThinker(SmallThinkerConfig(**kw))

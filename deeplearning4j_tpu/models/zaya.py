@@ -27,7 +27,10 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.config import NeuralNetConfiguration, register_config
 from deeplearning4j_tpu.nn.layers.attention import cca_attention
-from deeplearning4j_tpu.nn.layers.moe import RoutedExperts
+from deeplearning4j_tpu.nn.layers.moe import (
+    RoutedExperts,
+    load_max_over_mean,
+)
 from deeplearning4j_tpu.observability.vocab import (
     COUNTER_MOE_LOAD,
     COUNTER_MOE_PIECES_RUN,
@@ -176,13 +179,10 @@ class Zaya:
         with jax.named_scope(SCOPE_HEAD):
             loss = jnp.mean(losses.linear_softmax_cross_entropy(
                 h[:, :-1], params["embeddings"]["word"], ids[:, 1:]))
-        load = counted["tokens_here"].astype(jnp.float32)
         metrics = {
             "loss": loss,
             COUNTER_MOE_TOKENS_HERE: counted["tokens_here"],
-            COUNTER_MOE_LOAD: jnp.mean(
-                jnp.max(load, axis=1)
-                / jnp.maximum(jnp.mean(load, axis=1), 1.0)),
+            COUNTER_MOE_LOAD: load_max_over_mean(counted["tokens_here"]),
             COUNTER_MOE_PIECES_RUN: counted["pieces_run"],
         }
         return loss, (state, metrics)

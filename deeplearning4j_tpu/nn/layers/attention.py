@@ -16,6 +16,7 @@ the transpose.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from dataclasses import dataclass
 from typing import Optional
@@ -26,12 +27,15 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.kernels.flash_attention import (
     flash_attention,
     forward_plan,
+    pairs_of_call,
 )
 from deeplearning4j_tpu.nn.activations import get_activation
 from deeplearning4j_tpu.nn.config import LayerConfig, register_config
 from deeplearning4j_tpu.nn.initializers import get_initializer
 from deeplearning4j_tpu.observability.vocab import (
     SCOPE_ATTN,
+    SCOPE_ATTN_GLOBAL,
+    SCOPE_ATTN_WINDOW,
     SCOPE_CCA_MIX,
     SCOPE_DSA_INDEX,
     SCOPE_DSA_SELECT,
@@ -247,11 +251,11 @@ def indexed_attention(params, h, *, num_heads: int, num_kv_heads: int,
     normed. Returns the sub-layer's output and what it counted:
     ``pairs_selected`` (over the batch) and ``tiles_empty_share``.
 
-    Main path: q, k, v by ``Wq``, ``Wk``, ``Wv`` (no bias), RMSNorm of
-    each head of q and of k with a learned gain (``q_norm``, ``k_norm``),
-    rotary positions on the whole head (dimension i paired with i + half),
-    k and v repeated to the query heads, ``flash_attention`` with the pair
-    mask, ``Wo``.
+    Main path (``_grouped_query``): q, k, v by ``Wq``, ``Wk``, ``Wv`` (no
+    bias), RMSNorm of each head of q and of k with a learned gain
+    (``q_norm``, ``k_norm``), rotary positions on the whole head
+    (dimension i paired with i + half), k and v repeated to the query
+    heads, ``flash_attention`` with the pair mask, ``Wo``.
 
     The indexer (leaves under ``params["index"]``) reads ``h`` with its
     gradient stopped, in float32 with every product at ``highest``:
@@ -266,7 +270,6 @@ def indexed_attention(params, h, *, num_heads: int, num_kv_heads: int,
     mask ``dsa_select``.
     """
     n, t, _ = h.shape
-    group = num_heads // num_kv_heads
     f32, exact = jnp.float32, jax.lax.Precision.HIGHEST
     index = params["index"]
     with jax.named_scope(SCOPE_DSA_INDEX):
@@ -288,23 +291,74 @@ def indexed_attention(params, h, *, num_heads: int, num_kv_heads: int,
     pair_mask = _selected_pairs(*jax.lax.stop_gradient(
         (q_index, k_index, w_index)), top_k)
 
-    q = opsnn.linear(h, params["Wq"]).reshape(n, t, num_heads, -1)
-    k = opsnn.linear(h, params["Wk"]).reshape(n, t, num_kv_heads, -1)
-    v = opsnn.linear(h, params["Wv"]).reshape(n, t, num_kv_heads, -1)
-    q = opsnn.rms_norm(q, params["q_norm"], eps)
-    k = opsnn.rms_norm(k, params["k_norm"], eps)
-    q = _rotary(q.astype(f32), rope_theta, 1.0).astype(h.dtype)
-    k = _rotary(k.astype(f32), rope_theta, 1.0).astype(h.dtype)
-    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
-    y = flash_attention(q, k, v, causal=True, pair_mask=pair_mask)
-    y = opsnn.linear(_merge_heads(y), params["Wo"])
+    y = _grouped_query(params, h, num_heads=num_heads,
+                       num_kv_heads=num_kv_heads, rope_theta=rope_theta,
+                       qk_norm_eps=eps, pair_mask=pair_mask)
     with jax.named_scope(SCOPE_DSA_SELECT):
         counted = {
             "pairs_selected": jnp.sum(pair_mask, dtype=jnp.int32),
-            "tiles_empty_share": _empty_tile_share(pair_mask, q.shape[-1]),
+            "tiles_empty_share": _empty_tile_share(
+                pair_mask, params["Wq"].shape[-1] // num_heads),
         }
     return y, counted
+
+
+def _grouped_query(params, h, *, num_heads: int, num_kv_heads: int,
+                   rope_theta: Optional[float], qk_norm_eps=None,
+                   pair_mask=None, window=None, scope=None):
+    """Causal grouped-query attention over ``h`` [N,T,E], already normed:
+    q, k, v by ``Wq``, ``Wk``, ``Wv`` (no bias); with ``qk_norm_eps``,
+    RMSNorm of each head of q and of k (``q_norm``, ``k_norm``); with
+    ``rope_theta``, rotary positions on the whole head (dimension i paired
+    with i + half), else nothing that tells one position from another; k
+    and v repeated to the query heads; ``flash_attention`` under the pair
+    mask or the window, where there is one; ``Wo``. Everything between the
+    projections and ``Wo`` carries the sub-scope ``scope``, where one is
+    named."""
+    n, t, _ = h.shape
+    group = num_heads // num_kv_heads
+    f32 = jnp.float32
+    q = opsnn.linear(h, params["Wq"]).reshape(n, t, num_heads, -1)
+    k = opsnn.linear(h, params["Wk"]).reshape(n, t, num_kv_heads, -1)
+    v = opsnn.linear(h, params["Wv"]).reshape(n, t, num_kv_heads, -1)
+    if qk_norm_eps is not None:
+        q = opsnn.rms_norm(q, params["q_norm"], qk_norm_eps)
+        k = opsnn.rms_norm(k, params["k_norm"], qk_norm_eps)
+    with (jax.named_scope(scope) if scope else contextlib.nullcontext()):
+        if rope_theta is not None:
+            q = _rotary(q.astype(f32), rope_theta, 1.0).astype(h.dtype)
+            k = _rotary(k.astype(f32), rope_theta, 1.0).astype(h.dtype)
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+        y = flash_attention(q, k, v, causal=True, pair_mask=pair_mask,
+                            window=window)
+    return opsnn.linear(_merge_heads(y), params["Wo"])
+
+
+def grouped_query_attention(params, h, *, num_heads: int, num_kv_heads: int,
+                            rope_theta: Optional[float],
+                            window: Optional[int]):
+    """One layer's attention of a decoder whose layers differ in kind
+    (SmallThinker's ``rope_layout`` and ``sliding_window_layout``), over
+    ``h`` [N,T,E], already normed: causal grouped-query attention
+    (``_grouped_query``; no bias, no q-k norm) with rotary positions at
+    ``rope_theta`` or, where that is None, no positions at all, over the
+    last ``window`` keys of each query's past (itself counted) or, where
+    that is None, over all of it. The attention proper is the sub-scope
+    ``attn_window`` or ``attn_global``, by ``window``. Returns the
+    sub-layer's output and what it counted, over the batch and for one
+    head: ``pairs_required`` (what the layer's kind asks) and
+    ``pairs_touched`` (what runs computes:
+    ``kernels.flash_attention.pairs_of_call``)."""
+    n, t, _ = h.shape
+    y = _grouped_query(
+        params, h, num_heads=num_heads, num_kv_heads=num_kv_heads,
+        rope_theta=rope_theta, window=window,
+        scope=SCOPE_ATTN_GLOBAL if window is None else SCOPE_ATTN_WINDOW)
+    required, touched = pairs_of_call(
+        t, t, params["Wq"].shape[-1] // num_heads, causal=True, window=window)
+    return y, {"pairs_required": jnp.int32(n * required),
+               "pairs_touched": jnp.int32(n * touched)}
 
 
 def _record_selection(seq_len, top_k, index_heads, width):

@@ -289,12 +289,16 @@ def _record_grouped_product(pairs, m, k, n, groups):
         inner=k, columns=n, groups=groups)
 
 
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 @register_config
 @dataclass
 class RoutedExperts(LayerConfig):
-    """Drop-free top-``top_k`` routing over ``experts_total`` SiLU-gated
-    experts, of which this layer holds ``experts_held`` (expert
-    parallelism: the other chips hold the rest).
+    """Drop-free top-``top_k`` routing over ``experts_total`` gated
+    experts (``down(act(gate x) * (up x))``, ``act`` by
+    ``gate_activation``: SiLU or ReLU), of which this layer holds
+    ``experts_held`` (expert parallelism: the other chips hold the rest).
 
     The router is, by ``router``, one of two forms, both over ALL the
     experts whatever is held and both in float32 from their first matrix
@@ -305,7 +309,9 @@ class RoutedExperts(LayerConfig):
     ``"linear"``: z = x Wg, p = softmax(z), no bias and no state. A token's
     ``top_k`` largest are its experts, each weighted by its p over the sum
     of the chosen p's, wherever those experts are held (one expert a token
-    keeps its own p).
+    keeps its own p). The router reads the rows the experts read unless
+    ``apply`` is given others (``router_input``: a layer whose router
+    stands before its attention reads the layer's input).
 
     The token-expert pairs are sorted by the expert's place among those
     held (a pair whose expert is held elsewhere sorts last and adds
@@ -332,6 +338,12 @@ class RoutedExperts(LayerConfig):
     carries_router: bool = True   # False in the first layer: no gamma
     top_k: int = 1
     router: str = "mlp"           # or "linear": one matrix, no state
+    gate_activation: str = "silu"  # or "relu"
+
+    def __post_init__(self):
+        if self.gate_activation not in _GATES:
+            raise ValueError(f"gate_activation {self.gate_activation!r} not "
+                             f"in {sorted(_GATES)}")
 
     def output_shape(self, input_shape):
         return tuple(input_shape)
@@ -389,9 +401,13 @@ class RoutedExperts(LayerConfig):
             share = share / jnp.sum(share, axis=-1, keepdims=True)
         return r, chosen, share
 
-    def apply(self, params, state, x, *, train=False, rng=None):
+    def apply(self, params, state, x, *, train=False, rng=None,
+              router_input=None):
         shape = x.shape
         tokens = x.reshape(-1, shape[-1])
+        routed_on = (tokens if router_input is None or router_input is x
+                     else router_input.reshape(-1, router_input.shape[-1]))
+        gate = _GATES[self.gate_activation]
         held, fan = len(self.experts_held), self.top_k
         # an expert's place among those held; ``held`` for one held elsewhere
         place = np.full((self.experts_total,), held, np.int32)
@@ -400,7 +416,7 @@ class RoutedExperts(LayerConfig):
         rows_a_piece = _piece_rows(pairs, held, self.experts_total)
         with jax.named_scope(SCOPE_MOE_ROUTE):
             r, chosen, share = self.route(
-                params, tokens, state.get("router"))
+                params, routed_on, state.get("router"))
             # a token's pairs lie side by side: pair p is of token p // fan
             local = jnp.asarray(place)[chosen.reshape(-1)]
             order = jnp.argsort(local)
@@ -422,7 +438,7 @@ class RoutedExperts(LayerConfig):
                 rows = _dispatch(tokens, source, inverse, tokens.shape[0])
             with jax.named_scope(SCOPE_MOE_EXPERTS):
                 inner = (
-                    jax.nn.silu(_grouped(rows, params["gate"], sizes_here))
+                    gate(_grouped(rows, params["gate"], sizes_here))
                     * _grouped(rows, params["up"], sizes_here))
                 out = _grouped(inner, params["down"], sizes_here)
             with jax.named_scope(SCOPE_MOE_ROUTE):
@@ -454,6 +470,15 @@ class RoutedExperts(LayerConfig):
         if r is not None:
             routed["router"] = r
         return y, routed
+
+
+def load_max_over_mean(tokens_here) -> jnp.ndarray:
+    """``RoutedExperts``' ``tokens_here`` of a model's layers [layers, held]
+    as one number of the step: the largest load over the mean load of the
+    experts held, averaged over the layers."""
+    load = tokens_here.astype(jnp.float32)
+    return jnp.mean(jnp.max(load, axis=1)
+                    / jnp.maximum(jnp.mean(load, axis=1), 1.0))
 
 
 def load_balance_loss(probs, dispatch) -> jnp.ndarray:

@@ -226,8 +226,15 @@ SCOPE_MOE_EXPERTS = "moe_experts"  # in mlp: the experts' grouped products
 SCOPE_DSA_INDEX = "dsa_index"      # in attn: the indexer's projections, norm,
                                    # rotary, score product, relu, weighting
 SCOPE_DSA_SELECT = "dsa_select"    # in attn: the threshold and the pair mask
+# in attn, of a decoder whose layers differ in kind: the attention proper of
+# a layer (rotary where it has positions, the repeat of k and v to the query
+# heads, the three flash kernels forward and backward), by whether its
+# queries reach back over a window or over everything before them
+SCOPE_ATTN_WINDOW = "attn_window"
+SCOPE_ATTN_GLOBAL = "attn_global"
 SUB_SCOPES = (SCOPE_CCA_MIX, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
-              SCOPE_DSA_INDEX, SCOPE_DSA_SELECT)
+              SCOPE_DSA_INDEX, SCOPE_DSA_SELECT, SCOPE_ATTN_WINDOW,
+              SCOPE_ATTN_GLOBAL)
 
 # Metrics of the step that ``Trainer.fit`` fetches once as it returns (the
 # last step's) and publishes in ``observability/runtime.step_counters``.
@@ -243,7 +250,13 @@ COUNTER_DSA_KEYS_MEAN = "dsa.keys_selected_mean"   # a query, over the layers
 COUNTER_DSA_TILES_EMPTY = "dsa.tiles_empty_share"  # of flash_fwd's live tiles
 DSA_COUNTERS = (COUNTER_DSA_PAIRS, COUNTER_DSA_KEYS_MEAN,
                 COUNTER_DSA_TILES_EMPTY)
-STEP_COUNTERS = MOE_COUNTERS + DSA_COUNTERS
+# Known while tracing (functions of the shapes and each layer's kind).
+COUNTER_SWA_PAIRS_REQUIRED = "swa.pairs_required"  # [layers], over the batch:
+                                                   # what the layer's kind asks
+COUNTER_SWA_PAIRS_TOUCHED = "swa.pairs_touched"    # [layers], over the batch:
+                                                   # what a kernel computes
+SWA_COUNTERS = (COUNTER_SWA_PAIRS_REQUIRED, COUNTER_SWA_PAIRS_TOUCHED)
+STEP_COUNTERS = MOE_COUNTERS + DSA_COUNTERS + SWA_COUNTERS
 
 # ``name=`` of each Pallas kernel: the custom call reads ``jvp(flash_fwd)``
 # where an unnamed one reads ``jvp()``.

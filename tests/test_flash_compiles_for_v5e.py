@@ -30,7 +30,8 @@ def one_chip():
 
 
 # (batch, heads, queries, keys, head width), dtype, causal, key mask, and
-# optionally the caller's own blocks, environment, and a mask of pairs
+# optionally the caller's own blocks, environment, a mask of pairs, and a
+# window
 _CALLS = {
     "gpt2_small.train_s1024": ((16, 12, 1024, 1024, 64), "bfloat16", True,
                                False),
@@ -42,6 +43,14 @@ _CALLS = {
     # them, one [batch, T, S] int8 mask of the selected pairs for all heads
     "keye_vl2_30b_a3b.train_s8192": ((2, 32, 8192, 8192, 128), "bfloat16",
                                      True, False, None, None, True),
+    # a windowed layer's call: 28 query heads of 128, k and v repeated to
+    # them, the last 4,096 keys of each query's past
+    "smallthinker_21b_a3b.train_s16384": ((1, 28, 16384, 16384, 128),
+                                          "bfloat16", True, False, None,
+                                          None, False, 4096),
+    "windowed_ragged_float32_pair_masked": ((2, 4, 3000, 3000, 64),
+                                            "float32", True, True, None,
+                                            None, True, 1000),
     "pair_masked_ragged_float32": ((2, 4, 1000, 1000, 64), "float32", True,
                                    True, None, None, True),
     "bert_like_masked_float32": ((2, 8, 2048, 2048, 128), "float32", False,
@@ -67,7 +76,7 @@ _CALLS = {
 def test_forward_and_backward_compile_at_the_default_geometry(
         call, one_chip, as_on_tpu, monkeypatch):
     (b, h, t, s, d), dtype, causal, masked, *rest = _CALLS[call]
-    own_blocks, env, pairs = (rest + [None, None, False])[:3]
+    own_blocks, env, pairs, window = rest + [None, None, False, None][len(rest):]
     for name, value in (env or {}).items():
         monkeypatch.setenv(name, value)
     blocks = flash_block_sizes(t, s, d, causal)
@@ -90,7 +99,7 @@ def test_forward_and_backward_compile_at_the_default_geometry(
         key_mask = masks.pop(0) if masked else None
         pair_mask = masks.pop(0) if pairs else None
         out = fa._flash(q, k, v, key_mask, causal, d ** -0.5, blocks,
-                        pair_mask)
+                        window, pair_mask)
         return jnp.sum(out.astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
@@ -112,7 +121,7 @@ def test_the_gpt2_call_compiles_at_each_split_the_rule_can_return(
 
     def loss(q, k, v):
         out = fa._flash(q, k, v, None, True, 0.125,
-                        FlashBlocks(*[(block, block)] * 3))
+                        FlashBlocks(*[(block, block)] * 3), None)
         return jnp.sum(out.astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(

@@ -121,12 +121,14 @@ def _touched(plan):
             continue
         diagonal = bool(plan.diagonal(qi, ki))
         assert bool(plan.whole(qi, ki)) is not diagonal
-        for row, rows, keys, causal in plan.parts(diagonal):
-            r = qi * plan.block_q + row
-            c = ki * plan.block_k
+        for part in plan.parts("diagonal" if diagonal else "whole"):
+            rows, keys = part.rows, part.keys
+            r = qi * plan.block_q + part.row
+            c = ki * plan.block_k + part.key
+            assert part.key == 0 and not part.window  # no window: a prefix
             assert not touched[r:r + rows, c:c + keys].any()  # once each
             touched[r:r + rows, c:c + keys] = True
-            if not causal:  # then the causal term kills no pair of it
+            if not part.causal:  # then the causal term kills no pair of it
                 i = np.arange(r, r + rows)[:, None]
                 j = np.arange(c, c + keys)[None, :]
                 assert not plan.causal or (j <= i + plan.offset).all()
@@ -143,10 +145,10 @@ def test_which_tiles_are_diagonal_and_how_they_are_split(case):
     assert (counts["sub_blocks"], counts["diagonal"], counts["live"]) == (
         sub_blocks, diagonal, live)
     if sub_blocks == 1:
-        assert plan.parts(False) == [(0, plan.block_q, plan.block_k,
-                                      plan.causal)]
+        assert plan.parts("whole") == [(0, plan.block_q, 0, plan.block_k,
+                                        plan.causal, False)]
     else:
-        assert len(plan.parts(True)) == sub_blocks
+        assert len(plan.parts("diagonal")) == sub_blocks
     # every pair the mask leaves is computed, and counted as the event says
     dense = _dense_mask(plan)
     touched = _touched(plan)
@@ -216,15 +218,17 @@ def test_the_plan_is_a_flight_event_of_every_traced_call(flight):
     data = first["data"]
     assert (data["seq_q"], data["seq_k"], data["head_dim"]) == (128, 128, 16)
     assert data["causal"] is True and data["key_mask"] is False
-    want = {"block_q": 32, "block_k": 64, "dead": 2, "live": 6,
-            "diagonal": 0, "sub_blocks": 1,
+    want = {"block_q": 32, "block_k": 64, "dead": 2, "dead_steps": 2,
+            "live": 6,
+            "diagonal": 0, "edge": 0, "sub_blocks": 1,
             "pairs_touched_over_required": round(
                 6 * 32 * 64 / (128 * 129 / 2), 4)}
     assert data["fwd"] == data["dkv"] == data["dq"] == want
     assert second["data"]["causal"] is False
     assert second["data"]["dq"] == {"block_q": 64, "block_k": 128,
-                                    "dead": 0, "live": 2, "diagonal": 0,
-                                    "sub_blocks": 1,
+                                    "dead": 0, "dead_steps": 0, "live": 2,
+                                    "diagonal": 0,
+                                    "edge": 0, "sub_blocks": 1,
                                     "pairs_touched_over_required": 1.0}
     # a recorder armed later sees the plans of the next trace as well
     later = set_flight_recorder(FlightRecorder())
@@ -247,8 +251,9 @@ def test_the_default_plan_at_the_benchmarks_shape(flight):
     assert _QUOTED == {name: data[name] for name in ("fwd", "dkv", "dq")}
 
 
-_ONE_TILE_IN_FOUR = {"block_q": 1024, "block_k": 1024, "dead": 0, "live": 1,
-                    "diagonal": 1, "sub_blocks": 4,
+_ONE_TILE_IN_FOUR = {"block_q": 1024, "block_k": 1024, "dead": 0,
+                    "dead_steps": 0, "live": 1,
+                    "diagonal": 1, "edge": 0, "sub_blocks": 4,
                     "pairs_touched_over_required": 1.2488}
 _QUOTED = {"fwd": _ONE_TILE_IN_FOUR, "dkv": _ONE_TILE_IN_FOUR,
            "dq": _ONE_TILE_IN_FOUR}

@@ -68,7 +68,7 @@ class TestInterpretOnlyByFlag:
         q, k, v = _qkv()
         with pytest.raises(RuntimeError, match="DL4J_TPU_FORCE_PALLAS"):
             fa._flash(q, k, v, None, False, 0.25,
-                      _dispatch.FlashBlocks(*[(32, 128)] * 3))
+                      _dispatch.FlashBlocks(*[(32, 128)] * 3), None)
 
 
 class TestExplicitPallasIsAContract:

@@ -191,7 +191,7 @@ def test_flash_kernels_at_three_geometries():
     blocks = FlashBlocks(fwd=(32, 64), dkv=(64, 32), dq=(16, 48))
 
     def fn(q, k, v):
-        return fa._flash(q, k, v, None, True, 0.25, blocks)
+        return fa._flash(q, k, v, None, True, 0.25, blocks, None)
 
     ref = functools.partial(reference_attention, causal=True)
     np.testing.assert_allclose(np.asarray(fn(q, k, v)),

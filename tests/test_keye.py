@@ -447,7 +447,7 @@ def test_fit_publishes_what_the_last_step_selected_and_routed(monkeypatch):
     losses = [float(m["total_loss"]) for m in Keep.seen]
     assert losses[-1] < losses[0]
     counters = runtime.step_counters()
-    assert set(counters) == set(vocab.STEP_COUNTERS)
+    assert set(counters) == set(vocab.MOE_COUNTERS + vocab.DSA_COUNTERS)
     last = Keep.seen[-1]
     here = np.asarray(last[vocab.COUNTER_MOE_TOKENS_HERE])
     assert here.shape == (2, 4) and here.dtype == np.int32
