@@ -161,50 +161,90 @@ class MoEBlock(LayerConfig):
         return y.reshape(shape), new_state
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(tokens, source, inverse, count):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _dispatch(tokens, source, inverse, count, most):
     """The rows of the sorted pairs, or of a piece of them: ``tokens[source]``
-    (``count`` tokens), with the gradient ``_combine`` of the rows'
-    gradients, its transpose, and not a scatter in ``tokens``' own
-    rounding."""
+    (``count`` tokens, of which each has ``most`` rows at most), with the
+    gradient ``_combine`` of the rows' gradients, its transpose, and not a
+    scatter in ``tokens``' own rounding."""
     return tokens[source]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _combine(rows, source, inverse, count):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _combine(rows, source, inverse, count, most):
     """For each of ``count`` tokens the sum of its pairs' ``rows``; its
     gradient is ``_dispatch`` of the tokens' gradients."""
-    return _sum_by_token(rows, source, inverse, count)
+    return _sum_by_token(rows, source, inverse, count, most)
 
 
-def _sum_by_token(rows, source, inverse, count):
-    """Each token's rows summed in float32. Where the rows are all the
-    sorted pairs, ``inverse`` [pairs] is each pair's row, a token's pairs
-    side by side: a gather back and the sum. Where they are a piece of
-    them it is None, a token's other pairs may lie in another piece, and
-    each row is added to the token that ``source`` names: on the chip
-    that scatter of a piece's rows costs less than two thirds of the
-    gather of all the pairs' positions out of the piece (PERF.md section
-    5, 2b)."""
-    f32 = jnp.float32
+def _sum_by_token(rows, source, inverse, count, most):
+    """Each token's rows summed in float32 and rounded once. Where the
+    rows are all the sorted pairs, ``inverse`` [pairs] is each pair's row,
+    a token's pairs side by side: a gather back and the sum. Where they
+    are a piece of them it is None, a token's other pairs may lie in
+    another piece, and ``source`` names each row's token, ``count`` for a
+    row of no group, which adds nothing: the rows are put in token order
+    and summed by sorted segments (``_sum_runs``)."""
     if inverse is None:
-        total = jnp.zeros((count, rows.shape[-1]), f32)
-        return total.at[source].add(rows.astype(f32)).astype(rows.dtype)
+        return _sum_runs(rows, source, count, most)
     back = rows[inverse]
     if back.shape[0] > count:
-        back = jnp.sum(back.reshape(count, -1, rows.shape[-1]).astype(f32),
-                       axis=1).astype(rows.dtype)
+        back = jnp.sum(back.reshape(count, -1, rows.shape[-1]).astype(
+            jnp.float32), axis=1).astype(rows.dtype)
     return back
 
 
+# How a piece's rows are summed into their tokens, as the flight event
+# ``kernel.grouped_product`` names it (``combine``).
+COMBINE_A_PIECE = "sorted_segments"
+
+
+def _sum_runs(rows, source, count, most):
+    """``rows`` [n, H] summed by ``source`` [n] into [``count``, H], where
+    no token below ``count`` has more than ``most`` rows and a row whose
+    source is ``count`` is dropped. No index is written through, so none
+    can repeat: the rows are sorted by token (one sort of ``n`` keys, one
+    row gather) and, on the chip, summed by the kernel
+    ``kernels/segment_rows.py``. Off it each row takes in its next
+    ``most - 1`` neighbours of the same token (in float32, rounded once),
+    and each token reads the first row of its run, found by counting the
+    keys below it; a token of no row reads a row of zeros gathered past
+    the last."""
+    n = rows.shape[0]
+    keys, at = jax.lax.sort(
+        (source.astype(jnp.int32), jnp.arange(n, dtype=jnp.int32)),
+        num_keys=1)
+    if use_pallas():
+        from deeplearning4j_tpu.kernels.segment_rows import sum_sorted_rows
+
+        return sum_sorted_rows(rows[at], keys, count, most,
+                               interpret=interpret())
+    # past the last row ``most`` more, each under a key of its own that no
+    # token has; the first of them is the row of zeros
+    keys = jnp.concatenate([keys, -1 - jnp.arange(most, dtype=jnp.int32)])
+    ordered = rows[jnp.pad(at, (0, most))]
+    real = (jnp.arange(n + 1) < n)[:, None]
+    total = jnp.where(real, ordered[:n + 1].astype(jnp.float32), 0.0)
+    for ahead in range(1, most):
+        same = keys[ahead:ahead + n + 1] == keys[:n + 1]
+        total = total + jnp.where(
+            same[:, None], ordered[ahead:ahead + n + 1].astype(jnp.float32),
+            0.0)
+    total = total.astype(rows.dtype)
+    first = jnp.searchsorted(keys[:n], jnp.arange(count + 1, dtype=jnp.int32),
+                             method="sort")
+    return total[jnp.where(first[1:] > first[:-1], first[:-1], n)]
+
+
 _dispatch.defvjp(
-    lambda tokens, source, inverse, count: (tokens[source],
-                                            (source, inverse)),
-    lambda count, kept, g: (_sum_by_token(g, *kept, count), None, None))
+    lambda tokens, source, inverse, count, most: (tokens[source],
+                                                  (source, inverse)),
+    lambda count, most, kept, g: (_sum_by_token(g, *kept, count, most),
+                                  None, None))
 _combine.defvjp(
-    lambda rows, source, inverse, count: (
-        _sum_by_token(rows, source, inverse, count), source),
-    lambda count, source, g: (g[source], None, None))
+    lambda rows, source, inverse, count, most: (
+        _sum_by_token(rows, source, inverse, count, most), source),
+    lambda count, most, source, g: (g[source], None, None))
 
 # The grouped product on the chip: megablox ``gmm`` (Pallas; JAX ships it),
 # chosen over ``jax.lax.ragged_dot`` by traces on a v5e (PERF.md section 6,
@@ -276,8 +316,10 @@ def _piece_of(order, sizes, first, rows):
 
 def _record_grouped_product(pairs, m, k, n, groups):
     """One ``kernel.grouped_product`` flight event per layer, at trace
-    time: which product the experts run through, its tiles, and the
-    pieces of ``m`` rows that the ``pairs`` sorted rows are walked in."""
+    time: which product the experts run through, its tiles, the pieces of
+    ``m`` rows that the ``pairs`` sorted rows are walked in, and under
+    ``combine`` how a piece's rows are summed into their tokens
+    (``"inverse_gather"`` where one piece holds all the pairs)."""
     from deeplearning4j_tpu.observability.flightrecorder import record_event
 
     on_chip = use_pallas()
@@ -286,6 +328,7 @@ def _record_grouped_product(pairs, m, k, n, groups):
         product="megablox.gmm" if on_chip else "jax.lax.ragged_dot",
         tiles=list(_tiles(m, k, n)) if on_chip else None,
         rows=pairs, rows_a_piece=m, pieces=-(-pairs // m),
+        combine="inverse_gather" if m == pairs else COMBINE_A_PIECE,
         inner=k, columns=n, groups=groups)
 
 
@@ -414,6 +457,8 @@ class RoutedExperts(LayerConfig):
         place[list(self.experts_held)] = np.arange(held)
         pairs = tokens.shape[0] * fan
         rows_a_piece = _piece_rows(pairs, held, self.experts_total)
+        # the tokens, and the most rows that one of them has in a piece
+        sums = (tokens.shape[0], min(fan, held))
         with jax.named_scope(SCOPE_MOE_ROUTE):
             r, chosen, share = self.route(
                 params, routed_on, state.get("router"))
@@ -435,7 +480,11 @@ class RoutedExperts(LayerConfig):
                 span, sizes_here = _piece_of(order, sizes, first,
                                              rows_a_piece)
                 source = span if fan == 1 else span // fan
-                rows = _dispatch(tokens, source, inverse, tokens.shape[0])
+                if inverse is None:  # a row of no group: of no token
+                    source = jnp.where(
+                        jnp.arange(rows_a_piece) < rows_a_piece
+                        - sizes_here[-1], source, tokens.shape[0])
+                rows = _dispatch(tokens, source, inverse, *sums)
             with jax.named_scope(SCOPE_MOE_EXPERTS):
                 inner = (
                     gate(_grouped(rows, params["gate"], sizes_here))
@@ -445,7 +494,7 @@ class RoutedExperts(LayerConfig):
                 weight = jnp.where(local < held, share.reshape(-1), 0.0)[span]
                 out = (out * weight[:, None]).astype(x.dtype)
                 # the weighted results of one token add up
-                return _combine(out, source, inverse, tokens.shape[0])
+                return _combine(out, source, inverse, *sums)
 
         y, pieces_run = piece(0), jnp.int32(1)
         if rows_a_piece < pairs:

@@ -263,6 +263,7 @@ STEP_COUNTERS = MOE_COUNTERS + DSA_COUNTERS + SWA_COUNTERS
 KERNEL_NAMES = frozenset({
     "flash_fwd", "flash_bwd_dkv", "flash_bwd_dq",
     "lstm_scan_fwd", "lstm_scan_bwd", "gru_scan_fwd", "gru_scan_bwd",
+    "segment_rows_sum",
 })
 
 # ``observability.trace.annotate`` on the host side: spans on the profiler's

@@ -228,3 +228,34 @@ def test_the_keye_cells_expert_layer_compiles_in_pieces(one_chip,
     assert f"[{pairs},{hidden}]" not in text
     # the parent's layer planned 2.66 GB here (PERF.md section 6, PR 34)
     assert compiled.memory_analysis().peak_memory_in_bytes < 2.5e9
+
+
+# rows of a piece, tokens, width, the most rows of one token, dtype
+_PIECE_SUMS = {
+    "smallthinker_21b_a3b.train_s16384": (24576, 16384, 2560, 6, "bfloat16"),
+    "keye_vl2_30b_a3b.train_s8192": (32768, 16384, 2048, 8, "bfloat16"),
+    "ragged_float32": (1000, 3000, 640, 3, "float32"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PIECE_SUMS))
+def test_the_sum_of_a_pieces_rows_compiles(name, one_chip, monkeypatch):
+    """``RoutedExperts``' sum of a piece's rows into their tokens where
+    pieces are walked: a sort, one row gather and the kernel
+    ``segment_rows_sum`` at its default tiles, and no scatter."""
+    from deeplearning4j_tpu.nn.layers import moe
+
+    monkeypatch.setattr(moe, "use_pallas", lambda: True)
+    monkeypatch.setattr(moe, "interpret", lambda: False)
+    rows, count, width, most, dtype = _PIECE_SUMS[name]
+    compiled = jax.jit(
+        lambda r, s: moe._sum_by_token(r, s, None, count, most)).lower(
+        jax.ShapeDtypeStruct((rows, width), jnp.dtype(dtype),
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "segment_rows_sum" in text and " scatter(" not in text
+    # the rows in token order and nothing else of their size
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2 * (
+        rows + 256) * width * jnp.dtype(dtype).itemsize

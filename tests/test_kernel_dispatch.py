@@ -318,7 +318,8 @@ def test_routed_experts_walk_pieces_only_where_a_part_is_held(case,
     megablox's calls as before there were pieces. Where fewer are held,
     every ``gmm`` / ``tgmm`` call takes a piece's rows, the rows gathered
     from the tokens are a piece's, and no operation is over all the
-    pairs' rows: a piece's results are added to their tokens."""
+    pairs' rows: a piece's results are summed into their tokens by
+    sorted segments."""
     import re
 
     from deeplearning4j_tpu.nn.layers import moe
@@ -372,10 +373,17 @@ def test_routed_experts_walk_pieces_only_where_a_part_is_held(case,
         r": \(tensor<(\d+x%d)xf32>, tensor<\d+x1xi32>, "
         r"tensor<(\d+x%d)xf32>\) -> tensor<" % (hidden, hidden), text))
     if walked:  # no array has a row for each of the pairs: a piece's rows
-        # are gathered, and added to their tokens in float32
+        # are gathered
         assert not [shape for pair in wide for shape in pair
                     if shape.startswith(f"{pairs}x")], wide
-        assert scattered == {(f"{tokens}x{hidden}", f"{rows}x{hidden}")}
+        # and summed into their tokens by sorted segments: the rows in
+        # token order through ``segment_rows_sum``, no scatter of rows
+        assert not scattered
+        sums = [line for line in text.splitlines()
+                if 'kernel_name = "segment_rows_sum"' in line]
+        assert sums and all(
+            f"tensor<{rows}x{hidden}xf32>) -> tensor<{tokens}x{hidden}xf32>"
+            in line for line in sums)
     else:  # all the pairs' rows, gathered there and back: no scatter
         assert (f"{pairs}x{hidden}", f"{pairs}x{hidden}") in wide
         assert not scattered

@@ -472,4 +472,5 @@ def test_fit_publishes_what_the_last_step_selected_and_routed(monkeypatch):
     # half of the experts held: the sorted pairs are one piece
     assert products[0]["data"]["rows_a_piece"] == 2 * ROWS * SEQ
     assert products[0]["data"]["pieces"] == 1
+    assert products[0]["data"]["combine"] == "inverse_gather"
     assert "attention.dsa_select" in vocab.known_event_kinds()

@@ -35,6 +35,8 @@ BEFORE = {
         "6e8356887f3a99f20e23df50cc6a770f01765a11fb3889a05f619d610562dae9",
     "keye_tiny":
         "dcac22b8a0421da54e46adc99360c2e3a98223fdf61315d58debd56d6fa16dab",
+    "layer_of_one_piece":
+        "b0ddc98ed9ef6247205e19f6d7cf6eca9449349dfecc816388784c685452eba6",
 }
 
 
@@ -87,6 +89,52 @@ def step_digest(name: str) -> str:
     return _digest(jax.jit(step).lower(variables["params"]).as_text())
 
 
+def scatter_updates(text: str):
+    """The type of each ``stablehlo.scatter``'s update operand."""
+    return [re.search(r"\}\) : \(([^)]*)\) -> ", text[at.end():]).group(
+        1).split(", ")[-1]
+            for at in re.finditer(r'"stablehlo\.scatter"\(', text)]
+
+
+def layer_text(held, top_k, router):
+    """One ``RoutedExperts`` layer of 16 experts over 128 tokens of 64,
+    value and gradients, lowered."""
+    from deeplearning4j_tpu.nn.layers.moe import RoutedExperts
+
+    layer = RoutedExperts(experts_total=16, experts_held=tuple(range(held)),
+                          units=32, router_hidden=16, top_k=top_k,
+                          router=router)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.key(0), (64,), jnp.float32)[0])
+    x = jax.ShapeDtypeStruct((2, 64, 64), jnp.float32)
+    carried = jax.ShapeDtypeStruct((128, 16), jnp.float32)
+
+    def loss(p, x, carried):
+        y, _ = layer.apply(p, {"router": carried}, x)
+        return jnp.sum(jnp.square(y))
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x, carried).as_text()
+
+
+def test_a_layer_of_one_piece_lowers_to_what_it_lowered_to():
+    """``zaya1_8b``'s shape, tiny: 8 of 16 experts held behind the MLP
+    router, one piece of all the tokens; the digest is the text's at the
+    commit before the sum by sorted segments (PR 36)."""
+    assert _digest(layer_text(8, 1, "mlp")) == BEFORE["layer_of_one_piece"]
+
+
+def test_a_layer_that_walks_pieces_scatters_no_row():
+    """2 of 16 held at top-3: pieces of 96 of the 384 pairs. No scatter of
+    the lowered step, forward or backward, takes a piece's rows (or the
+    tokens') as its update: what is left writes numbers, not rows."""
+    text = layer_text(2, 3, "linear")
+    assert "stablehlo.while" in text  # the walk over the later pieces
+    updates = scatter_updates(text)
+    assert updates  # the run starts' ranks, the router's gradient
+    assert not [u for u in updates if re.fullmatch(r"tensor<\d+x64x\w+>", u)]
+
+
 @pytest.mark.parametrize("name", sorted(CALLS))
 def test_a_call_without_a_window_lowers_to_what_it_lowered_to(name,
                                                               as_on_tpu):
@@ -125,3 +173,4 @@ if __name__ == "__main__":
         print(f'    "{name}": "{call_digest(name)}",')
     for name in ("zaya_tiny", "keye_tiny"):
         print(f'    "{name}": "{step_digest(name)}",')
+    print(f'    "layer_of_one_piece": "{_digest(layer_text(8, 1, "mlp"))}",')

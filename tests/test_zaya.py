@@ -338,6 +338,7 @@ def test_fit_publishes_the_last_steps_expert_load(monkeypatch):
     assert counters[vocab.COUNTER_MOE_PIECES_RUN] == [1, 1]  # a layer
     assert events[0]["data"]["rows_a_piece"] == ROWS * SEQ
     assert events[0]["data"]["pieces"] == 1
+    assert events[0]["data"]["combine"] == "inverse_gather"
     assert "kernel.grouped_product" in vocab.known_event_kinds()
 
 
