@@ -16,16 +16,21 @@ the reference mount was empty during the survey, so citations are to the
 upstream layout, not literal line numbers).
 """
 
-from deeplearning4j_tpu.version import __version__
+import time as _time
+
+_IMPORT_STARTED = _time.perf_counter()
+
+from deeplearning4j_tpu.version import __version__  # noqa: E402
 
 # Convenience top-level re-exports (lazy-ish: keep light to not force jax init
 # ordering issues; submodules import jax themselves).
-from deeplearning4j_tpu.nn.config import (
+from deeplearning4j_tpu.nn.config import (  # noqa: E402
     NeuralNetConfiguration,
     SequentialConfig,
     GraphConfig,
 )
-from deeplearning4j_tpu.nn.model import SequentialModel, GraphModel
+from deeplearning4j_tpu.nn.model import SequentialModel, GraphModel  # noqa: E402
+from deeplearning4j_tpu.observability import trace as _trace  # noqa: E402
 
 __all__ = [
     "__version__",
@@ -35,3 +40,8 @@ __all__ = [
     "SequentialModel",
     "GraphModel",
 ]
+
+# what importing the package cost, as a span of the host timeline
+_trace.record_span(
+    "import.deeplearning4j_tpu", trace_id=_trace.new_id(),
+    start=_trace.from_perf_counter(_IMPORT_STARTED), end=_trace.now())

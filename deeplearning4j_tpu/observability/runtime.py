@@ -13,7 +13,12 @@ Three signal sources:
   ``backend_compile_duration`` events — count + wall time per
   recompile, so a serving warmup that misses a batch bucket (every miss
   is a fresh compile on the request path) is visible in the scrape
-  rather than only as a latency outlier.
+  rather than only as a latency outlier. The same listener keeps each
+  event of a compilation's stages (trace, lowering, backend compile,
+  cache read) with its end on ``trace.now()``'s clock, the function's
+  name, the thread and whether the persistent cache held the program
+  (:func:`compile_events`), so a span of the host timeline
+  (``observability/trace.py``) can say what compiled inside it.
 - **Explicit**: :func:`record_transfer` counters the instrumented hot
   paths call with the byte counts they move (Trainer.fit's batch
   device_put, ParallelInference's per-dispatch H2D/D2H, checkpoint
@@ -45,9 +50,11 @@ from __future__ import annotations
 
 import re
 import threading
+from collections import deque
 from typing import Callable, Dict, List, Optional
 
 from deeplearning4j_tpu.observability import metrics as _metrics
+from deeplearning4j_tpu.observability import trace as _trace
 from deeplearning4j_tpu.observability.vocab import scope_of, subscope_of
 
 # memory_stats keys worth a gauge (present on TPU PJRT; CPU returns {}).
@@ -162,28 +169,98 @@ _collector_lock = threading.Lock()
 _listener_installed = False
 
 
+# the stages of a compilation that jax times, by the event's last name
+_COMPILE_STAGES = frozenset({
+    "jaxpr_trace_duration", "jaxpr_to_mlir_module_duration",
+    "backend_compile_duration", "cache_retrieval_time_sec",
+    "compile_time_saved_sec"})
+_COMPILE_EVENTS: deque = deque(maxlen=4096)
+# tracing a model's step traces every jnp function it calls as a function
+# of its own (3,744 such events in gpt2_small's step, microseconds each,
+# all inside the step's own event, which counts their time): a trace
+# shorter than this is not kept
+_TRACE_FLOOR_S = 1e-3
+_CACHE_VERDICTS = {"/jax/compilation_cache/cache_hits": "hit",
+                   "/jax/compilation_cache/cache_misses": "miss"}
+_CACHE_COUNTS = {"hit": 0, "miss": 0}
+# a lock of its own, held for the count alone: a compile can start under
+# any other lock of this module (program_table() fetches a program's text,
+# a read of the cache, with _programs_lock held)
+_cache_counts_lock = threading.Lock()
+# per thread: the cache's verdict and the cache's own timings since the
+# thread's last backend compile, which closes the compilation they belong
+# to (jax reports them first, and without the function's name)
+_compiling = threading.local()
+
+
 def _dispatch_event(event: str, duration: float, **kw):
-    c = _collector
-    if (c is not None and _metrics.enabled()
-            and event.endswith("backend_compile_duration")):
-        try:
-            c.on_compile(duration)
-        except Exception:  # noqa: BLE001 - telemetry never breaks compiles
-            pass
+    if not _metrics.enabled():
+        return
+    kind = event.rsplit("/", 1)[-1]
+    if kind not in _COMPILE_STAGES or (
+            kind == "jaxpr_trace_duration" and duration < _TRACE_FLOOR_S):
+        return
+    row = {"kind": kind, "seconds": float(duration), "end": _trace.now(),
+           "fun_name": kw.get("fun_name"),
+           "thread": threading.current_thread().name}
+    if kind == "backend_compile_duration":
+        row["cache"] = _compiling.__dict__.pop("cache", None)
+        for earlier in _compiling.__dict__.pop("rows", ()):
+            earlier["fun_name"] = row["fun_name"]
+        c = _collector
+        if c is not None:
+            try:
+                c.on_compile(duration)
+            except Exception:  # noqa: BLE001 - telemetry never breaks compiles
+                pass
+    elif row["fun_name"] is None:
+        _compiling.__dict__.setdefault("rows", []).append(row)
+    _COMPILE_EVENTS.append(row)
 
 
-def _install_listener():
-    """Register the module-level listener once per process. jax has no
-    unregister, so the listener is a fixed dispatcher that forwards to
-    the CURRENT collector — registry resets swap the target, never
-    stack callbacks."""
+def _dispatch_cache_event(event: str, **kw):
+    verdict = _CACHE_VERDICTS.get(event)
+    if verdict is not None and _metrics.enabled():
+        _compiling.cache = verdict
+        with _cache_counts_lock:
+            _CACHE_COUNTS[verdict] += 1
+
+
+def watch_compiles():
+    """Register the module-level listeners once per process (whoever
+    compiles first calls this: a ``Trainer``, a collector). jax has no
+    unregister, so they are fixed dispatchers that forward to the CURRENT
+    collector — registry resets swap the target, never stack callbacks."""
     global _listener_installed
     if _listener_installed:
         return
     import jax.monitoring
 
     jax.monitoring.register_event_duration_secs_listener(_dispatch_event)
+    jax.monitoring.register_event_listener(_dispatch_cache_event)
     _listener_installed = True
+
+
+def compile_events() -> List[dict]:
+    """Every compilation stage jax timed since :func:`watch_compiles`, in
+    the order they ended: ``{"kind", "seconds", "end", "fun_name",
+    "thread"}`` with ``end`` on ``trace.now()``'s clock and ``kind`` one of
+    ``jaxpr_trace_duration``, ``jaxpr_to_mlir_module_duration``,
+    ``backend_compile_duration`` (which has ``cache``: ``"hit"``,
+    ``"miss"`` or None where no persistent cache was asked),
+    ``cache_retrieval_time_sec`` and ``compile_time_saved_sec``; traces
+    under a millisecond (the jnp functions inside a traced function, whose
+    own event holds their time) are left out. A bounded list that outlives
+    whoever compiled, as the program table does."""
+    return [dict(row) for row in list(_COMPILE_EVENTS)]
+
+
+def cache_counts() -> Dict[str, int]:
+    """How often the persistent compilation cache held a program asked of
+    it (``hit``) and how often one was compiled and written to it
+    (``miss``)."""
+    with _cache_counts_lock:
+        return dict(_CACHE_COUNTS)
 
 
 def get_runtime_collector() -> RuntimeCollector:
@@ -193,7 +270,7 @@ def get_runtime_collector() -> RuntimeCollector:
     with _collector_lock:
         if _collector is None:
             _collector = RuntimeCollector()
-            _install_listener()
+            watch_compiles()
     return _collector
 
 
@@ -345,8 +422,10 @@ def publish_program(module: str, *, flops: Optional[float],
 
 def _resolve(module: str, entry: dict):
     """Fetch and parse a pending entry's text, in place."""
-    text = entry["text"]()
-    _, (scopes, subscopes) = _scopes_of_hlo(text, (scope_of, subscope_of))
+    with _trace.span("program_table.resolve", module=module):
+        text = entry["text"]()
+        _, (scopes, subscopes) = _scopes_of_hlo(
+            text, (scope_of, subscope_of))
     carries = entry["carries"]
     del entry["text"], entry["carries"]
     entry["scopes"] = scopes

@@ -50,6 +50,19 @@ on their clock; ``span()`` opens one too, so every span recorded here is
 also there. While no profiler runs an annotation costs a fraction of a
 microsecond, and it is a no-op while ``jax`` has not been imported.
 
+**The host's side of a training run** (:class:`Timeline`,
+:class:`IterationLegs`): a fit loop runs thousands of iterations of five
+legs each, too many for a :class:`Span` apiece on the hot path. The loop
+opens its legs through one :class:`IterationLegs`, which annotates each
+(as above), reads ``time.perf_counter`` once at each boundary, feeds the
+``train_step_seconds`` / ``train_data_read_seconds`` histograms and keeps
+one tuple an iteration in the process :class:`Timeline`, a bounded ring
+under a root entry for each fit. :meth:`Timeline.spans` turns a fit's rows
+into spans through :func:`record_span` when somebody asks (``train.fit``
+-> ``train.step`` -> the four legs, one trace id a fit), and
+``observability/runtime.compile_events()`` has the compilations on the
+same clock.
+
 Stdlib only at import; safe to import from any layer (the retention
 policy's rolling baseline is imported lazily from
 ``observability.sentinel``, and ``annotate`` looks ``jax`` up in
@@ -72,11 +85,17 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 # across threads and meaningful as dates, but never go backwards the way
 # raw time.time() can under NTP slew.
 _T0 = time.time() - time.perf_counter()
+_clock = time.perf_counter
 
 
 def now() -> float:
     """Trace timestamp (seconds, wall-anchored monotonic)."""
     return _T0 + time.perf_counter()
+
+
+def from_perf_counter(t: float) -> float:
+    """A ``time.perf_counter`` reading as a trace timestamp."""
+    return _T0 + t
 
 
 # Span ids are minted on the serving hot path; uuid4 costs ~8 µs a call,
@@ -491,6 +510,243 @@ def record_span(name: str, *, start: float, end: float, trace_id: str,
              attrs=dict(attrs))
     _route(s, tracer)
     return s
+
+
+# -- the host's side of a training run ------------------------------------------
+
+FIT = "train.fit"
+ITERATION = "train.step"
+# the legs of an iteration, in the order they run; each ends where the
+# next begins, so a row holds one clock reading a boundary
+LEGS = ("train.read", "train.put", "train.dispatch", "train.listeners")
+ROWS_KEPT = 16384  # iterations, over all fits; the longest window is ~1,550
+FITS_KEPT = 256
+
+
+class Fit:
+    """A fit's root entry: ``start`` and ``end`` are ``perf_counter``
+    readings (``end`` None while the loop runs), ``steps`` the rows it
+    wrote, ``id`` the trace id its spans share."""
+
+    __slots__ = ("id", "start", "end", "steps", "thread")
+
+    def __init__(self):
+        self.id = new_id()
+        self.start = _clock()
+        self.end: Optional[float] = None
+        self.steps = 0
+        self.thread = threading.current_thread().name
+
+
+class Timeline:
+    """Every fit loop's iterations, one row each: ``(fit id, step number,
+    iteration start, end of train.read, of train.put, of train.dispatch,
+    of train.listeners)``, ``perf_counter`` readings, in a ring that drops
+    the oldest. Written by :class:`IterationLegs` without a lock (a deque's
+    ``append`` is atomic); read through :meth:`fits` and :meth:`spans`."""
+
+    def __init__(self, rows_kept: int = ROWS_KEPT):
+        self._rows: deque = deque(maxlen=rows_kept)
+        self._fits: deque = deque(maxlen=FITS_KEPT)
+
+    def open_fit(self) -> Fit:
+        fit = Fit()
+        self._fits.append(fit)
+        return fit
+
+    def fits(self) -> List[Fit]:
+        return list(self._fits)
+
+    def rows(self, fit: Fit) -> List[tuple]:
+        """The rows of ``fit`` that the ring still holds, oldest first."""
+        return [r for r in list(self._rows) if r[0] == fit.id]
+
+    def clear(self):
+        self._rows.clear()
+        self._fits.clear()
+
+    def spans(self, fit: Optional[Fit] = None, *,
+              tracer: Optional[Tracer] = None) -> List[Span]:
+        """The spans of one fit (the last, unless told): ``train.fit``,
+        under it a ``train.step`` for each row, under that the four legs,
+        all with the fit's id as trace id. They are made here, through
+        :func:`record_span`, into ``tracer``; without one they go to a
+        ring of their own, made to hold them, and not to the process ring,
+        which a window's nine thousand spans would flush."""
+        if fit is None:
+            if not self._fits:
+                return []
+            fit = self._fits[-1]
+        rows = self.rows(fit)
+        if tracer is None:
+            tracer = Tracer(capacity=1 + 5 * len(rows))
+        end = fit.end
+        if end is None:  # still running
+            end = rows[-1][6] if rows else _clock()
+        root = record_span(
+            FIT, start=_T0 + fit.start, end=_T0 + end, trace_id=fit.id,
+            thread=fit.thread, tracer=tracer, steps=fit.steps)
+        out = [root]
+        for _, step, *marks in rows:
+            it = record_span(
+                ITERATION, start=_T0 + marks[0], end=_T0 + marks[4],
+                trace_id=fit.id, parent_id=root.span_id, thread=fit.thread,
+                tracer=tracer, step=step)
+            out.append(it)
+            for name, lo, hi in zip(LEGS, marks, marks[1:]):
+                out.append(record_span(
+                    name, start=_T0 + lo, end=_T0 + hi, trace_id=fit.id,
+                    parent_id=it.span_id, thread=fit.thread, tracer=tracer))
+        return out
+
+
+_TIMELINE = Timeline()
+
+
+def get_timeline() -> Timeline:
+    return _TIMELINE
+
+
+_annotate = annotate  # IterationLegs takes a parameter of the name
+
+
+class _Leg:
+    """One leg of the iteration as a context manager, made once a fit: an
+    annotation around the block and, where the timeline is on, the clock
+    at the block's end into ``marks[slot]``."""
+
+    __slots__ = ("_name", "_annotate", "_marks", "_slot", "_open")
+
+    def __init__(self, name: str, annotate, marks: Optional[list],
+                 slot: int = 0):
+        self._name, self._annotate = name, annotate
+        self._marks, self._slot = marks, slot
+        self._open = None
+
+    def __enter__(self):
+        self._open = self._annotate(self._name)
+        self._open.__enter__()
+
+    def __exit__(self, et, ev, tb):
+        self._open.__exit__(et, ev, tb)
+        if self._marks is not None:
+            self._marks[self._slot] = _clock()
+        return False
+
+
+class _DispatchLeg(_Leg):
+    """``train.dispatch``: its start is ``train.put``'s end; a dispatch
+    that returns makes the iteration a step, with its two histogram
+    readings."""
+
+    __slots__ = ("_legs",)
+
+    def __enter__(self):
+        if self._marks is not None:
+            self._marks[2] = _clock()
+        self._open = self._annotate(self._name)
+        self._open.__enter__()
+
+    def __exit__(self, et, ev, tb):
+        self._open.__exit__(et, ev, tb)
+        marks = self._marks
+        if marks is not None and et is None:
+            # the listeners' mark too: a loop that opens no such leg, or
+            # leaves it by an exception, still writes an ordered row
+            marks[3] = marks[4] = t = _clock()
+            legs = self._legs
+            legs.read_s = read_s = marks[1] - marks[0]
+            legs.step_s = step_s = t - marks[2]
+            legs._om.data_read_seconds.observe(read_s)
+            legs._om.step_seconds.observe(step_s)
+            legs._stepped = True
+        return False
+
+
+class _IterationLeg(_Leg):
+    """``train.step``, called with the step's number: the iteration's
+    start on entry, its row on exit if a step was dispatched in it."""
+
+    __slots__ = ("_legs", "_step")
+
+    def __call__(self, step_num: int) -> "_IterationLeg":
+        self._step = step_num
+        return self
+
+    def __enter__(self):
+        self._open = self._annotate(self._name, step_num=self._step)
+        self._open.__enter__()
+        if self._marks is not None:
+            self._marks[0] = _clock()
+
+    def __exit__(self, et, ev, tb):
+        self._open.__exit__(et, ev, tb)
+        legs = self._legs
+        if legs._stepped:
+            legs._stepped = False
+            legs._fit.steps += 1
+            legs._append((legs._fit.id, self._step, *self._marks))
+        return False
+
+
+class IterationLegs:
+    """What a fit loop opens its iteration and its legs through, made once
+    a fit and shared by ``Trainer.fit`` and ``FaultTolerantTrainer.fit``::
+
+        legs = IterationLegs(om)
+        try:
+            while ...:
+                with legs.step(n):
+                    with legs.read: ...
+                    with legs.put: ...
+                    with legs.dispatch: ...
+                    with legs.listeners: ...
+        finally:
+            legs.close()
+
+    Every leg is an ``annotate(name)`` (``HOST_SPANS``), so a running
+    profiler shows it. With ``om`` (the training metrics bundle; None while
+    ``metrics.enabled()`` is off) the boundaries between the legs are read
+    off ``time.perf_counter`` once each: the iteration's start, the end of
+    the read, the start and the end of the dispatch, the end of the
+    listeners; ``train.put`` is what lies between the read and the
+    dispatch. A dispatch that returns observes ``read_s`` and ``step_s``
+    (kept here for the caller) in ``om.data_read_seconds`` and
+    ``om.step_seconds``, and the iteration's exit then appends its row to
+    the timeline; an iteration that dispatched nothing (the feed's end, a
+    skipped batch, a step rolled back) leaves neither. No lock, no device
+    sync, and nothing allocated beyond the annotations and the row.
+    ``annotate`` is the factory of the annotations, for a tool that times
+    the legs its own way."""
+
+    def __init__(self, om, *, annotate=annotate,
+                 timeline: Optional[Timeline] = None):
+        self._om = om
+        self._stepped = False
+        self.read_s = self.step_s = 0.0
+        if om is None:
+            self._fit = marks = self._append = None
+        else:
+            timeline = timeline if timeline is not None else _TIMELINE
+            self._fit = timeline.open_fit()
+            marks = [self._fit.start] * 5
+            self._append = timeline._rows.append
+        leg = step = annotate
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if annotate is _annotate and profiler is not None:
+            # what annotate() would look up again at every leg
+            leg, step = profiler.TraceAnnotation, profiler.StepTraceAnnotation
+        self.step = _IterationLeg(ITERATION, step, marks)
+        self.read = _Leg(LEGS[0], leg, marks, 1)
+        self.put = _Leg(LEGS[1], leg, None)
+        self.dispatch = _DispatchLeg(LEGS[2], leg, marks)
+        self.listeners = _Leg(LEGS[3], leg, marks, 4)
+        self.step._legs = self.dispatch._legs = self
+
+    def close(self):
+        """The fit's end, from its ``finally``."""
+        if self._fit is not None and self._fit.end is None:
+            self._fit.end = _clock()
 
 
 # -- JSONL / Chrome-trace conversion ----------------------------------------

@@ -267,10 +267,20 @@ KERNEL_NAMES = frozenset({
 })
 
 # ``observability.trace.annotate`` on the host side: spans on the profiler's
-# own clock (the host plane's ``python`` line), beside the device's.
+# own clock (the host plane's ``python`` line), beside the device's. The
+# ``train.*`` iteration and its legs are also rows of the host timeline
+# (``observability/trace.py::Timeline``, under ``train.fit``), and the
+# set-up phases are ``trace.span()``s: in the process ring and, while a
+# profiler runs, on its host plane.
 HOST_SPANS = frozenset({
+    "train.fit",
     "train.step", "train.read", "train.put", "train.dispatch",
     "train.listeners",
+    "import.deeplearning4j_tpu",   # the package's __init__, first to last line
+    "train.init_state",            # Trainer.init_state
+    "train.step_cost_analysis",    # Trainer.step_flops' thread: lower, compile,
+                                   # cost_analysis
+    "program_table.resolve",       # runtime.program_table: as_text() and parse
     "generation.prefill", "generation.decode", "generation.graft",
 })
 

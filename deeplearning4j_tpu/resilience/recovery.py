@@ -34,13 +34,12 @@ from __future__ import annotations
 import dataclasses
 import json
 import math
-import time
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Set, Tuple
 
 import jax
 
-from deeplearning4j_tpu.observability.trace import annotate
+from deeplearning4j_tpu.observability.trace import IterationLegs, annotate
 
 
 class NonFiniteLossError(RuntimeError):
@@ -312,6 +311,12 @@ class FaultTolerantTrainer:
             from deeplearning4j_tpu.train.trainer import _StepTelemetry
 
             tele = _StepTelemetry(tr, tm)
+        # the same legs as Trainer.fit, through the same helper: spans on
+        # the profiler's clock and, with the metrics on, the timing
+        # histograms and the host timeline's rows. The read is timed so the
+        # starvation detector sees FT runs too; a skipped batch and a step
+        # rolled back leave no row and no reading
+        legs = IterationLegs(tm, annotate=annotate)
         for lst in listeners:
             lst.on_fit_start(tr, ts)
         # incident pipeline: arm the "train" device-capture hook for the
@@ -340,19 +345,12 @@ class FaultTolerantTrainer:
                 b = 0
                 it = iter(data)
                 while True:
-                    # the same spans on the profiler's clock as Trainer.fit
-                    with annotate("train.step", step_num=host_step + 1):
-                        # manual next(): the read is timed so the starvation
-                        # detector sees FT runs too (Trainer.fit measures the
-                        # same leg)
-                        t_read = time.perf_counter() if tm is not None else 0.0
-                        with annotate("train.read"):
-                            try:
+                    with legs.step(host_step + 1):
+                        try:
+                            with legs.read:
                                 batch = next(it)
-                            except StopIteration:
-                                break
-                        read_s = (time.perf_counter() - t_read
-                                  if tm is not None else 0.0)
+                        except StopIteration:
+                            break
                         if b < skip_batches:
                             b += 1
                             continue
@@ -375,22 +373,24 @@ class FaultTolerantTrainer:
                             inj.maybe_fail("train.worker_kill")
                             batch = inj.maybe_poison_batch(batch)
                         if tr._batch_sharding is not None:
-                            with annotate("train.put"):
+                            with legs.put:
                                 batch = jax.device_put(
                                     batch, tr._batch_sharding)
                         new_ts = None
-                        t_step = time.perf_counter() if tm is not None else 0.0
                         try:
-                            with annotate("train.dispatch"):
+                            # the leg holds the loss check, as step_seconds
+                            # always has here
+                            with legs.dispatch:
                                 new_ts, metrics = self._step_fn(ts, batch)
-                            if pol.check_every and \
-                                    (host_step + 1) % pol.check_every == 0:
-                                loss = float(jax.device_get(
-                                    metrics["total_loss"]))
-                                if not math.isfinite(loss):
-                                    raise NonFiniteLossError(
-                                        f"non-finite loss {loss} at step "
-                                        f"{host_step + 1}", step=host_step + 1)
+                                if pol.check_every and \
+                                        (host_step + 1) % pol.check_every == 0:
+                                    loss = float(jax.device_get(
+                                        metrics["total_loss"]))
+                                    if not math.isfinite(loss):
+                                        raise NonFiniteLossError(
+                                            f"non-finite loss {loss} at step "
+                                            f"{host_step + 1}",
+                                            step=host_step + 1)
                         except nan_types as e:
                             rollbacks += 1
                             key = (epoch, b)
@@ -426,20 +426,18 @@ class FaultTolerantTrainer:
                         note_train_step()  # armed incident capture boundary
                         touch_heartbeat()  # supervisor hang-detector beacon
                         if tm is not None:
-                            step_s = time.perf_counter() - t_step
-                            tm.step_seconds.observe(step_s)
-                            tm.data_read_seconds.observe(read_s)
                             tm.steps_total.inc()
                             feats = jax.tree_util.tree_leaves(
                                 batch["features"])
                             tm.samples_total.inc(feats[0].shape[0])
-                            tele.on_step(ts, batch, read_s, step_s, host_step)
+                            tele.on_step(ts, batch, legs.read_s, legs.step_s,
+                                         host_step)
                         b += 1
                         if pol.checkpoint_every and \
                                 host_step % pol.checkpoint_every == 0:
                             self._save(ts, epoch=epoch, batch_in_epoch=b,
                                        tag="auto")
-                        with annotate("train.listeners"):
+                        with legs.listeners:
                             for lst in listeners:
                                 if lst.on_iteration(epoch, host_step, ts,
                                                     metrics):
@@ -468,6 +466,7 @@ class FaultTolerantTrainer:
                     self._save(ts, epoch=epoch, batch_in_epoch=0,
                                tag=f"epoch{epoch - 1}")
         finally:
+            legs.close()
             exit_training()
             tr._upd_update = self._orig_upd
             for lst in listeners:

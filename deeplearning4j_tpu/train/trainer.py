@@ -16,7 +16,6 @@ import atexit
 import dataclasses
 import os
 import threading
-import time
 from collections import deque
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 
@@ -346,6 +345,9 @@ class Trainer:
         self._step_cost_lock = threading.Lock()
         # module name of the step the last analysis described
         self._step_module: Optional[str] = None
+        # whatever this trainer compiles from here on is an event with its
+        # time, stage and function (observability/runtime.compile_events)
+        _watch_compiles()
 
     # -- analytic step cost (observability) ---------------------------------
 
@@ -396,9 +398,10 @@ class Trainer:
                 if _COST_SHUTDOWN.is_set():
                     result = "failed"  # process exiting: never start a
                 else:                  # compile the exit would tear down
-                    lowered = lower()
-                    costs = normalize_cost_analysis(
-                        lowered.compile().cost_analysis())
+                    with _span("train.step_cost_analysis"):
+                        lowered = lower()
+                        costs = normalize_cost_analysis(
+                            lowered.compile().cost_analysis())
                     flops = float(costs.get("flops") or 0.0)
                     result = flops if flops > 0 else "failed"
                     # what the step is made of: its text is fetched when
@@ -686,17 +689,18 @@ class Trainer:
     # -- state construction ------------------------------------------------
 
     def init_state(self, variables=None, seed: Optional[int] = None) -> TrainState:
-        variables = variables if variables is not None else self.model.init(seed)
-        seed = self.net.seed if seed is None else seed
-        ts = TrainState(
-            params=variables["params"],
-            model_state=variables["state"],
-            opt_state=self._upd_init(variables["params"]),
-            step=jnp.zeros((), jnp.int32),
-            rng=jax.random.key(
-                seed, impl=getattr(self.net, "rng_impl", None)),
-        )
-        return ts
+        with _span("train.init_state"):
+            variables = (variables if variables is not None
+                         else self.model.init(seed))
+            seed = self.net.seed if seed is None else seed
+            return TrainState(
+                params=variables["params"],
+                model_state=variables["state"],
+                opt_state=self._upd_init(variables["params"]),
+                step=jnp.zeros((), jnp.int32),
+                rng=jax.random.key(
+                    seed, impl=getattr(self.net, "rng_impl", None)),
+            )
 
     def variables(self, ts: TrainState):
         return {"params": ts.params, "state": ts.model_state}
@@ -714,6 +718,18 @@ class Trainer:
     ) -> TrainState:
         listeners = listeners or []
         wmetrics: List[Dict[str, jax.Array]] = []
+        # Shared-registry telemetry (observability/metrics.py): step/read
+        # timing + throughput counters, sampled once per fit so a disabled
+        # switch costs nothing in the loop. None of it syncs the device —
+        # step_seconds measures the host loop's dispatch pace.
+        om = _training_metrics()
+        # the iteration and its legs: spans on the profiler's clock (a
+        # device trace names the host's part of every idle gap) and, while
+        # instrumentation is on, the two timing histograms and a row of the
+        # host timeline under this fit's root entry, which opens here; each
+        # boundary is read off the clock once
+        # (observability/trace.IterationLegs)
+        legs = _IterationLegs(om, annotate=_annotate)
         # persistent compile cache (JAX_COMPILATION_CACHE_DIR): a
         # supervisor-relaunched or re-expanded worker restores its step
         # programs from disk instead of recompiling — activation is
@@ -730,11 +746,6 @@ class Trainer:
         # One host sync up front; after that the step counter is tracked
         # host-side so the dispatch pipeline never blocks on the device.
         host_step = int(jax.device_get(ts.step))
-        # Shared-registry telemetry (observability/metrics.py): step/read
-        # timing + throughput counters, sampled once per fit so a disabled
-        # switch costs nothing in the loop. None of it syncs the device —
-        # step_seconds measures the host loop's dispatch pace.
-        om = _training_metrics()
         tele = _StepTelemetry(self, om) if om is not None else None
         # incident pipeline: while a fit loop is live, the sentinel's
         # "train" profile hook can capture the NEXT N steps on demand
@@ -751,20 +762,12 @@ class Trainer:
                 it = iter(data)
                 n = 0
                 while True:
-                    # the iteration and its legs are spans on the
-                    # profiler's clock (observability/trace.annotate): a
-                    # device trace names the host's part of every idle gap
-                    with _annotate("train.step", step_num=host_step + 1):
-                        t_read = time.perf_counter() if om is not None else 0.0
-                        with _annotate("train.read"):
-                            try:
+                    with legs.step(host_step + 1):
+                        try:
+                            with legs.read:
                                 batch = next(it)
-                            except StopIteration:
-                                break
-                        read_s = (time.perf_counter() - t_read
-                                  if om is not None else 0.0)
-                        if om is not None:
-                            om.data_read_seconds.observe(read_s)
+                        except StopIteration:
+                            break
                         batch = _as_batch_dict(batch)
                         if _fault_injector().enabled:
                             # "train.worker_kill" (SIGKILL/raise at the N-th
@@ -778,11 +781,10 @@ class Trainer:
                         if self._batch_sharding is not None:
                             if om is not None:
                                 _record_batch_transfer(batch)
-                            with _annotate("train.put"):
+                            with legs.put:
                                 batch = jax.device_put(
                                     batch, self._batch_sharding)
-                        t_step = time.perf_counter() if om is not None else 0.0
-                        with _annotate("train.dispatch"):
+                        with legs.dispatch:
                             if getattr(self.net, "backprop_type",
                                        "standard") == "tbptt":
                                 # ↔ TruncatedBPTT: every window is an
@@ -793,13 +795,11 @@ class Trainer:
                                 ts, metrics = self.train_step(ts, batch)
                                 wmetrics = [metrics]
                         if om is not None:
-                            step_s = time.perf_counter() - t_step
-                            om.step_seconds.observe(step_s)
                             om.steps_total.inc(len(wmetrics))
                             feats = jax.tree_util.tree_leaves(
                                 batch["features"])
                             om.samples_total.inc(feats[0].shape[0])
-                            tele.on_step(ts, batch, read_s, step_s,
+                            tele.on_step(ts, batch, legs.read_s, legs.step_s,
                                          host_step + len(wmetrics))
                         n += 1
                         # step boundary for an armed incident device capture
@@ -814,7 +814,7 @@ class Trainer:
                         # wide trace id (runtime/distributed.py; a bare
                         # global int store)
                         _note_step(host_step + len(wmetrics))
-                        with _annotate("train.listeners"):
+                        with legs.listeners:
                             for wm in wmetrics:
                                 host_step += 1
                                 for lst in listeners:
@@ -841,6 +841,7 @@ class Trainer:
                 if stop:
                     break
         finally:
+            legs.close()
             _incidents_exit_training()
             for lst in listeners:
                 lst.on_fit_end(self, ts)
@@ -976,9 +977,14 @@ from deeplearning4j_tpu.observability.incidents import (  # noqa: E402
 from deeplearning4j_tpu.resilience.cluster import touch_heartbeat as _touch_heartbeat  # noqa: E402
 from deeplearning4j_tpu.resilience.faults import get_fault_injector as _fault_injector  # noqa: E402
 from deeplearning4j_tpu.runtime.distributed import note_step as _note_step  # noqa: E402
-from deeplearning4j_tpu.observability.trace import annotate as _annotate  # noqa: E402
+from deeplearning4j_tpu.observability.trace import (  # noqa: E402
+    IterationLegs as _IterationLegs,
+    annotate as _annotate,
+    span as _span,
+)
 from deeplearning4j_tpu.observability.runtime import (  # noqa: E402
     program_table as _program_table,
     publish_program as _publish_program,
     publish_step_counters as _publish_step_counters,
+    watch_compiles as _watch_compiles,
 )

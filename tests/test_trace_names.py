@@ -469,9 +469,16 @@ def test_a_text_that_cannot_be_had_is_no_entry(monkeypatch):
 
 
 def test_every_annotated_span_of_the_package_is_declared():
-    found = set()
+    """Every literal ``annotate("...")``, the timeline's own names (the
+    fit, the iteration, its legs) and the set-up phases, which are
+    ``span()``s and one ``record_span()`` under the prefixes of the
+    training side."""
+    found = {trace.FIT, trace.ITERATION, *trace.LEGS}
     for path in glob.glob(os.path.join(ROOT, "deeplearning4j_tpu", "**",
                                        "*.py"), recursive=True):
         with open(path, encoding="utf-8") as f:
-            found.update(re.findall(r'annotate\(\s*"([\w.]+)"', f.read()))
+            text = f.read()
+        found.update(re.findall(r'annotate\(\s*"([\w.]+)"', text))
+        found.update(re.findall(
+            r'span\(\s*"((?:train|import|program_table)\.[\w.]+)"', text))
     assert found == vocab.HOST_SPANS
