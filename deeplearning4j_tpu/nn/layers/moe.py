@@ -31,7 +31,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from deeplearning4j_tpu.kernels._dispatch import interpret, use_pallas
 from deeplearning4j_tpu.nn.activations import get_activation
@@ -299,27 +298,92 @@ def _piece_rows(pairs, held, total):
     return min(pairs, -(-rows // tile) * tile)
 
 
+def _slice_of(ordered, first, rows):
+    """``ordered[first:first + rows]`` of a number a sorted pair, with zeros
+    past the last pair."""
+    if rows == ordered.shape[0]:
+        return ordered
+    return jax.lax.dynamic_slice_in_dim(
+        jnp.pad(ordered, (0, -ordered.shape[0] % rows)), first, rows)
+
+
 def _piece_of(order, sizes, first, rows):
     """Of the sorted rows ``[first, first + rows)``: the pair in each
     (``order`` there; past the last pair a piece is padded with rows of no
     group) and the rows of each group, last those of no group."""
-    pairs = order.shape[0]
-    if rows == pairs:
+    if rows == order.shape[0]:
         return order, sizes
-    span = jax.lax.dynamic_slice_in_dim(
-        jnp.pad(order, (0, -pairs % rows)), first, rows)
+    span = _slice_of(order, first, rows)
     ends = jnp.cumsum(sizes[:-1])
     here = (jnp.clip(ends, first, first + rows)
             - jnp.clip(ends - sizes[:-1], first, first + rows))
     return span, jnp.append(here, rows - jnp.sum(here))
 
 
+# How a token-expert pair finds what belongs to its expert, as the flight
+# event ``kernel.grouped_product`` names it (``lookup``).
+LOOKUP_A_PAIR = "compare_select"
+
+
+def _of_chosen(prob, chosen):
+    """Each token's row of ``prob`` [M, experts] at its chosen experts ([M],
+    or [M, top_k]), by no index: the experts' numbers are compared with the
+    chosen one and the row's entries selected and summed over the experts.
+    One term of each sum is not zero, so it is the entry to the bit, and
+    its transpose gives each (token, expert) one term at most, a token's
+    experts being distinct. XLA fuses it as an elementwise pass with a
+    reduction where an index is a gather of scalars (and its gradient a
+    scatter of them), which the chip runs one number at a time. The
+    barrier keeps that pass out of its consumer's fusion: fused with the
+    sum over a token's shares, the two reductions become one over all the
+    [top_k, experts] terms, which adds the shares in another order and
+    runs five times slower (PERF.md section 5, 2b)."""
+    picked = chosen[..., None] == jnp.arange(prob.shape[-1])
+    rows = jnp.expand_dims(prob, tuple(range(1, chosen.ndim)))
+    return jax.lax.optimization_barrier(
+        jnp.sum(jnp.where(picked, rows, 0.0), axis=-1))
+
+
+def _place_of(chosen, experts_held):
+    """Each chosen expert's place among ``experts_held``, and their count
+    for one held elsewhere: a compare and a select an expert held, so an
+    elementwise pass over the pairs with no table to index and nothing to
+    reduce."""
+    local = jnp.full(chosen.shape, len(experts_held), jnp.int32)
+    for at, expert in enumerate(experts_held):
+        local = jnp.where(chosen == expert, at, local)
+    return local
+
+
+@jax.custom_vjp
+def _sorted_by_place(local, weight):
+    """The pairs in the order of ``local`` (stable: ``jnp.argsort``'s) and
+    their ``weight``s in that order, carried through the one sort; the
+    weights' gradient is sorted back by the same order, so that no pair's
+    number is gathered or scattered by index."""
+    _, order, carried = jax.lax.sort(
+        (local, jnp.arange(local.shape[0], dtype=jnp.int32), weight),
+        num_keys=1)
+    return order, carried
+
+
+def _sorted_by_place_fwd(local, weight):
+    order, carried = _sorted_by_place(local, weight)
+    return (order, carried), order
+
+
+_sorted_by_place.defvjp(
+    _sorted_by_place_fwd,
+    lambda order, g: (None, jax.lax.sort((order, g[1]), num_keys=1)[1]))
+
+
 def _record_grouped_product(pairs, m, k, n, groups):
     """One ``kernel.grouped_product`` flight event per layer, at trace
     time: which product the experts run through, its tiles, the pieces of
-    ``m`` rows that the ``pairs`` sorted rows are walked in, and under
+    ``m`` rows that the ``pairs`` sorted rows are walked in, under
     ``combine`` how a piece's rows are summed into their tokens
-    (``"inverse_gather"`` where one piece holds all the pairs)."""
+    (``"inverse_gather"`` where one piece holds all the pairs), and under
+    ``lookup`` how a pair finds its expert's probability and place."""
     from deeplearning4j_tpu.observability.flightrecorder import record_event
 
     on_chip = use_pallas()
@@ -329,7 +393,7 @@ def _record_grouped_product(pairs, m, k, n, groups):
         tiles=list(_tiles(m, k, n)) if on_chip else None,
         rows=pairs, rows_a_piece=m, pieces=-(-pairs // m),
         combine="inverse_gather" if m == pairs else COMBINE_A_PIECE,
-        inner=k, columns=n, groups=groups)
+        lookup=LOOKUP_A_PAIR, inner=k, columns=n, groups=groups)
 
 
 _GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
@@ -356,9 +420,14 @@ class RoutedExperts(LayerConfig):
     ``apply`` is given others (``router_input``: a layer whose router
     stands before its attention reads the layer's input).
 
-    The token-expert pairs are sorted by the expert's place among those
-    held (a pair whose expert is held elsewhere sorts last and adds
-    nothing here), and the sorted rows are walked in pieces of
+    Nothing is looked up by index over the token-expert pairs: a chosen
+    expert's probability and its place among the experts held come from
+    compares and selects (``_of_chosen``, ``_place_of``), and a pair's
+    weight reaches its sorted row through the sort itself
+    (``_sorted_by_place``), where gathers of scalars, which the chip runs
+    one number at a time, stood. The pairs are sorted by the expert's place
+    among those held (a pair whose expert is held elsewhere sorts last and
+    adds nothing here), and the sorted rows are walked in pieces of
     ``_piece_rows``: twice what a balanced router lands here, so one
     piece of all the pairs where half of the experts or more are held.
     A piece gathers its tokens' rows, runs the experts' three products
@@ -437,10 +506,11 @@ class RoutedExperts(LayerConfig):
             tilted = prob + jax.lax.stop_gradient(params["bias"].astype(f32))
         if self.top_k == 1:
             chosen = jnp.argmax(tilted, axis=-1)
-            share = jnp.take_along_axis(prob, chosen[:, None], axis=-1)[:, 0]
         else:
             _, chosen = jax.lax.top_k(tilted, self.top_k)
-            share = jnp.take_along_axis(prob, chosen, axis=-1)
+        # ``prob`` at ``chosen``, whether or not a bias tilted the choice
+        share = _of_chosen(prob, chosen)
+        if self.top_k > 1:
             share = share / jnp.sum(share, axis=-1, keepdims=True)
         return r, chosen, share
 
@@ -452,9 +522,6 @@ class RoutedExperts(LayerConfig):
                      else router_input.reshape(-1, router_input.shape[-1]))
         gate = _GATES[self.gate_activation]
         held, fan = len(self.experts_held), self.top_k
-        # an expert's place among those held; ``held`` for one held elsewhere
-        place = np.full((self.experts_total,), held, np.int32)
-        place[list(self.experts_held)] = np.arange(held)
         pairs = tokens.shape[0] * fan
         rows_a_piece = _piece_rows(pairs, held, self.experts_total)
         # the tokens, and the most rows that one of them has in a piece
@@ -463,8 +530,10 @@ class RoutedExperts(LayerConfig):
             r, chosen, share = self.route(
                 params, routed_on, state.get("router"))
             # a token's pairs lie side by side: pair p is of token p // fan
-            local = jnp.asarray(place)[chosen.reshape(-1)]
-            order = jnp.argsort(local)
+            local = _place_of(chosen, self.experts_held).reshape(-1)
+            # what a pair's row weighs: nothing where its expert is not here
+            weight = jnp.where(local < held, share.reshape(-1), 0.0)
+            order, weight = _sorted_by_place(local, weight)
             # each pair's row, where one piece holds them all
             inverse = (jnp.argsort(order) if rows_a_piece == pairs
                        else None)
@@ -491,8 +560,8 @@ class RoutedExperts(LayerConfig):
                     * _grouped(rows, params["up"], sizes_here))
                 out = _grouped(inner, params["down"], sizes_here)
             with jax.named_scope(SCOPE_MOE_ROUTE):
-                weight = jnp.where(local < held, share.reshape(-1), 0.0)[span]
-                out = (out * weight[:, None]).astype(x.dtype)
+                here = _slice_of(weight, first, rows_a_piece)
+                out = (out * here[:, None]).astype(x.dtype)
                 # the weighted results of one token add up
                 return _combine(out, source, inverse, *sums)
 

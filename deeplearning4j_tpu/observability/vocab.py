@@ -220,8 +220,10 @@ COMPONENT_SCOPES = (SCOPE_EMBED, SCOPE_ATTN, SCOPE_MLP, SCOPE_HEAD,
 # ``scope_of`` says.
 SCOPE_CCA_MIX = "cca_mix"          # in attn: CCA's projections, value shift,
                                    # convolutions, q-k mean, norm, rotary
-SCOPE_MOE_ROUTE = "moe_route"      # in mlp: router, argmax, sort, gather,
-                                   # scatter and weighting
+SCOPE_MOE_ROUTE = "moe_route"      # in mlp: router, argmax, the chosen experts'
+                                   # probabilities and places by compare and
+                                   # select, sort, the rows' gather, weighting
+                                   # and the sum by sorted segments
 SCOPE_MOE_EXPERTS = "moe_experts"  # in mlp: the experts' grouped products
 SCOPE_DSA_INDEX = "dsa_index"      # in attn: the indexer's projections, norm,
                                    # rotary, score product, relu, weighting

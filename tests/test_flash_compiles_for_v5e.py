@@ -226,6 +226,12 @@ def test_the_keye_cells_expert_layer_compiles_in_pieces(one_chip,
     assert not [line for line in kernels if f"[{pairs}," in line]
     assert f"[{pairs},{units}]" not in text
     assert f"[{pairs},{hidden}]" not in text
+    # no number of a pair is looked up by index (PR 38): what gathers and
+    # scatters of numbers are left take a handful a group, none an array
+    # of a piece's rows, of all the pairs or of a token's experts
+    numbers = re.findall(r"= \w+\[(\d+)\]\S* (?:gather|scatter)\(", text)
+    assert numbers and max(map(int, numbers)) < 1024
+    assert not re.search(rf"\[{tokens},128\]\S* (?:gather|scatter)\(", text)
     # the parent's layer planned 2.66 GB here (PERF.md section 6, PR 34)
     assert compiled.memory_analysis().peak_memory_in_bytes < 2.5e9
 
