@@ -92,28 +92,44 @@ def flash_block_sizes(seq_q: int, seq_k: int, head_dim: int,
                       causal: bool) -> FlashBlocks:
     """Default geometry of the three flash kernels for a call's shapes.
 
-    One rule: the largest tile, 1024 x 1024, which ``flash_attention`` cuts
-    to the sequence where that is shorter. Swept on the chip (TPU v5e,
-    ``[16, 12, 1024, 64]`` and ``[4, 12, 4096, 64]`` bf16 causal, blocks of
-    128 to 1024 each way, each kernel's device time read from a trace;
-    table in PERF.md section 5), every kernel is fastest there: their
-    per-tile work that does not grow with the tile's width (the row maxima
-    and sums, the rescale of the accumulators, the step's copies) outweighs
-    the dead pairs a large tile drags in, and a tile that the causal
-    diagonal crosses corner to corner no longer computes its dead half
-    (``flash_attention.TilePlan``: four row sub-blocks of 256 at this
-    size). That ended the one exception, ``flash_bwd_dkv`` at 512 x 512 for
-    1024 causal queries and keys, which skipped the dead quarter through
-    the grid: 0.821 ms a layer at one tile in four sub-blocks against 1.042
-    at 512 x 512 tiles in two each and 1.051 whole (PR 32, the same trace).
+    One rule: the largest tile whose operands fit, which ``flash_attention``
+    cuts to the sequence where that is shorter. The widest operand sets it:
+    ``head_dim`` is the widest of q and k's width and v's (the output and
+    the gradients are as wide as their operands), and ``flash_attention``
+    passes that. 1024 x 1024 where it is no wider than 128, 512 x 512
+    where it is. A kernel's blocks of q, k,
+    v, the output's gradient and its float32 accumulators all grow with the
+    tile times their width, so the widest decides what fits.
 
-    Not measured, so the rule is a guess there: any ``head_dim`` but 64 and
-    128, float32 operands, calls that are not causal, carry a ``key_mask``
-    or have ``seq_q != seq_k``, and sequences that are no multiple of the
-    tile (padded up to whole tiles). Heads wider than 128 get 512 x 512
-    because the chip's compiler refuses 1024 x 1024 there (``flash_bwd_dkv``
-    at 256 wide in float32 does not fit its share of VMEM); what the
-    defaults compile for is pinned in
+    Swept on the chip at width 64 (TPU v5e, ``[16, 12, 1024, 64]`` and
+    ``[4, 12, 4096, 64]`` bf16 causal, blocks of 128 to 1024 each way, each
+    kernel's device time read from a trace; table in PERF.md section 5):
+    every kernel is fastest at 1024 x 1024. Their per-tile work that does
+    not grow with the tile's width (the row maxima and sums, the rescale
+    of the accumulators, the step's copies) outweighs the dead pairs a
+    large tile drags in, and a tile that the causal diagonal crosses
+    corner to corner no longer computes its dead half
+    (``flash_attention.TilePlan``: four row sub-blocks of 256 at this
+    size). That ended the one exception, ``flash_bwd_dkv`` at 512 x 512
+    for 1024 causal queries and keys (0.821 ms a layer at one tile in four
+    sub-blocks against 1.042 at 512 x 512 tiles in two each; PR 32). At
+    width 128 the same tile was timed in the cells (``[2, 8, 4096, 128]``,
+    ``[2, 32, 8192, 128]`` under a pair mask, ``[1, 28, 16384, 128]`` under
+    a window) but not swept against smaller ones.
+
+    Over 128 the rule is 512 x 512 because the chip's compiler refuses 1024
+    x 1024 at 256 wide in float32 (``flash_bwd_dkv`` does not fit its share
+    of VMEM). At latent attention's 192-wide q and k over 128-wide v
+    (``kanana2_30b_a3b``'s ``[2, 32, 8192]``, bf16 causal) 1024 x 1024
+    compiles and is faster: one layer's forward 13.27 ms against 15.01 at
+    512 x 512, forward and backward 52.64 against 60.16 (PR 39, host clock
+    around ``block_until_ready``, 12 calls a reading; PERF.md section 5).
+    The rule stands at 512 there all the same: the cell was measured and
+    its limits set at it, and a change of geometry is a ``perf_opt`` PR's
+    to claim in that cell. Not measured: float32 operands, calls that are
+    not causal, carry a ``key_mask`` or have ``seq_q != seq_k``, and
+    sequences that are no multiple of the tile (padded up to whole
+    tiles). What the defaults compile for is pinned in
     ``tests/test_flash_compiles_for_v5e.py``.
 
     DL4J_TPU_FLASH_BLOCK_Q/K, where set, give all three kernels that one

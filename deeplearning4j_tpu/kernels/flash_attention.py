@@ -34,7 +34,10 @@ far edge crosses corner to corner is swept in sub-blocks as the diagonal's
 mirror image. The head dimension is never padded (a block as wide
 as the array is legal at any width; 8 to 256 were compiled for the v5e),
 and the softmax scale goes on the ``[block_q, D]`` operand, not on the
-scores.
+scores. The value, and with it the output, its gradient and dv, may be
+narrower than q and k (latent attention's 128 under 192): each operand,
+accumulator and gradient takes its own width, so the products that read
+v (PV, dP, dV) run at v's and no operand is padded to the other's.
 
 Backward: blockwise Pallas kernels (FlashAttention-2 style). The forward
 saves the per-row logsumexp (lane-broadcast [BH,T,128] layout, the Mosaic
@@ -702,7 +705,7 @@ def _pair_mask_tiles(pair_mask, block_q, block_k):
 def _flash_fwd(q, k, v, key_mask, pair_mask, *, causal, scale, block_q,
                block_k, window=None, save_lse=False):
     b, h, t, d = q.shape
-    s_len = k.shape[2]
+    s_len, dv = k.shape[2], v.shape[-1]
     plan = TilePlan(t, s_len, block_q, block_k, causal, pair_mask is not None,
                     window)
     qp, kp, vp = _rows(q, block_q), _rows(k, block_k), _rows(v, block_k)
@@ -720,7 +723,7 @@ def _flash_fwd(q, k, v, key_mask, pair_mask, *, causal, scale, block_q,
     operands = [qp, kp, vp]
     in_specs = [pl.BlockSpec((1, block_q, d), q_index),
                 pl.BlockSpec((1, block_k, d), kv_index),
-                pl.BlockSpec((1, block_k, d), kv_index)]
+                pl.BlockSpec((1, block_k, dv), kv_index)]
     if key_mask is not None:
         operands.append(_key_mask_rows(key_mask, h, block_k))
         in_specs.append(pl.BlockSpec(
@@ -730,8 +733,8 @@ def _flash_fwd(q, k, v, key_mask, pair_mask, *, causal, scale, block_q,
         in_specs.append(pl.BlockSpec(
             (1, block_q, block_k),
             lambda bh, qi, step: (bh // h, qi, fetched_k(qi, step))))
-    out_specs = [pl.BlockSpec((1, block_q, d), q_index)]
-    out_shape = [jax.ShapeDtypeStruct((b * h, tq, d), q.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, dv), q_index)]
+    out_shape = [jax.ShapeDtypeStruct((b * h, tq, dv), q.dtype)]
     if save_lse:
         out_specs.append(pl.BlockSpec((1, block_q, 128), q_index))
         out_shape.append(jax.ShapeDtypeStruct((b * h, tq, 128), jnp.float32))
@@ -754,14 +757,14 @@ def _flash_fwd(q, k, v, key_mask, pair_mask, *, causal, scale, block_q,
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
                                          pair_mask=plan.pair_mask),
         interpret=_interpret(),
         name="flash_fwd",
     )(*operands)
-    out = res[0][:, :t].reshape(b, h, t, d)
+    out = res[0][:, :t].reshape(b, h, t, dv)
     return out, (res[1] if save_lse else None)
 
 
@@ -859,9 +862,11 @@ def _flash_bwd_call(kernel_fn, q, k, v, key_mask, pair_mask, g, lse, delta, *,
     """One backward kernel at its own geometry, as (kernel, the keyword
     arguments of its ``pallas_call``, operands). ``key_major``: the grid is
     (bh, ki, qi), the query sweep innermost (``flash_bwd_dkv``); else
-    (bh, qi, ki) (``flash_bwd_dq``). The outputs come back padded."""
+    (bh, qi, ki) (``flash_bwd_dq``). The outputs come back padded. q, k
+    and dq, dk are ``d`` wide, v, the output's gradient ``g`` and dv
+    ``dv`` wide."""
     b, h, t, d = q.shape
-    s_len = k.shape[2]
+    s_len, dv = k.shape[2], v.shape[-1]
     plan = TilePlan(t, s_len, block_q, block_k, causal, pair_mask is not None,
                     window)
 
@@ -890,9 +895,11 @@ def _flash_bwd_call(kernel_fn, q, k, v, key_mask, pair_mask, g, lse, delta, *,
 
     q_spec = pl.BlockSpec((1, block_q, d), q_index)
     kv_spec = pl.BlockSpec((1, block_k, d), kv_index)
+    v_spec = pl.BlockSpec((1, block_k, dv), kv_index)
+    g_spec = pl.BlockSpec((1, block_q, dv), q_index)
     row_spec = pl.BlockSpec((1, block_q, 128), q_index)
     operands = [_rows(q, block_q), _rows(k, block_k), _rows(v, block_k)]
-    in_specs = [q_spec, kv_spec, kv_spec]
+    in_specs = [q_spec, kv_spec, v_spec]
     if key_mask is not None:
         operands.append(_key_mask_rows(key_mask, h, block_k))
         in_specs.append(pl.BlockSpec((1, 1, block_k), km_index))
@@ -900,15 +907,16 @@ def _flash_bwd_call(kernel_fn, q, k, v, key_mask, pair_mask, g, lse, delta, *,
         operands.append(_pair_mask_tiles(pair_mask, block_q, block_k))
         in_specs.append(pl.BlockSpec((1, block_q, block_k), pm_index))
     operands += [_rows(g, block_q), rows_q(lse), rows_q(delta)]
-    in_specs += [q_spec, row_spec, row_spec]
+    in_specs += [g_spec, row_spec, row_spec]
     tq, tk = operands[0].shape[1], operands[1].shape[1]
 
     if key_major:
         grid = (b * h, plan.n_k, plan.steps_q)
-        out_specs = [kv_spec, kv_spec]
+        out_specs = [kv_spec, v_spec]
         out_shape = [jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
-                     jax.ShapeDtypeStruct((b * h, tk, d), v.dtype)]
-        scratch = [pltpu.VMEM((block_k, d), jnp.float32)] * 2
+                     jax.ShapeDtypeStruct((b * h, tk, dv), v.dtype)]
+        scratch = [pltpu.VMEM((block_k, d), jnp.float32),
+                   pltpu.VMEM((block_k, dv), jnp.float32)]
     else:
         grid = (b * h, plan.n_q, plan.steps_k)
         out_specs = [q_spec]
@@ -936,9 +944,10 @@ def _flash_bwd_call(kernel_fn, q, k, v, key_mask, pair_mask, g, lse, delta, *,
 
 def _flash_bwd_impl(q, k, v, key_mask, pair_mask, out, lse, g, *, causal,
                     scale, blocks, window):
-    """Blockwise backward: two kernels, each at its own geometry."""
+    """Blockwise backward: two kernels, each at its own geometry; delta is
+    taken at the output's width, v's."""
     b, h, t, d = q.shape
-    s_len = k.shape[2]
+    s_len, width_v = k.shape[2], v.shape[-1]
     delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta.reshape(b * h, t)[:, :, None],
                              (b * h, t, 128))
@@ -961,7 +970,7 @@ def _flash_bwd_impl(q, k, v, key_mask, pair_mask, out, lse, g, *, causal,
     )(*operands)
     return (dq[:, :t].reshape(b, h, t, d),
             dk[:, :s_len].reshape(b, h, s_len, d),
-            dv[:, :s_len].reshape(b, h, s_len, d))
+            dv[:, :s_len].reshape(b, h, s_len, width_v))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -1057,7 +1066,11 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None, bias=None,
                     key_mask=None, pair_mask=None, window: int = None,
                     block_q: int = None, block_k: int = None,
                     backend: str = None):
-    """Blockwise attention; q [B,H,T,D], k/v [B,H,S,D] → [B,H,T,D].
+    """Blockwise attention; q [B,H,T,D], k [B,H,S,D], v [B,H,S,Dv] →
+    [B,H,T,Dv]. The value may be narrower than the query and key (latent
+    attention's 192-wide query-key heads over 128-wide values): the
+    kernels then hold v, the output, its gradient and dv at Dv and q, k,
+    dq, dk at D, and the softmax scale stays D ** -0.5.
 
     ``key_mask`` [B,S] 1/0 (padding mask) runs inside the kernel — the
     BERT path keeps the flash fast path. ``pair_mask`` [B,T,S] of int8
@@ -1085,8 +1098,10 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None, bias=None,
                                    key_mask=key_mask, pair_mask=pair_mask,
                                    scale=scale, window=window)
     t, s_len = q.shape[2], k.shape[2]
-    blocks = _call_blocks(t, s_len, d, causal, block_q, block_k)
-    _record_plan(t, s_len, d, causal, key_mask is not None,
+    dv = v.shape[-1]
+    # the widest operand sets the tile: q and k, or v where it is wider
+    blocks = _call_blocks(t, s_len, max(d, dv), causal, block_q, block_k)
+    _record_plan(t, s_len, d, dv, causal, key_mask is not None,
                  pair_mask is not None, window, blocks)
     mesh = _active_kernel_mesh()
     if mesh is not None:
@@ -1160,8 +1175,8 @@ def pairs_of_call(seq_q, seq_k, head_dim, *, causal, window=None):
     return required, sum(p.pairs_touched() for p in plans) // len(plans)
 
 
-def _record_plan(seq_q, seq_k, head_dim, causal, has_mask, has_pairs, window,
-                 blocks):
+def _record_plan(seq_q, seq_k, head_dim, head_dim_v, causal, has_mask,
+                 has_pairs, window, blocks):
     """One ``kernel.flash_plan`` flight event per call, at trace time (a
     jitted step traces its calls once, so this costs a run nothing): the
     geometry of each kernel, how many of its tiles are dead (skipped,
@@ -1173,7 +1188,8 @@ def _record_plan(seq_q, seq_k, head_dim, causal, has_mask, has_pairs, window,
 
     record_event(
         "kernel.flash_plan", seq_q=seq_q, seq_k=seq_k, head_dim=head_dim,
-        causal=causal, key_mask=has_mask, pair_mask=has_pairs, window=window,
+        head_dim_v=head_dim_v, causal=causal, key_mask=has_mask,
+        pair_mask=has_pairs, window=window,
         **{name: {"block_q": bq, "block_k": bk,
                   **TilePlan(seq_q, seq_k, bq, bk, causal,
                              window=window).counts()}

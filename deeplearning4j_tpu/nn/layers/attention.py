@@ -39,6 +39,7 @@ from deeplearning4j_tpu.observability.vocab import (
     SCOPE_CCA_MIX,
     SCOPE_DSA_INDEX,
     SCOPE_DSA_SELECT,
+    SCOPE_MLA_LATENT,
     SCOPE_MLP,
 )
 from deeplearning4j_tpu.ops import nn as opsnn
@@ -102,14 +103,27 @@ def _causal_convs(x, w0, w1):
                for j in range(len(w1)))
 
 
-def _rotary(x, theta, share):
+def _rotary(x, theta, share, interleaved=False):
     """Rotary positions on the first ``share`` of the last axis of ``x``
-    [N,T,heads,d] (float32); dimension i is paired with i + half."""
+    [N,T,heads,d] (float32); dimension i is paired with i + half, or
+    (``interleaved``, with ``share`` 1) dimension 2i with 2i + 1, both
+    turned by ``pos * theta ** (-2i / d)``."""
     t, d = x.shape[1], x.shape[-1]
     turned = int(d * share)
     half = turned // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / turned)
     angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None]
+    if interleaved:
+        if turned != d:
+            raise ValueError("interleaved pairs turn the whole axis")
+        # each angle twice, side by side; a pair's partner is its lane
+        # neighbour, reached by a roll of the lanes (no reshape to pairs,
+        # whose last axis of 2 the chip would pad to 128)
+        angle = jnp.repeat(angle, 2, axis=-1)[:, None, :]
+        even = jnp.arange(d) % 2 == 0
+        partner = jnp.where(even, -jnp.roll(x, -1, axis=-1),
+                            jnp.roll(x, 1, axis=-1))
+        return x * jnp.cos(angle) + partner * jnp.sin(angle)
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
     a, b, rest = x[..., :half], x[..., half:turned], x[..., turned:]
     return jnp.concatenate(
@@ -359,6 +373,61 @@ def grouped_query_attention(params, h, *, num_heads: int, num_kv_heads: int,
         t, t, params["Wq"].shape[-1] // num_heads, causal=True, window=window)
     return y, {"pairs_required": jnp.int32(n * required),
                "pairs_touched": jnp.int32(n * touched)}
+
+
+def latent_attention(params, h, *, num_heads: int, nope_dim: int,
+                     rope_dim: int, v_dim: int, rope_theta: float,
+                     eps: float):
+    """Multi-head latent attention (MLA, DeepSeek-V2/V3, without a query
+    latent: ``q_lora_rank`` null), causal, over ``h`` [N,T,E], already
+    normed.
+
+    q = h Wq, each head split into ``nope_dim`` and ``rope_dim``
+    (``q_pe``). [c, k_pe] = h Wkva: a latent c and one rotary key a
+    position that all heads share. c is RMSNormed (gain ``kv_norm``), and
+    c Wkvb gives each head ``nope_dim`` of key (``k_nope``) and ``v_dim``
+    of value. ``q_pe`` and ``k_pe`` carry rotary positions on interleaved
+    pairs (dimension 2i with 2i + 1); k = [k_nope, k_pe] with ``k_pe``
+    broadcast to the heads, q = [q_nope, q_pe], both ``nope_dim +
+    rope_dim`` wide over values ``v_dim`` wide: ``flash_attention`` takes
+    the two widths as they are, the scale (nope_dim + rope_dim) ** -0.5.
+    ``Wo`` projects the heads back. Everything from Wkva to the joined q
+    and k, the up-projection included, is the ``mla_latent`` sub-scope;
+    Wq, the flash kernels and Wo are not."""
+    n, t, _ = h.shape
+    f32 = jnp.float32
+    latent = params["Wkvb"].shape[0]
+    q = opsnn.linear(h, params["Wq"]).reshape(n, t, num_heads, -1)
+    with jax.named_scope(SCOPE_MLA_LATENT):
+        compressed = opsnn.linear(h, params["Wkva"])
+        c = opsnn.rms_norm(compressed[..., :latent], params["kv_norm"], eps)
+        k_pe = _rotary(compressed[..., None, latent:].astype(f32),
+                       rope_theta, 1.0, interleaved=True).astype(h.dtype)
+        q_pe = _rotary(q[..., nope_dim:].astype(f32), rope_theta, 1.0,
+                       interleaved=True).astype(h.dtype)
+        q = jnp.concatenate([q[..., :nope_dim], q_pe],
+                            axis=-1).transpose(0, 2, 1, 3)
+        kv = opsnn.linear(c, params["Wkvb"]).reshape(n, t, num_heads, -1)
+        k = jnp.concatenate(
+            [kv[..., :nope_dim],
+             jnp.broadcast_to(k_pe, (n, t, num_heads, rope_dim))],
+            axis=-1).transpose(0, 2, 1, 3)
+        v = kv[..., nope_dim:].transpose(0, 2, 1, 3)
+    _record_mla(num_heads, nope_dim, rope_dim, v_dim, latent)
+    y = flash_attention(q, k, v, causal=True)
+    return opsnn.linear(_merge_heads(y), params["Wo"])
+
+
+def _record_mla(heads, nope_dim, rope_dim, v_dim, latent):
+    """One ``attention.mla`` flight event a layer, at trace time: the
+    widths of the heads and the latent. The up-projection is never
+    recomputed: the flash kernels keep k and v as their residuals."""
+    from deeplearning4j_tpu.observability.flightrecorder import record_event
+
+    record_event(
+        "attention.mla", heads=heads, qk_head_dim=nope_dim + rope_dim,
+        v_head_dim=v_dim, kv_lora_rank=latent, qk_rope_head_dim=rope_dim,
+        rope_pairs="interleaved", up_projection_recomputed=False)
 
 
 def _record_selection(seq_len, top_k, index_heads, width):

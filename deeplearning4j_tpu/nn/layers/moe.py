@@ -39,6 +39,7 @@ from deeplearning4j_tpu.nn.initializers import get_initializer
 from deeplearning4j_tpu.observability.vocab import (
     SCOPE_MOE_EXPERTS,
     SCOPE_MOE_ROUTE,
+    SCOPE_MOE_SHARED,
 )
 
 
@@ -407,8 +408,8 @@ class RoutedExperts(LayerConfig):
     ``gate_activation``: SiLU or ReLU), of which this layer holds
     ``experts_held`` (expert parallelism: the other chips hold the rest).
 
-    The router is, by ``router``, one of two forms, both over ALL the
-    experts whatever is held and both in float32 from their first matrix
+    The router is, by ``router``, one of three forms, all over ALL the
+    experts whatever is held and all in float32 from their first matrix
     on. ``"mlp"``: a small MLP over a ``router_hidden``-wide state that
     crosses layers (ZAYA1, arXiv:2511.17127): r = x Wr + gamma r_before,
     z = Wc gelu(Wb gelu(Wa r)), p = softmax(z), the experts chosen by
@@ -416,9 +417,21 @@ class RoutedExperts(LayerConfig):
     ``"linear"``: z = x Wg, p = softmax(z), no bias and no state. A token's
     ``top_k`` largest are its experts, each weighted by its p over the sum
     of the chosen p's, wherever those experts are held (one expert a token
-    keeps its own p). The router reads the rows the experts read unless
-    ``apply`` is given others (``router_input``: a layer whose router
-    stands before its attention reads the layer's input).
+    keeps its own p). ``"sigmoid"`` (DeepSeek-V3's ``noaux_tc`` in one
+    group): z = x Wg, p = sigmoid(z), each chosen expert weighted by its p
+    over the sum of the chosen p's (+1e-20) times ``scaling``. Where
+    ``params`` hold a ``bias`` (the "mlp" router's leaf; a caller may feed
+    the sigmoid router a constant one, DeepSeek-V3's correction bias), the
+    choice is by p plus the bias and the weights stay p's, and no gradient
+    reaches it. The router reads the
+    rows the experts read unless ``apply`` is given others
+    (``router_input``: a layer whose router stands before its attention
+    reads the layer's input).
+
+    ``shared_units`` (0: none) adds a dense SiLU-gated MLP of that width
+    (``shared_gate``, ``shared_up``, ``shared_down``) that every token
+    runs, to the routed sum: the ``moe_shared`` sub-scope, apart from the
+    routed experts' ``moe_experts``.
 
     Nothing is looked up by index over the token-expert pairs: a chosen
     expert's probability and its place among the experts held come from
@@ -449,13 +462,19 @@ class RoutedExperts(LayerConfig):
     router_hidden: int = 256
     carries_router: bool = True   # False in the first layer: no gamma
     top_k: int = 1
-    router: str = "mlp"           # or "linear": one matrix, no state
+    router: str = "mlp"           # or "linear": one matrix, no state; or
+                                  # "sigmoid": one matrix, sigmoid scores
     gate_activation: str = "silu"  # or "relu"
+    scaling: float = 1.0          # of the chosen weights, "sigmoid" only
+    shared_units: int = 0         # the shared experts' width; 0: none
 
     def __post_init__(self):
         if self.gate_activation not in _GATES:
             raise ValueError(f"gate_activation {self.gate_activation!r} not "
                              f"in {sorted(_GATES)}")
+        if self.router not in ("mlp", "linear", "sigmoid"):
+            raise ValueError(f"router {self.router!r} not in "
+                             "['linear', 'mlp', 'sigmoid']")
 
     def output_shape(self, input_shape):
         return tuple(input_shape)
@@ -465,7 +484,7 @@ class RoutedExperts(LayerConfig):
         i, r, held = self.units or h, self.router_hidden, len(self.experts_held)
         w_init = get_initializer("xavier")
         ks = jax.random.split(rng, 7)
-        if self.router == "linear":
+        if self.router in ("linear", "sigmoid"):
             params = {"Wg": w_init(ks[0], (h, self.experts_total), dtype)}
         else:
             params = {
@@ -481,6 +500,11 @@ class RoutedExperts(LayerConfig):
             gate=w_init(ks[4], (held, h, i), dtype),
             up=w_init(ks[5], (held, h, i), dtype),
             down=w_init(ks[6], (held, i, h), dtype))
+        if self.shared_units:
+            s, kg, ku, kd = self.shared_units, *jax.random.split(ks[4], 3)
+            params.update(shared_gate=w_init(kg, (h, s), dtype),
+                          shared_up=w_init(ku, (h, s), dtype),
+                          shared_down=w_init(kd, (s, h), dtype))
         return params, {}
 
     def route(self, params, tokens, carried):
@@ -492,7 +516,7 @@ class RoutedExperts(LayerConfig):
         def product(x, name):
             return jnp.matmul(x, params[name].astype(f32), precision=exact)
 
-        if self.router == "linear":
+        if self.router in ("linear", "sigmoid"):
             r, z = None, product(tokens.astype(f32), "Wg")
         else:
             r = product(tokens.astype(f32), "Wr")
@@ -501,7 +525,10 @@ class RoutedExperts(LayerConfig):
             z = product(
                 jax.nn.gelu(product(jax.nn.gelu(product(r, "Wa")), "Wb")),
                 "Wc")
-        prob = tilted = jax.nn.softmax(z, axis=-1)
+        if self.router == "sigmoid":
+            prob = tilted = jax.nn.sigmoid(z)
+        else:
+            prob = tilted = jax.nn.softmax(z, axis=-1)
         if "bias" in params:
             tilted = prob + jax.lax.stop_gradient(params["bias"].astype(f32))
         if self.top_k == 1:
@@ -510,7 +537,10 @@ class RoutedExperts(LayerConfig):
             _, chosen = jax.lax.top_k(tilted, self.top_k)
         # ``prob`` at ``chosen``, whether or not a bias tilted the choice
         share = _of_chosen(prob, chosen)
-        if self.top_k > 1:
+        if self.router == "sigmoid":
+            share = share / (jnp.sum(share, axis=-1, keepdims=True)
+                             + 1e-20) * self.scaling
+        elif self.top_k > 1:
             share = share / jnp.sum(share, axis=-1, keepdims=True)
         return r, chosen, share
 
@@ -583,6 +613,11 @@ class RoutedExperts(LayerConfig):
             # one branch not taken, where all that landed fits the first
             y = jax.lax.cond(rows_a_piece < landed, spilled, lambda y: y, y)
             pieces_run = jnp.maximum(1, -(-landed // rows_a_piece))
+        if self.shared_units:
+            with jax.named_scope(SCOPE_MOE_SHARED):
+                inner = (gate(tokens @ params["shared_gate"])
+                         * (tokens @ params["shared_up"]))
+                y = y + inner @ params["shared_down"]
         y = y.reshape(shape)
         routed = {"tokens_here": sizes[:held], "pieces_run": pieces_run}
         if r is not None:

@@ -130,6 +130,7 @@ COMPILE_KINDS = frozenset({
 # ops/loss.py)
 KERNEL_KINDS = frozenset({
     "attention.dsa_select",
+    "attention.mla",
     "head.linear_cross_entropy",
     "kernel.flash_plan",
     "kernel.grouped_product",
@@ -234,9 +235,16 @@ SCOPE_DSA_SELECT = "dsa_select"    # in attn: the threshold and the pair mask
 # queries reach back over a window or over everything before them
 SCOPE_ATTN_WINDOW = "attn_window"
 SCOPE_ATTN_GLOBAL = "attn_global"
+SCOPE_MLA_LATENT = "mla_latent"    # in attn: latent attention's down- and
+                                   # up-projections, the latent's norm, the
+                                   # rotary turns, k_pe's broadcast to the
+                                   # heads and the joins into q and k; not
+                                   # the flash kernels, nor Wq or Wo
+SCOPE_MOE_SHARED = "moe_shared"    # in mlp: the shared experts' three
+                                   # products and their gate
 SUB_SCOPES = (SCOPE_CCA_MIX, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
               SCOPE_DSA_INDEX, SCOPE_DSA_SELECT, SCOPE_ATTN_WINDOW,
-              SCOPE_ATTN_GLOBAL)
+              SCOPE_ATTN_GLOBAL, SCOPE_MLA_LATENT, SCOPE_MOE_SHARED)
 
 # Metrics of the step that ``Trainer.fit`` fetches once as it returns (the
 # last step's) and publishes in ``observability/runtime.step_counters``.

@@ -29,9 +29,9 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-# (batch, heads, queries, keys, head width), dtype, causal, key mask, and
-# optionally the caller's own blocks, environment, a mask of pairs, and a
-# window
+# (batch, heads, queries, keys, head width[, value width]), dtype, causal,
+# key mask, and optionally the caller's own blocks, environment, a mask of
+# pairs, and a window
 _CALLS = {
     "gpt2_small.train_s1024": ((16, 12, 1024, 1024, 64), "bfloat16", True,
                                False),
@@ -62,6 +62,15 @@ _CALLS = {
     "ragged_1000_masked": ((2, 8, 1000, 1000, 64), "bfloat16", True, True),
     "cross_512_by_2048": ((2, 8, 512, 2048, 64), "bfloat16", True, False),
     "wide_heads_float32": ((2, 4, 2048, 2048, 256), "float32", True, False),
+    # latent attention's call (PR 39): 32 heads whose q and k are 192 wide
+    # over v 128 wide, at the tile the widest operand sets, 512 x 512
+    "kanana2_30b_a3b.train_s8192": ((2, 32, 8192, 8192, 192, 128),
+                                    "bfloat16", True, False),
+    "unequal_widths_float32_windowed_pair_masked": (
+        (2, 4, 3000, 3000, 192, 128), "float32", True, True, None, None,
+        True, 1000),
+    "unequal_widths_not_causal_masked": ((2, 4, 2048, 2048, 192, 128),
+                                         "bfloat16", False, True),
     # parallel/sequence.py::ulysses_attention: the whole sequence, a
     # quarter of the heads, its own 256 x 256 blocks, the gathered mask
     "ulysses_local_call": ((2, 3, 4096, 4096, 64), "bfloat16", True, True,
@@ -75,18 +84,20 @@ _CALLS = {
 @pytest.mark.parametrize("call", sorted(_CALLS))
 def test_forward_and_backward_compile_at_the_default_geometry(
         call, one_chip, as_on_tpu, monkeypatch):
-    (b, h, t, s, d), dtype, causal, masked, *rest = _CALLS[call]
+    (b, h, t, s, d, *dv), dtype, causal, masked, *rest = _CALLS[call]
+    dv = dv[0] if dv else d
     own_blocks, env, pairs, window = rest + [None, None, False, None][len(rest):]
     for name, value in (env or {}).items():
         monkeypatch.setenv(name, value)
-    blocks = flash_block_sizes(t, s, d, causal)
+    blocks = flash_block_sizes(t, s, max(d, dv), causal)
     if own_blocks:
         blocks = FlashBlocks(*[own_blocks] * 3)
     blocks = FlashBlocks(*[fa._clamp_blocks(bq, bk, t, s)
                            for bq, bk in blocks])
     q = jax.ShapeDtypeStruct((b, h, t, d), dtype, sharding=one_chip)
     k = jax.ShapeDtypeStruct((b, h, s, d), dtype, sharding=one_chip)
-    args = (q, k, k)
+    v = jax.ShapeDtypeStruct((b, h, s, dv), dtype, sharding=one_chip)
+    args = (q, k, v)
     if masked:
         args += (jax.ShapeDtypeStruct((b, s), jnp.float32,
                                       sharding=one_chip),)
