@@ -96,10 +96,10 @@ def flash_block_sizes(seq_q: int, seq_k: int, head_dim: int,
     cuts to the sequence where that is shorter. The widest operand sets it:
     ``head_dim`` is the widest of q and k's width and v's (the output and
     the gradients are as wide as their operands), and ``flash_attention``
-    passes that. 1024 x 1024 where it is no wider than 128, 512 x 512
-    where it is. A kernel's blocks of q, k,
-    v, the output's gradient and its float32 accumulators all grow with the
-    tile times their width, so the widest decides what fits.
+    passes that. 1024 x 1024 where it is no wider than 192, 512 x 512
+    where it is. A kernel's blocks of q, k, v, the output's gradient and
+    its float32 accumulators all grow with the tile times their width, so
+    the widest decides what fits.
 
     Swept on the chip at width 64 (TPU v5e, ``[16, 12, 1024, 64]`` and
     ``[4, 12, 4096, 64]`` bf16 causal, blocks of 128 to 1024 each way, each
@@ -117,25 +117,27 @@ def flash_block_sizes(seq_q: int, seq_k: int, head_dim: int,
     ``[2, 32, 8192, 128]`` under a pair mask, ``[1, 28, 16384, 128]`` under
     a window) but not swept against smaller ones.
 
-    Over 128 the rule is 512 x 512 because the chip's compiler refuses 1024
-    x 1024 at 256 wide in float32 (``flash_bwd_dkv`` does not fit its share
-    of VMEM). At latent attention's 192-wide q and k over 128-wide v
-    (``kanana2_30b_a3b``'s ``[2, 32, 8192]``, bf16 causal) 1024 x 1024
-    compiles and is faster: one layer's forward 13.27 ms against 15.01 at
-    512 x 512, forward and backward 52.64 against 60.16 (PR 39, host clock
-    around ``block_until_ready``, 12 calls a reading; PERF.md section 5).
-    The rule stands at 512 there all the same: the cell was measured and
-    its limits set at it, and a change of geometry is a ``perf_opt`` PR's
-    to claim in that cell. Not measured: float32 operands, calls that are
-    not causal, carry a ``key_mask`` or have ``seq_q != seq_k``, and
-    sequences that are no multiple of the tile (padded up to whole
-    tiles). What the defaults compile for is pinned in
+    Swept on the chip at latent attention's 192-wide q and k over 128-wide
+    v (``kanana2_30b_a3b``'s ``[2, 32, 8192]``, bf16 causal, blocks of 512
+    to 2048 each way wherever a kernel compiles, device time from a trace;
+    PERF.md section 5): every kernel is fastest at 1024 x 1024 here too,
+    ``flash_fwd`` / ``flash_bwd_dkv`` / ``flash_bwd_dq`` 12.08 / 19.40 /
+    17.44 ms a layer against 13.96 / 22.28 / 20.22 at 512 x 512, 12 to 14%
+    less each; 512 x 1024 and 1024 x 512 take at most 1 ms a kernel off
+    512 x 512, and every tile 2048 long that compiles is slower than 512 x
+    512 (a tile whose sides differ has no diagonal split). Over 192 the
+    rule is 512 x 512 because the chip's compiler refuses 1024 x 1024 at
+    256 wide in float32 (``flash_bwd_dkv`` does not fit its share of
+    VMEM). A small float32 call at 192/128 under a window and both masks
+    (``[2, 4, 3000]``) reads 0.86 ms at 1024 x 1024 against 0.83 at 512 x
+    512. Not measured: calls that are not causal or have ``seq_q !=
+    seq_k``. What the defaults compile for is pinned in
     ``tests/test_flash_compiles_for_v5e.py``.
 
     DL4J_TPU_FLASH_BLOCK_Q/K, where set, give all three kernels that one
     geometry; the split follows from it.
     """
-    largest = 1024 if head_dim <= 128 else 512
+    largest = 1024 if head_dim <= 192 else 512
     blocks = FlashBlocks(*[(largest, largest)] * 3)
     env_q = os.environ.get("DL4J_TPU_FLASH_BLOCK_Q")
     env_k = os.environ.get("DL4J_TPU_FLASH_BLOCK_K")

@@ -62,8 +62,8 @@ _CALLS = {
     "ragged_1000_masked": ((2, 8, 1000, 1000, 64), "bfloat16", True, True),
     "cross_512_by_2048": ((2, 8, 512, 2048, 64), "bfloat16", True, False),
     "wide_heads_float32": ((2, 4, 2048, 2048, 256), "float32", True, False),
-    # latent attention's call (PR 39): 32 heads whose q and k are 192 wide
-    # over v 128 wide, at the tile the widest operand sets, 512 x 512
+    # latent attention's call: 32 heads whose q and k are 192 wide over v
+    # 128 wide, at the tile the widest operand sets, 1024 x 1024
     "kanana2_30b_a3b.train_s8192": ((2, 32, 8192, 8192, 192, 128),
                                     "bfloat16", True, False),
     "unequal_widths_float32_windowed_pair_masked": (

@@ -79,6 +79,17 @@ def test_causal_forward_and_gradients(interpreted, t, block):
     assert_close(got, want)
 
 
+def test_latent_widths_at_the_default_tile(interpreted):
+    """The cell's widths, 192 over 128, at the geometry the rule gives them:
+    2,048 positions in 1024 x 1024 tiles, the two diagonal tiles in four
+    row sub-blocks of 256."""
+    plan = fa.forward_plan(2048, 2048, 192, causal=True)
+    assert (plan.block_q, plan.block_k, plan.sub_blocks) == (1024, 1024, 4)
+    q, k, v, g = inputs(1, 1, 2048, 2048, seed=40, d=192, dv=128)
+    got, want = both(q, k, v, g, causal=True)
+    assert_close(got, want)
+
+
 @pytest.mark.parametrize("window", [100, 512])
 def test_a_window(interpreted, window):
     """A window of no whole block, and one whose far edge is split."""
@@ -136,14 +147,15 @@ def test_under_a_mesh(interpreted):
 
 
 def test_the_widest_operand_sets_the_tile(interpreted):
-    assert flash_block_sizes(8192, 8192, 128, True).fwd == (1024, 1024)
-    for d in (192, 256):
-        assert set(flash_block_sizes(8192, 8192, d, True)) == {(512, 512)}
+    for d in (64, 128, 192):
+        assert set(flash_block_sizes(8192, 8192, d, True)) == {(1024, 1024)}
+    assert set(flash_block_sizes(8192, 8192, 256, True)) == {(512, 512)}
     # a call keys the rule on its widest operand, q and k's or v's
     before = get_flight_recorder()
     flight = set_flight_recorder(FlightRecorder())
     try:
-        for d, dv in ((192, 128), (128, 192), (128, 64)):
+        for d, dv in ((192, 128), (128, 192), (128, 64), (256, 128),
+                      (128, 256)):
             q = jax.ShapeDtypeStruct((1, 1, 4096, d), jnp.bfloat16)
             v = jax.ShapeDtypeStruct((1, 1, 4096, dv), jnp.bfloat16)
             jax.eval_shape(lambda q, k, v: fa.flash_attention(
@@ -151,7 +163,7 @@ def test_the_widest_operand_sets_the_tile(interpreted):
     finally:
         set_flight_recorder(before)
     assert [e["data"]["fwd"]["block_q"] for e in flight.events(
-        kinds=["kernel.flash_plan"])] == [512, 512, 1024]
+        kinds=["kernel.flash_plan"])] == [1024, 1024, 1024, 512, 512]
 
 
 def test_the_flight_event_names_both_widths(interpreted):
@@ -196,12 +208,19 @@ def test_the_cells_call_lowers_for_the_chip_with_each_operand_at_its_width(
 
 def test_the_plans_a_caller_asks_for_take_both_widths(monkeypatch):
     """``forward_plan`` and ``pairs_of_call`` at the cell's call, keyed on
-    its widest operand (192): 512 x 512 tiles, where 128 would give 1024."""
+    its widest operand (192): 1024 x 1024 tiles as at 128, and 512 x 512
+    at 256. The tile changes no work: both geometries touch the same
+    pairs."""
     monkeypatch.setattr(fa, "_use_pallas", lambda: True)
     plan = fa.forward_plan(8192, 8192, 192, causal=True)
-    assert (plan.block_q, plan.block_k) == (512, 512)
+    assert (plan.block_q, plan.block_k) == (1024, 1024)
+    assert plan.sub_blocks == 4
     assert fa.forward_plan(8192, 8192, 128, causal=True).block_q == 1024
+    assert fa.forward_plan(8192, 8192, 256, causal=True).block_q == 512
     required, touched = fa.pairs_of_call(8192, 8192, 192, causal=True)
     assert required == 8192 * 8193 // 2
+    # 36 live tiles of 1024, 8 of them diagonal in four sub-blocks of 256
+    assert touched == (28 + 8 * 10 / 16) * 1024 * 1024 == 34_603_008
     # 136 live tiles of 512, 16 of them diagonal in two sub-blocks of 256
-    assert touched == (120 + 16 * 0.75) * 512 * 512
+    assert fa.pairs_of_call(8192, 8192, 256, causal=True) == (
+        required, (120 + 16 * 0.75) * 512 * 512)
